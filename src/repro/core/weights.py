@@ -158,7 +158,10 @@ class TernaryWeight:
     * ``nnz``   -- pack-time nonzero count (-1 when unknown, e.g. a wrapped
       pre-packed buffer);
     * ``scale`` / ``bias`` -- optional per-output-channel epilogue operands
-      consumed by ``ternary_gemm`` when the caller passes none explicitly.
+      consumed by ``ternary_gemm`` when the caller passes none explicitly;
+    * ``tp_dim`` -- which logical axis (``"k"`` / ``"n"``) a mesh's
+      ``"model"`` axis splits, set by ``distributed.tp.shard_params`` from
+      the placed arrays (None: unsplit); static aux data.
     """
 
     format_name = "abstract"
@@ -255,6 +258,7 @@ class Dense2Bit(TernaryWeight):
     bias: Optional[Any]               # (..., N) or None
     shape: Tuple[int, int]            # logical (K, N)
     nnz: int = -1
+    tp_dim: Optional[str] = None      # "k" | "n" | None (see base class)
 
     _leaves = ("packed", "scale", "bias")
 
@@ -306,6 +310,7 @@ class Tiled(TernaryWeight):
     tile_n: int = 128
     nnz: int = -1
     occupied_tiles: int = 0           # pack-time occupied-tile count
+    tp_dim: Optional[str] = None
 
     _leaves = ("packed", "kt_indices", "kt_counts", "scale", "bias")
     _stats = ("nnz", "occupied_tiles")
@@ -378,6 +383,7 @@ class Bitplane(TernaryWeight):
     bias: Optional[Any]
     shape: Tuple[int, int]
     nnz: int = -1
+    tp_dim: Optional[str] = None
 
     _leaves = ("plus", "minus", "scale", "bias")
 
@@ -423,6 +429,7 @@ class Base3(TernaryWeight):
     bias: Optional[Any]
     shape: Tuple[int, int]
     nnz: int = -1
+    tp_dim: Optional[str] = None
 
     _leaves = ("packed", "scale", "bias")
 

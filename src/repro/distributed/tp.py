@@ -4,15 +4,18 @@ The model's logical PartitionSpecs already encode the Megatron-style TP
 layout: QKV / up / gate projections are ``P("fsdp", "model")`` (N-dim
 column split — each device computes its own output columns, no collective)
 and down / o projections are ``P("model", "fsdp")`` (K-dim row split —
-each device holds a K-slice and XLA inserts the ``psum`` over partial
-products). ``shard_params`` makes those specs real at serve time: it
-validates every packed ``TernaryWeight`` spec twin against the mesh
-(shard boundaries must land on 2-bit pack-word / tile multiples —
-``weights.validate_spec_twin``), resolves logical names through
-``distributed.sharding.resolve_specs`` and ``device_put``s the tree.
-Execution then follows the data under GSPMD; off-TPU the packed linears
-dispatch the ``"ref"`` decode+dot lowering, which XLA partitions along the
-same splits.
+each device holds a K-slice and the partial products need a ``psum``).
+``shard_params`` makes those specs real at serve time: it validates every
+packed ``TernaryWeight`` spec twin against the mesh (shard boundaries must
+land on 2-bit pack-word / tile multiples — ``weights.validate_spec_twin``),
+resolves logical names through ``distributed.sharding.resolve_specs``,
+``device_put``s the tree and records each placed container's split in its
+static ``tp_dim``. Execution follows the data under GSPMD, except that a
+Pallas kernel has no partitioning rule: model code traced under
+``kernels.ops.tensor_parallel(mesh)`` runs each Pallas lowering of a
+TP-placed weight per shard under ``jax.shard_map`` (column split local,
+row split local then ``psum``), and paged attention over head-sharded
+pages likewise. The XLA ``"ref"`` lowering stays on GSPMD.
 
 Serving topology is dp x tp: ``replica_meshes`` carves ``dp`` disjoint
 tp-sized single-axis ``("model",)`` meshes out of the device list, one per
@@ -92,7 +95,16 @@ def shard_params(params, specs, mesh: Mesh, *, fsdp: bool = False,
     if validate:
         validate_param_specs(params, specs, mesh, fsdp=fsdp)
     shardings = sharding.resolve_specs(specs, params, mesh, fsdp)
-    return jax.device_put(params, shardings)
+    placed = jax.device_put(params, shardings)
+    split = gemm_shard_fn(mesh)
+
+    def mark(w):
+        part, _ = split((), w)
+        return w if part is None else w.replace(tp_dim=part)
+
+    return jax.tree.map(
+        lambda v: mark(v) if isinstance(v, weights.TernaryWeight) else v,
+        placed, is_leaf=lambda v: isinstance(v, weights.TernaryWeight))
 
 
 def cache_sharding(layers, cfg, mesh: Mesh):

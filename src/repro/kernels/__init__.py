@@ -11,7 +11,7 @@ from repro.kernels.ops import (SERVING_PHASES, FusedMlpPlan, GemmPlan,
                                register_fused, register_kernel,
                                register_paged_attn, serving_phase,
                                ternary_gemm, ternary_gemm_plan)
-from repro.kernels.ternary_gemm import (DECODE_MODES, K_PER_WORD,
+from repro.kernels.ternary_gemm import (K_PER_WORD,
                                         ternary_gemm_pallas,
                                         ternary_gemm_skip_db_pallas,
                                         ternary_gemm_skip_pallas)
@@ -25,7 +25,7 @@ __all__ = ["ternary_gemm", "ternary_gemm_plan", "GemmPlan",
            "fused_mlp_pallas",
            "pack_weights", "pack_weights_tiled",
            "ternary_gemm_pallas", "ternary_gemm_skip_pallas",
-           "ternary_gemm_skip_db_pallas", "DECODE_MODES",
+           "ternary_gemm_skip_db_pallas",
            "ternary_gemm_bitplane", "K_PER_WORD", "flash_attention_pallas",
            "paged_decode_attention", "register_paged_attn",
            "paged_attention_registry",

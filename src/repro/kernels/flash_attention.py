@@ -10,8 +10,9 @@ reshape K/V). Grid (BH, nq, nkv) with the KV dimension innermost
 ("arbitrary" semantics → sequential accumulation). Causal masking skips
 fully-masked KV blocks via @pl.when (no dot issued for them).
 
-Validated in interpret mode against the naive oracle
-(tests/test_flash_kernel.py); `ops`-style jit wrapper below.
+Checked in interpret mode against the naive oracle on CPU
+(tests/test_flash_kernel.py); it runs compiled only on a TPU, and only
+where ``attn_impl="pallas"`` selects it. `ops`-style jit wrapper below.
 """
 from __future__ import annotations
 
@@ -22,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ternary_gemm import CompilerParams
 
 NEG_INF = -1e30
 
@@ -115,7 +115,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -41,8 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ternary_gemm import (K_PER_WORD, CompilerParams,
-                                        _decode_tile)
+from repro.kernels.ternary_gemm import K_PER_WORD, _decode_tile
 
 __all__ = ["fused_mlp_pallas", "ACTIVATIONS"]
 
@@ -51,7 +50,9 @@ ACTIVATIONS = ("silu", "relu", "none")
 
 def _act(name: str, y: jnp.ndarray) -> jnp.ndarray:
     if name == "silu":
-        return jax.nn.silu(y)
+        # the sigmoid runs in f32 and rounds once to y's dtype, as XLA
+        # computes a bf16 ``jax.nn.silu`` (Mosaic has no bf16 logistic)
+        return y * jax.nn.sigmoid(y.astype(jnp.float32)).astype(y.dtype)
     if name == "relu":
         return jax.nn.relu(y)
     assert name == "none", name
@@ -69,7 +70,7 @@ def _pad2(a: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
 def _fused_body(x_ref, wg_hbm, wi_hbm, wo_hbm, sg_ref, bg_ref, si_ref,
                 bi_ref, so_ref, bo_ref, o_ref, wg_s, wi_s, wo_s, sem1,
                 sem2, h_ref, *, bm, bn1, bk1, bn2, bk2, nf1, nk1, nf2, nk2,
-                activation, decode):
+                activation):
     """One M-tile: up (+gate) projection strip pipeline into ``h_ref``,
     activation, then down projection strip pipeline into ``o_ref``."""
     bkw1 = bk1 // K_PER_WORD
@@ -110,12 +111,11 @@ def _fused_body(x_ref, wg_hbm, wi_hbm, wo_hbm, sg_ref, bg_ref, si_ref,
         def ktile(t, accs):
             xt = x_ref[:, pl.ds(t * bk1, bk1)]
             acc_i, acc_g = accs
-            ti = _decode_tile(wi_s[cur, pl.ds(t * bkw1, bkw1)], dt, decode)
+            ti = _decode_tile(wi_s[cur, pl.ds(t * bkw1, bkw1)], dt)
             acc_i = acc_i + jnp.dot(xt, ti,
                                     preferred_element_type=jnp.float32)
             if gated:
-                tg = _decode_tile(wg_s[cur, pl.ds(t * bkw1, bkw1)], dt,
-                                  decode)
+                tg = _decode_tile(wg_s[cur, pl.ds(t * bkw1, bkw1)], dt)
                 acc_g = acc_g + jnp.dot(xt, tg,
                                         preferred_element_type=jnp.float32)
             return acc_i, acc_g
@@ -161,7 +161,7 @@ def _fused_body(x_ref, wg_hbm, wi_hbm, wo_hbm, sg_ref, bg_ref, si_ref,
 
         def ktile(t, acc):
             ht = h_ref[:, pl.ds(t * bk2, bk2)]
-            to = _decode_tile(wo_s[cur, pl.ds(t * bkw2, bkw2)], dt, decode)
+            to = _decode_tile(wo_s[cur, pl.ds(t * bkw2, bkw2)], dt)
             return acc + jnp.dot(ht, to,
                                  preferred_element_type=jnp.float32)
 
@@ -181,8 +181,7 @@ def _fused_body(x_ref, wg_hbm, wi_hbm, wo_hbm, sg_ref, bg_ref, si_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("n", "ff", "block_m", "block_n1", "block_k1",
-                     "block_n2", "block_k2", "activation", "interpret",
-                     "decode"),
+                     "block_n2", "block_k2", "activation", "interpret"),
 )
 def fused_mlp_pallas(
     x: jnp.ndarray,                     # (M, K) f32/bf16
@@ -205,7 +204,6 @@ def fused_mlp_pallas(
     block_k2: int = 256,
     activation: str = "silu",
     interpret: bool = False,
-    decode: str = "lut",
 ) -> jnp.ndarray:
     """Fused ``act(x @ Wg) * (x @ Wi) @ Wo`` (gate optional) — see module
     docstring. Returns the (M, n) logical output; ``h`` never leaves VMEM.
@@ -239,11 +237,11 @@ def fused_mlp_pallas(
     wo_p = _pad2(wo_packed[:, :n], k2p // K_PER_WORD, n2p)
 
     operands = [wi_p, wo_p]
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY)]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
     if wg_packed is not None:
         operands.append(_pad2(wg_packed[:, :ff], k1p // K_PER_WORD, ff1))
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
 
     def vec(v, width):
         return _pad2(v.reshape(1, -1), 1, width)
@@ -273,7 +271,7 @@ def fused_mlp_pallas(
                     eps[3], eps[4], eps[5], o_ref, wg_s, wi_s, wo_s, sem1,
                     sem2, h_ref, bm=bm, bn1=block_n1, bk1=block_k1,
                     bn2=block_n2, bk2=block_k2, nf1=nf1, nk1=nk1, nf2=nf2,
-                    nk2=nk2, activation=activation, decode=decode)
+                    nk2=nk2, activation=activation)
 
     scratch = []
     if gated:
@@ -298,7 +296,7 @@ def fused_mlp_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, n2p), x.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xp, *operands)
     return y[:m, :n]

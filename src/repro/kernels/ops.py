@@ -59,12 +59,13 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core import formats, weights
 from repro.kernels import ref
 from repro.obs import clock as obs_clock
 from repro.kernels import autotune as autotune_lib
-from repro.kernels.fused_mlp import ACTIVATIONS, fused_mlp_pallas
+from repro.kernels.fused_mlp import ACTIVATIONS, _act, fused_mlp_pallas
 from repro.kernels.ternary_gemm import (K_PER_WORD, ternary_gemm_pallas,
                                         ternary_gemm_skip_db_pallas,
                                         ternary_gemm_skip_pallas)
@@ -77,9 +78,10 @@ __all__ = ["ternary_gemm", "ternary_gemm_plan", "GemmPlan", "KernelImpl",
            "register_fused", "fused_registry", "precompute_fused_plans",
            "pack_weights", "pack_weights_tiled",
            "serving_phase", "current_phase", "SERVING_PHASES",
+           "tensor_parallel", "current_tp_mesh",
            "kernel_probe", "SKIP_OCCUPANCY_CUTOFF",
            "paged_decode_attention", "register_paged_attn",
-           "paged_attention_registry"]
+           "paged_attention_registry", "resolve_paged_attn"]
 
 # Serving-phase tag consumed at trace time: prefill GEMMs are M=B·L
 # GEMM-shaped, decode GEMMs are M=slots GEMV-shaped, verify GEMMs
@@ -108,6 +110,36 @@ def serving_phase(phase: Optional[str]):
 
 def current_phase() -> Optional[str]:
     return _SERVING_PHASE.get()
+
+
+# Tensor-parallel mesh consumed at trace time (DESIGN.md §13). A Pallas
+# kernel has no GSPMD partitioning rule, so model code traced for a mesh
+# runs its Pallas lowerings per shard under ``jax.shard_map``: GEMMs of
+# TP-placed weights (``TernaryWeight.tp_dim``) and paged attention over
+# head-sharded pages. XLA lowerings ("ref", the "jax" paged gather) stay
+# on GSPMD, which partitions them itself.
+_TP_MESH: contextvars.ContextVar[Optional[Any]] = \
+    contextvars.ContextVar("repro_tp_mesh", default=None)
+
+
+@contextlib.contextmanager
+def tensor_parallel(mesh):
+    """Trace model code in this scope for ``mesh``'s ``"model"`` axis (the
+    serving engine wraps its steps in this). ``mesh=None`` or a mesh
+    without a ``"model"`` axis wider than 1 leaves dispatch unchanged."""
+    token = _TP_MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _TP_MESH.reset(token)
+
+
+def current_tp_mesh():
+    """The ambient TP mesh, or None when its ``"model"`` axis is 1-wide."""
+    mesh = _TP_MESH.get()
+    if mesh is None or dict(mesh.shape).get("model", 1) <= 1:
+        return None
+    return mesh
 
 
 # Optional kernel timing probe (DESIGN.md §15): a callback receiving
@@ -367,7 +399,9 @@ class GemmPlan:
         """Roofline position of this plan on the modeled machine
         (``autotune.HBM_BW`` / ``autotune.PEAK_FLOPS``): achieved vs
         ceiling FLOP/s, arithmetic intensity, and remaining headroom.
-        Emitted per registered kernel by ``benchmarks/roofline.py``."""
+        Emitted per registered kernel by ``benchmarks/roofline.py``.
+        Raises on a TPU the modeled peaks do not describe."""
+        autotune_lib.check_modeled_device()
         t = self.traffic()
         ai = t["flops"] / max(t["bytes"], 1.0)
         ceiling = min(autotune_lib.PEAK_FLOPS, ai * autotune_lib.HBM_BW)
@@ -660,13 +694,14 @@ _PAGED_ATTN: Dict[str, PagedAttnImpl] = {}
 def register_paged_attn(impl: str, *, priority: int = 0,
                         predicate: Optional[Callable] = None):
     """Decorator registering a paged decode-attention lowering under
-    ``impl``. ``predicate(q, k_pages, v_pages, block_table, lengths)``
-    gates ``impl="auto"`` selection (highest admissible priority wins)."""
+    ``impl``. ``predicate()`` says whether the lowering is admissible on
+    the current backend; it gates ``impl="auto"`` selection (highest
+    admissible priority wins)."""
 
     def deco(fn):
         _PAGED_ATTN[impl] = PagedAttnImpl(
             impl=impl, priority=priority,
-            predicate=predicate or (lambda *a, **k: True), fn=fn)
+            predicate=predicate or (lambda: True), fn=fn)
         return fn
 
     return deco
@@ -695,6 +730,20 @@ def _ensure_paged_impls() -> None:
     import repro.paging.kernels  # noqa: F401
 
 
+def resolve_paged_attn(impl: str = "auto") -> str:
+    """The registered paged-attention lowering ``impl`` names: itself when
+    registered, or for ``"auto"`` the highest-priority one admissible on
+    this backend (the Pallas kernel on TPU, the jax gather elsewhere)."""
+    _ensure_paged_impls()
+    if impl == "auto":
+        cands = sorted(_PAGED_ATTN.values(), key=lambda pi: -pi.priority)
+        return next((pi for pi in cands if pi.predicate()), cands[-1]).impl
+    if impl not in _PAGED_ATTN:
+        raise ValueError(f"no paged-attention impl {impl!r} registered; "
+                         f"available: {sorted(_PAGED_ATTN)}")
+    return impl
+
+
 def paged_decode_attention(q, k_pages, v_pages, block_table, lengths, *,
                            window: int = 0, impl: str = "auto",
                            interpret: Optional[bool] = None):
@@ -703,20 +752,43 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, lengths, *,
     q (B, H, hd); k_pages/v_pages (P, ps, KV, hd) arrays or
     ``paging.quant.Int8Pages``; block_table (B, T) int32; lengths (B,)
     int32 valid-token counts (including the current token). ``impl`` picks
-    a registered lowering ("auto" = best admissible by priority)."""
-    _ensure_paged_impls()
-    if impl == "auto":
-        cands = sorted(_PAGED_ATTN.values(), key=lambda pi: -pi.priority)
-        chosen = next((pi for pi in cands
-                       if pi.predicate(q, k_pages, v_pages, block_table,
-                                       lengths)), cands[-1])
-    else:
-        chosen = _PAGED_ATTN.get(impl)
-        if chosen is None:
-            raise ValueError(f"no paged-attention impl {impl!r} registered; "
-                             f"available: {sorted(_PAGED_ATTN)}")
+    a registered lowering (``resolve_paged_attn``)."""
+    chosen = _PAGED_ATTN[resolve_paged_attn(impl)]
+    mesh = current_tp_mesh()
+    if mesh is not None and chosen.impl != "jax":
+        return _tp_paged_attention(mesh, chosen.fn, q, k_pages, v_pages,
+                                   block_table, lengths, window=window,
+                                   interpret=interpret)
     return chosen.fn(q, k_pages, v_pages, block_table, lengths,
                      window=window, interpret=interpret)
+
+
+def _tp_paged_attention(mesh, fn, q, k_pages, v_pages, block_table, lengths,
+                        *, window, interpret):
+    """A Pallas paged lowering per shard: q heads and the pages' KV-head
+    axis split over ``"model"`` (the layout ``distributed.tp.
+    cache_sharding`` places), block table and lengths replicated."""
+    ntp = dict(mesh.shape)["model"]
+    kv = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
+    if q.shape[1] % ntp or kv % ntp:
+        raise ValueError(
+            f"paged attention: {q.shape[1]} query / {kv} KV heads do not "
+            f"split {ntp} ways over the mesh's 'model' axis")
+
+    def page_spec(a):
+        # (P, ps, KV, hd) pages and (P, ps, KV) int8 scales
+        return P(None, None, "model", None) if a.ndim == 4 \
+            else P(None, None, "model")
+
+    heads = P(None, "model", None)
+    return jax.shard_map(
+        lambda qq, kk, vv, bt, ln: fn(qq, kk, vv, bt, ln, window=window,
+                                      interpret=interpret),
+        mesh=mesh,
+        in_specs=(heads, jax.tree.map(page_spec, k_pages),
+                  jax.tree.map(page_spec, v_pages), P(), P()),
+        out_specs=heads, check_vma=False)(
+            q, k_pages, v_pages, block_table, lengths)
 
 
 # ---------------------------------------------------------------------------
@@ -745,6 +817,17 @@ def _coerce_weight(w: Any, k: Optional[int],
         f"(got {type(w).__name__}); the DeprecationWarning shim was "
         f"removed after two release cycles. Pack into a typed container: "
         f"{hint}.")
+
+
+def _shard_view(w: weights.TernaryWeight, partition: Optional[str],
+                tp: int) -> weights.TernaryWeight:
+    """Static view of one ``tp``-way shard of ``w`` (leaves untouched): the
+    per-shard logical shape the shard's kernel plans and runs against."""
+    if partition is None:
+        return w
+    k, n = w.shape
+    return w.replace(shape=(k // tp, n) if partition == "k"
+                     else (k, n // tp))
 
 
 def _validate_k(w: weights.TernaryWeight, xk: int, k: Optional[int]) -> None:
@@ -841,7 +924,8 @@ def ternary_gemm_plan(
             avail = sorted(i for f, i in _KERNELS if f == fmt)
             raise ValueError(f"no impl {impl!r} registered for format "
                              f"{fmt!r}; available: {avail}")
-    bm, bn, bk = chosen.plan_blocks(w, m, phase, block_m, block_n, block_k)
+    bm, bn, bk = chosen.plan_blocks(_shard_view(w, partition, tp), m, phase,
+                                    block_m, block_n, block_k)
     if partition is not None:
         # per-shard tiles: clamp the global autotune blocks to the shard's
         # axis extent so the plan's tiling matches what one device runs
@@ -963,7 +1047,9 @@ class FusedMlpPlan:
         """Fused vs unfused roofline: the chain's HBM traffic (both GEMMs,
         plus the hidden activation's write + per-N-tile re-reads), the
         fused kernel's (x and each weight once per M tile, h never leaves
-        VMEM), and the modeled speedup ratio the CI bench gates on."""
+        VMEM), and the modeled speedup ratio the CI bench gates on. Raises
+        on a TPU the modeled peaks do not describe."""
+        autotune_lib.check_modeled_device()
         up, down = self.sub_plans()
         n_up = 2 if self.gated else 1
         unfused_bytes = n_up * up.traffic()["bytes"] \
@@ -1144,14 +1230,6 @@ def fused_mlp_plan(w_in: Any, w_out: Any, w_gate: Any = None, *,
         collective="psum" if tp > 1 else None, tp=tp)
 
 
-def _apply_act(name: str, y: jnp.ndarray) -> jnp.ndarray:
-    if name == "silu":
-        return jax.nn.silu(y)
-    if name == "relu":
-        return jax.nn.relu(y)
-    return y
-
-
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(10, 11, 12, 13, 14, 15, 16, 17, 18))
 def _fused_2bit(x, wi_p, wo_p, wg_p, si, bi, sg, bg, so, bo,
@@ -1194,9 +1272,9 @@ def _fused_2bit_bwd(n, ff, bm, bn1, bk1, bn2, bk2, activation, interpret,
             yg = epi(jnp.dot(d["x"], tg,
                              preferred_element_type=jnp.float32),
                      d.get("sg"), d.get("bg"))
-            h = _apply_act(activation, yg) * yi
+            h = _act(activation, yg) * yi
         else:
-            h = _apply_act(activation, yi)
+            h = _act(activation, yi)
         h = h.astype(x.dtype)
         return epi(jnp.dot(h, to, preferred_element_type=jnp.float32),
                    d.get("so"), d.get("bo")).astype(x.dtype)
@@ -1240,9 +1318,9 @@ def _lower_fused_chain(plan, x, w_in, w_out, w_gate):
     yi = ternary_gemm(x, w_in, interpret=plan.interpret)
     if w_gate is not None:
         yg = ternary_gemm(x, w_gate, interpret=plan.interpret)
-        h = _apply_act(plan.activation, yg) * yi
+        h = _act(plan.activation, yg) * yi
     else:
-        h = _apply_act(plan.activation, yi)
+        h = _act(plan.activation, yi)
     return ternary_gemm(h, w_out, interpret=plan.interpret)
 
 
@@ -1270,6 +1348,12 @@ def fused_mlp(x: jnp.ndarray, w_in: Any, w_out: Any, w_gate: Any = None,
     if x.shape[1] != w_in.k:
         raise ValueError(f"x has K={x.shape[1]} but the up projection "
                          f"encodes K={w_in.k}")
+    mesh = current_tp_mesh()
+    if mesh is not None and plan.impl == "pallas" and w_in.tp_dim == "n" \
+            and w_out.tp_dim == "k" \
+            and (w_gate is None or w_gate.tp_dim == "n"):
+        return _tp_fused(mesh, x, w_in, w_out, w_gate,
+                         activation=activation, interpret=plan.interpret)
     probe = _KERNEL_PROBE.get()
     if probe is not None and not isinstance(x, jax.core.Tracer):
         return _probe_dispatch(
@@ -1277,6 +1361,42 @@ def fused_mlp(x: jnp.ndarray, w_in: Any, w_out: Any, w_gate: Any = None,
             f"fused_mlp[{plan.impl} m={plan.m} k={plan.k} ff={plan.ff}]",
             lambda: _FUSED[plan.impl].fn(plan, x, w_in, w_out, w_gate))
     return _FUSED[plan.impl].fn(plan, x, w_in, w_out, w_gate)
+
+
+def _tp_fused(mesh, x, w_in, w_out, w_gate, *, activation, interpret):
+    """The Megatron MLP shard-locally: up/gate column split and down row
+    split over ``"model"`` keep the hidden activation local, so each shard
+    runs the fused kernel on its hidden slice and one f32 ``psum`` (plus
+    the down bias, once) finishes the block."""
+    ntp = dict(mesh.shape)["model"]
+    vec = P("model")
+    args, in_specs, locals_ = {"x": x}, {"x": P()}, {}
+    for name, w in (("in", w_in), ("out", w_out), ("gate", w_gate)):
+        if w is None:
+            continue
+        leaves, specs = _shard_leaves(w, w.tp_dim)
+        args[name], in_specs[name] = dict(leaves), dict(specs)
+        for v in ("scale", "bias"):
+            if getattr(w, v) is not None and not (name == "out"
+                                                  and v == "bias"):
+                args[name][v] = getattr(w, v)
+                in_specs[name][v] = vec if name != "out" else P()
+        locals_[name] = _shard_view(w, w.tp_dim, ntp).replace(
+            scale=None, bias=None, tp_dim=None,
+            nnz=w.nnz // ntp if w.nnz >= 0 else -1)
+
+    def shard(a):
+        ws = {name: locals_[name].replace(**a[name]) for name in locals_}
+        y = fused_mlp(a["x"], ws["in"], ws["out"], ws.get("gate"),
+                      activation=activation, impl="pallas",
+                      interpret=interpret)
+        return jax.lax.psum(y.astype(jnp.float32), "model").astype(y.dtype)
+
+    y = jax.shard_map(shard, mesh=mesh, in_specs=(in_specs,), out_specs=P(),
+                      check_vma=False)(args)
+    if w_out.bias is not None:
+        y = y + w_out.bias.reshape(1, -1).astype(y.dtype)
+    return y
 
 
 def precompute_fused_plans(params, *, prefill_ms=(), decode_ms=(),
@@ -1329,6 +1449,58 @@ def precompute_fused_plans(params, *, prefill_ms=(), decode_ms=(),
 # The public op
 # ---------------------------------------------------------------------------
 
+def _shard_leaves(w: weights.TernaryWeight, dim: str):
+    """(packed leaves, their PartitionSpecs) of a TP-placed container: the
+    (K-packed, N) word/plane arrays split on K (``dim="k"``) or N."""
+    if w.format_name not in ("dense2bit", "bitplane"):
+        raise NotImplementedError(
+            f"no per-shard rule for TP-placed {w.format_name} weights")
+    names = [f for f in w._leaves if f not in ("scale", "bias")
+             and getattr(w, f) is not None]
+    spec = P("model", None) if dim == "k" else P(None, "model")
+    return {f: getattr(w, f) for f in names}, {f: spec for f in names}
+
+
+def _tp_gemm(mesh, x, w, scale, bias, *, impl, fuse_prelu, prelu_alpha,
+             interpret):
+    """One TP-placed GEMM, run shard-locally (DESIGN.md §13). Column split
+    (``tp_dim="n"``): replicated x, local columns out, no collective. Row
+    split (``"k"``): K-sharded x, f32 ``psum`` of the partial products,
+    then bias and PReLU once on the reduced output."""
+    ntp = dict(mesh.shape)["model"]
+    col = w.tp_dim == "n"
+    leaves, specs = _shard_leaves(w, w.tp_dim)
+    local = _shard_view(w, w.tp_dim, ntp).replace(
+        scale=None, bias=None, tp_dim=None,
+        nnz=w.nnz // ntp if w.nnz >= 0 else -1)
+    vec = P("model") if col else P()
+    args = {"x": x, "w": leaves}
+    in_specs = {"x": P() if col else P(None, "model"), "w": specs}
+    if scale is not None:
+        args["scale"], in_specs["scale"] = scale, vec
+    if col and bias is not None:
+        args["bias"], in_specs["bias"] = bias, vec
+
+    def shard(a):
+        y = ternary_gemm(a["x"], local.replace(**a["w"]), a.get("scale"),
+                         a.get("bias"), impl=impl,
+                         fuse_prelu=fuse_prelu and col,
+                         prelu_alpha=prelu_alpha, interpret=interpret)
+        if col:
+            return y
+        return jax.lax.psum(y.astype(jnp.float32), "model").astype(y.dtype)
+
+    y = jax.shard_map(shard, mesh=mesh, in_specs=(in_specs,),
+                      out_specs=P(None, "model") if col else P(),
+                      check_vma=False)(args)
+    if not col:
+        if bias is not None:
+            y = y + bias.reshape(1, -1).astype(y.dtype)
+        if fuse_prelu:
+            y = jnp.where(y >= 0, y, jnp.asarray(prelu_alpha, y.dtype) * y)
+    return y
+
+
 def ternary_gemm(
     x: jnp.ndarray,
     w: Any,
@@ -1360,6 +1532,11 @@ def ternary_gemm(
         w, x.shape[0], impl=impl, block_m=block_m, block_n=block_n,
         block_k=block_k, fuse_prelu=fuse_prelu, prelu_alpha=prelu_alpha,
         interpret=interpret)
+    mesh = current_tp_mesh()
+    if mesh is not None and w.tp_dim is not None and plan.impl != "ref":
+        return _tp_gemm(mesh, x, w, scale, bias, impl=plan.impl,
+                        fuse_prelu=fuse_prelu, prelu_alpha=prelu_alpha,
+                        interpret=plan.interpret)
     probe = _KERNEL_PROBE.get()
     if probe is not None and not isinstance(x, jax.core.Tracer):
         return _probe_dispatch(

@@ -8,7 +8,7 @@ TPU adaptation of the paper's kernel (see DESIGN.md §2). The mapping:
   "confine irregular accesses to a cache window", except on TPU we remove the
   irregularity altogether and the window is the VMEM tile).
 * paper's structural sign encoding -> 2-bit codes (0,+1,-1) decoded with pure
-  VPU bit ops: v = (c & 1) - ((c >> 1) & 1). One pass, no ± branches -- the
+  VPU bit ops: v = (c & 1) - (c >> 1). One pass, no ± branches -- the
   interleaving insight expressed as data-parallel arithmetic.
 * paper's multi-accumulator unrolling -> f32 VMEM scratch accumulator carried
   across the K grid dimension, MXU `jnp.dot(..., preferred_element_type=f32)`.
@@ -21,10 +21,11 @@ Weight bandwidth is 2 bits/element = 16x less than f32 (8x less than bf16):
 on a memory-bound GEMM (the paper's own diagnosis of this workload) that is
 the roofline lever on TPU.
 
-Mosaic note: the decode uses a (bk/16, 16, bn) -> (bk, bn) sublane reshape;
-on real hardware a relayout may be inserted. Validated in interpret mode
-(this container is CPU-only); `ops.ternary_gemm` picks interpret
-automatically off the backend.
+Mosaic note: every vector op of the decode is 2-D and 32-bit (the TPU
+compiler refuses 3-D gathers and int8 arithmetic there), and the kernels
+compile at every block shape the autotuner can pick
+(tests/test_tpu_compile.py). On a TPU they run compiled; on other backends
+`ops.ternary_gemm` runs them in interpret mode, which the CPU tests use.
 """
 from __future__ import annotations
 
@@ -33,98 +34,50 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 WORD_BITS = 32
 K_PER_WORD = WORD_BITS // 2  # 16 ternary weights per uint32 word
-NIBBLES_PER_WORD = WORD_BITS // 4   # 8 nibbles = 8 codeword *pairs* / word
-
-# jax renamed TPUCompilerParams -> CompilerParams across versions; if a jax
-# exposes neither, fail at import (AttributeError naming pltpu), not at the
-# first kernel launch.
-CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                  or pltpu.TPUCompilerParams)
 
 __all__ = ["ternary_gemm_pallas", "ternary_gemm_skip_pallas",
-           "ternary_gemm_skip_db_pallas", "K_PER_WORD", "DECODE_MODES"]
-
-# Decode strategies for the 2-bit code words (DESIGN.md §12):
-#   "lut"   -- 16-entry lookup tables indexed by 4-bit nibble: one shift +
-#              two table reads decode a *pair* of codewords (8 shifts/word
-#              instead of 16 — the Litespark ternary-LUT trick).
-#   "shift" -- per-codeword shift/mask arithmetic (the original path, kept
-#              as the LUT oracle and the fallback for backends where a
-#              small-table gather lowers poorly).
-# Both produce identical int8 values, so kernel outputs are bitwise equal.
-DECODE_MODES = ("lut", "shift")
-
-# nibble -> decoded value of its low / high 2-bit codeword.
-# code c: 0 -> 0, 1 -> +1, 2 -> -1, 3 -> 0 (same map as (c&1) - ((c>>1)&1)).
-_CODE_VAL = np.array([0, 1, -1, 0], np.int8)
-NIBBLE_LUT_LO = np.asarray(_CODE_VAL[np.arange(16) & 3])      # (16,) int8
-NIBBLE_LUT_HI = np.asarray(_CODE_VAL[np.arange(16) >> 2])     # (16,) int8
+           "ternary_gemm_skip_db_pallas", "K_PER_WORD"]
 
 
-def _nibble_luts():
-    """The two 16-entry nibble tables, built *inside* the kernel trace.
+def _expand_rows(words: jnp.ndarray, reps: int) -> jnp.ndarray:
+    """(q, bn) int32 -> (q * reps, bn): row ``i`` repeated ``reps`` times.
 
-    Pallas rejects kernels that capture array constants, so the tables are
-    materialised from an iota each call — the compiler folds the 16-lane
-    arithmetic to the same constant vectors as ``NIBBLE_LUT_LO/HI``."""
-    idx = jax.lax.iota(jnp.int32, 16)
-    lut_lo = ((idx & 1) - ((idx >> 1) & 1)).astype(jnp.int8)
-    lut_hi = (((idx >> 2) & 1) - ((idx >> 3) & 1)).astype(jnp.int8)
-    return lut_lo, lut_hi
-
-
-def _decode_tile_shift(words: jnp.ndarray, out_dtype) -> jnp.ndarray:
-    """(bk/16, bn) uint32 -> (bk, bn) ±1/0 tile, pure VPU shift/mask ops."""
+    Built from 2-D sublane broadcasts and one sublane concatenation, so
+    Mosaic never sees a 3-D vector or a gather."""
     q, bn = words.shape
-    shifts = 2 * jax.lax.broadcasted_iota(jnp.uint32, (1, K_PER_WORD, 1), 1)
-    c = (words[:, None, :] >> shifts) & 3
-    vals = (c & 1).astype(jnp.int8) - ((c >> 1) & 1).astype(jnp.int8)
-    return vals.reshape(q * K_PER_WORD, bn).astype(out_dtype)
+    return jnp.concatenate(
+        [jnp.broadcast_to(words[i:i + 1], (reps, bn)) for i in range(q)],
+        axis=0)
 
 
-def _decode_tile_lut(words: jnp.ndarray, out_dtype) -> jnp.ndarray:
-    """(bk/16, bn) uint32 -> (bk, bn) ±1/0 tile via 16-entry nibble LUTs.
+def _decode_tile(words: jnp.ndarray, out_dtype) -> jnp.ndarray:
+    """(bk/16, bn) uint32 -> (bk, bn) ±1/0 tile.
 
-    Each 4-bit nibble holds two adjacent 2-bit codewords; two table reads
-    decode both at once. Value-identical to ``_decode_tile_shift`` (same
-    int8 outputs), so downstream matmuls are bitwise equal."""
-    q, bn = words.shape
-    shifts = 4 * jax.lax.broadcasted_iota(jnp.uint32, (1, NIBBLES_PER_WORD, 1),
-                                          1)
-    nib = ((words[:, None, :] >> shifts) & 0xF).astype(jnp.int32)
-    lut_lo, lut_hi = _nibble_luts()
-    lo = jnp.take(lut_lo, nib)            # codeword 2i   (q, 8, bn)
-    hi = jnp.take(lut_hi, nib)            # codeword 2i+1 (q, 8, bn)
-    pair = jnp.stack([lo, hi], axis=2)    # (q, 8, 2, bn): K-order restored
-    return pair.reshape(q * K_PER_WORD, bn).astype(out_dtype)
-
-
-def _decode_tile(words: jnp.ndarray, out_dtype,
-                 mode: str = "lut") -> jnp.ndarray:
-    """(bk/16, bn) uint32 -> (bk, bn) ±1/0 tile. ``mode`` in DECODE_MODES;
-    both modes are value-identical (pinned in tests/test_fused_mlp.py)."""
-    if mode == "lut":
-        return _decode_tile_lut(words, out_dtype)
-    assert mode == "shift", mode
-    return _decode_tile_shift(words, out_dtype)
+    Tile row ``r`` is codeword ``r % 16`` of word row ``r // 16``; code
+    ``c`` decodes to ``(c & 1) - (c >> 1)`` (0, +1, -1, 0). Every vector op
+    is 2-D and 32-bit; the one cast to the matmul dtype comes last."""
+    rep = _expand_rows(jax.lax.bitcast_convert_type(words, jnp.int32),
+                       K_PER_WORD)
+    shift = 2 * (jax.lax.broadcasted_iota(jnp.int32, rep.shape, 0)
+                 % K_PER_WORD)
+    c = jax.lax.shift_right_logical(rep, shift) & 3
+    return ((c & 1) - (c >> 1)).astype(jnp.float32).astype(out_dtype)
 
 
 def _kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, acc_ref, *,
-            nk: int, fuse_prelu: bool, prelu_alpha: float,
-            decode: str = "lut"):
+            nk: int, fuse_prelu: bool, prelu_alpha: float):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    t = _decode_tile(w_ref[...], x_ref.dtype, decode)
+    t = _decode_tile(w_ref[...], x_ref.dtype)
     acc_ref[...] += jnp.dot(x_ref[...], t,
                             preferred_element_type=jnp.float32)
 
@@ -143,7 +96,7 @@ def _kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, acc_ref, *,
 @functools.partial(
     jax.jit,
     static_argnames=("block_m", "block_n", "block_k", "fuse_prelu",
-                     "prelu_alpha", "interpret", "decode"),
+                     "prelu_alpha", "interpret"),
 )
 def ternary_gemm_pallas(
     x: jnp.ndarray,                    # (M, K)  f32/bf16, K % block_k == 0
@@ -157,7 +110,6 @@ def ternary_gemm_pallas(
     fuse_prelu: bool = False,
     prelu_alpha: float = 0.25,
     interpret: bool = False,
-    decode: str = "lut",
 ) -> jnp.ndarray:
     """Y = X @ decode(w_packed) * scale + bias (+ PReLU). Shapes must be
     pre-padded to block multiples -- `ops.ternary_gemm` handles padding."""
@@ -191,8 +143,7 @@ def ternary_gemm_pallas(
             b_ref = refs[idx]; idx += 1
         o_ref, acc_ref = refs[idx], refs[idx + 1]
         _kernel(x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref,
-                nk=nk, fuse_prelu=fuse_prelu, prelu_alpha=prelu_alpha,
-                decode=decode)
+                nk=nk, fuse_prelu=fuse_prelu, prelu_alpha=prelu_alpha)
 
     return pl.pallas_call(
         kernel,
@@ -201,7 +152,7 @@ def ternary_gemm_pallas(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -214,7 +165,7 @@ def ternary_gemm_pallas(
 
 def _skip_kernel(idx_ref, cnt_ref, x_ref, w_ref, scale_ref, bias_ref, o_ref,
                  acc_ref, *, max_occ: int, fuse_prelu: bool,
-                 prelu_alpha: float, decode: str = "lut"):
+                 prelu_alpha: float):
     j = pl.program_id(1)
     s = pl.program_id(2)
 
@@ -227,7 +178,7 @@ def _skip_kernel(idx_ref, cnt_ref, x_ref, w_ref, scale_ref, bias_ref, o_ref,
     # over occupied tiles in ascending K order.
     @pl.when(s < cnt_ref[j])
     def _body():
-        t = _decode_tile(w_ref[...], x_ref.dtype, decode)
+        t = _decode_tile(w_ref[...], x_ref.dtype)
         acc_ref[...] += jnp.dot(x_ref[...], t,
                                 preferred_element_type=jnp.float32)
 
@@ -246,7 +197,7 @@ def _skip_kernel(idx_ref, cnt_ref, x_ref, w_ref, scale_ref, bias_ref, o_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("block_m", "block_n", "block_k", "fuse_prelu",
-                     "prelu_alpha", "interpret", "decode"),
+                     "prelu_alpha", "interpret"),
 )
 def ternary_gemm_skip_pallas(
     x: jnp.ndarray,                    # (M, K) f32/bf16, pre-padded
@@ -262,7 +213,6 @@ def ternary_gemm_skip_pallas(
     fuse_prelu: bool = False,
     prelu_alpha: float = 0.25,
     interpret: bool = False,
-    decode: str = "lut",
 ) -> jnp.ndarray:
     """Tile-skipping ternary GEMM (DESIGN.md §3).
 
@@ -312,7 +262,7 @@ def ternary_gemm_skip_pallas(
         o_ref, acc_ref = refs[pos], refs[pos + 1]
         _skip_kernel(idx_ref, cnt_ref, x_ref, w_ref, s_ref, b_ref, o_ref,
                      acc_ref, max_occ=max_occ, fuse_prelu=fuse_prelu,
-                     prelu_alpha=prelu_alpha, decode=decode)
+                     prelu_alpha=prelu_alpha)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -326,7 +276,7 @@ def ternary_gemm_skip_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -341,7 +291,7 @@ def ternary_gemm_skip_pallas(
 def _skip_db_kernel(idx_ref, cnt_ref, x_hbm, w_hbm, scale_ref, bias_ref,
                     o_ref, xs, ws, sem, acc_ref, *, block_m: int,
                     block_n: int, block_k: int, fuse_prelu: bool,
-                    prelu_alpha: float, decode: str):
+                    prelu_alpha: float):
     """Grid is (M-tiles, N-tiles); the occupied-K-tile walk happens *inside*
     the kernel as an explicit two-slot ``make_async_copy`` pipeline: while
     tile ``s`` is decoded and matmul'd out of slot ``s % 2``, tile ``s + 1``
@@ -388,7 +338,7 @@ def _skip_db_kernel(idx_ref, cnt_ref, x_hbm, w_hbm, scale_ref, bias_ref,
                 start(jax.lax.rem(s + 1, 2), s + 1)
 
             wait(cur, s)
-            t = _decode_tile(ws[cur], xs.dtype, decode)
+            t = _decode_tile(ws[cur], xs.dtype)
             acc_ref[...] += jnp.dot(xs[cur], t,
                                     preferred_element_type=jnp.float32)
             return 0
@@ -408,7 +358,7 @@ def _skip_db_kernel(idx_ref, cnt_ref, x_hbm, w_hbm, scale_ref, bias_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("block_m", "block_n", "block_k", "fuse_prelu",
-                     "prelu_alpha", "interpret", "decode"),
+                     "prelu_alpha", "interpret"),
 )
 def ternary_gemm_skip_db_pallas(
     x: jnp.ndarray,                    # (M, K) f32/bf16, pre-padded
@@ -424,7 +374,6 @@ def ternary_gemm_skip_db_pallas(
     fuse_prelu: bool = False,
     prelu_alpha: float = 0.25,
     interpret: bool = False,
-    decode: str = "lut",
 ) -> jnp.ndarray:
     """Tile-skipping ternary GEMM with an explicit double-buffered DMA
     pipeline (DESIGN.md §12).
@@ -451,8 +400,8 @@ def ternary_gemm_skip_db_pallas(
 
     # x / packed words stay in HBM; only scale/bias (tiny) are block-fed.
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [x, w_packed]
     if scale is not None:
@@ -477,8 +426,7 @@ def ternary_gemm_skip_db_pallas(
         _skip_db_kernel(idx_ref, cnt_ref, x_hbm, w_hbm, s_ref, b_ref, o_ref,
                         xs, ws, sem, acc_ref, block_m=block_m,
                         block_n=block_n, block_k=block_k,
-                        fuse_prelu=fuse_prelu, prelu_alpha=prelu_alpha,
-                        decode=decode)
+                        fuse_prelu=fuse_prelu, prelu_alpha=prelu_alpha)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -497,7 +445,7 @@ def ternary_gemm_skip_db_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

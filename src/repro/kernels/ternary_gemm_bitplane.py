@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ternary_gemm import CompilerParams
+from repro.kernels.ternary_gemm import _expand_rows
 
 K_PER_BYTE = 8
 
@@ -33,21 +33,20 @@ __all__ = ["ternary_gemm_bitplane"]
 
 
 def _unpack_plane(plane, out_dtype):
-    """(bk/8, bn) uint8 plane -> (bk, bn) 0/1 tile (no sign combine)."""
-    q, bn = plane.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (1, K_PER_BYTE, 1), 1)
-    bits = (plane[:, None, :] >> shifts) & 1
-    return bits.reshape(q * K_PER_BYTE, bn).astype(out_dtype)
+    """(bk/8, bn) uint8 plane -> (bk, bn) 0/1 tile (no sign combine).
+
+    Tile row ``r`` is bit ``r % 8`` of byte row ``r // 8``; the bytes widen
+    to int32 first, so every vector op is 2-D and 32-bit."""
+    rep = _expand_rows(plane.astype(jnp.int32), K_PER_BYTE)
+    shift = jax.lax.broadcasted_iota(jnp.int32, rep.shape, 0) % K_PER_BYTE
+    return (jax.lax.shift_right_logical(rep, shift) & 1).astype(
+        jnp.float32).astype(out_dtype)
 
 
 def _decode_planes(plus, minus, out_dtype):
     """(bk/8, bn) uint8 planes -> (bk, bn) ±1/0 tile."""
-    q, bn = plus.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (1, K_PER_BYTE, 1), 1)
-    p = (plus[:, None, :] >> shifts) & 1
-    m = (minus[:, None, :] >> shifts) & 1
-    vals = p.astype(jnp.int8) - m.astype(jnp.int8)
-    return vals.reshape(q * K_PER_BYTE, bn).astype(out_dtype)
+    return (_unpack_plane(plus, jnp.float32)
+            - _unpack_plane(minus, jnp.float32)).astype(out_dtype)
 
 
 def _kernel(x_ref, p_ref, m_ref, scale_ref, o_ref, acc_ref, *, nk: int,
@@ -135,7 +134,7 @@ def ternary_gemm_bitplane(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -47,7 +47,9 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
+import os
 import sys
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -91,6 +93,55 @@ class BatchedServer:
             logits, cache = self._decode(self.params, cache, tok)
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return np.concatenate(out, axis=1)
+
+
+# ---------------------------------------------------------------------------
+# Set-up shared with chip_smoke.py
+# ---------------------------------------------------------------------------
+
+CHECKOUT = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                        "..", "..", ".."))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point: the
+    directory ``$JAX_COMPILATION_CACHE_DIR`` names when set (JAX reads it
+    itself), else the fixed, gitignored ``<checkout>/.jax_cache`` — a fixed
+    path, so a later run finds what an earlier one compiled. Called by
+    entry points only, never on import. Returns the directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def load_model(arch: str, *, reduced: bool = False, packed: bool = False,
+               ternary_min_dim: int = 0, seed: int = 0, **overrides):
+    """(cfg, params) for serving: random weights from ``seed``; with
+    ``packed`` every ternarizable projection is packed into the 2-bit
+    ``TernaryWeight`` serving format (cfg switches to
+    ``quantization="ternary_packed"`` when anything was packed)."""
+    if ternary_min_dim > 0:
+        overrides["ternary_min_dim"] = ternary_min_dim
+    cfg = get_config(arch, reduced=reduced, **overrides)
+    params = LM(cfg).init(jax.random.PRNGKey(seed))
+    if packed:
+        from repro.core import weights
+        from repro.models import layers as L
+        params = L.pack_params(params, cfg)
+        n_packed = sum(isinstance(w, weights.TernaryWeight)
+                       for w in jax.tree_util.tree_leaves(
+                           params, is_leaf=lambda v: isinstance(
+                               v, weights.TernaryWeight)))
+        if n_packed:
+            cfg = dataclasses.replace(cfg, quantization="ternary_packed")
+        else:
+            print(f"warning: --packed converted nothing (quantization="
+                  f"{cfg.quantization!r}, no projection meets "
+                  f"ternary_min_dim={cfg.ternary_min_dim}); serving the "
+                  f"dense model", file=sys.stderr)
+    return cfg, params
 
 
 # ---------------------------------------------------------------------------
@@ -300,9 +351,11 @@ def main(argv: Optional[Sequence[str]] = None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    overrides = ({"ternary_min_dim": args.ternary_min_dim}
-                 if args.ternary_min_dim > 0 else {})
-    cfg = get_config(args.arch, reduced=args.reduced, **overrides)
+    enable_compile_cache()
+    cfg, params = load_model(args.arch, reduced=args.reduced,
+                             packed=args.packed,
+                             ternary_min_dim=args.ternary_min_dim,
+                             seed=args.seed)
     gen_lens = [int(g) for g in args.gen_lens.split(",")]
     spec_headroom = args.spec_k if args.spec != "off" else 0
     max_len = args.max_len or (args.prompt_len + max(gen_lens) + 1
@@ -310,24 +363,6 @@ def main(argv: Optional[Sequence[str]] = None):
     prompts, gens, extras = build_workload(cfg, args.requests,
                                            args.prompt_len, gen_lens,
                                            seed=args.seed)
-
-    params = LM(cfg).init(jax.random.PRNGKey(args.seed))
-    if args.packed:
-        import dataclasses
-        from repro.core import weights
-        from repro.models import layers as L
-        params = L.pack_params(params, cfg)
-        n_packed = sum(isinstance(w, weights.TernaryWeight)
-                       for w in jax.tree_util.tree_leaves(
-                           params, is_leaf=lambda v: isinstance(
-                               v, weights.TernaryWeight)))
-        if n_packed:
-            cfg = dataclasses.replace(cfg, quantization="ternary_packed")
-        else:
-            print(f"warning: --packed converted nothing (quantization="
-                  f"{cfg.quantization!r}, no projection meets "
-                  f"ternary_min_dim={cfg.ternary_min_dim}); serving the "
-                  f"dense model", file=sys.stderr)
 
     tracer = None
     if args.trace:

@@ -43,7 +43,6 @@ def make_compressed_dp_step(model: LM, cfg: ModelConfig, mesh, lr_fn):
     ternary codes + scales with error feedback, the optimizer update is
     replicated. The paper's {-1,0,+1} value system applied to the comm
     layer."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import compression
@@ -71,10 +70,10 @@ def make_compressed_dp_step(model: LM, cfg: ModelConfig, mesh, lr_fn):
 
     def step(params, opt_state, err, batch):
         bs = {k: P(axes[-1]) for k in batch}
-        f = shard_map(local_step, mesh=mesh,
-                      in_specs=(rep, rep, rep, bs),
-                      out_specs=(rep, rep, rep, rep),
-                      check_rep=False)
+        f = jax.shard_map(local_step, mesh=mesh,
+                          in_specs=(rep, rep, rep, bs),
+                          out_specs=(rep, rep, rep, rep),
+                          check_vma=False)
         return f(params, opt_state, err, batch)
 
     return step, opt_init

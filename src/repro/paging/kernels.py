@@ -16,12 +16,14 @@ Three registered lowerings (``kernels.ops.register_paged_attn``):
 * ``pallas`` — ``PrefetchScalarGridSpec`` kernel: the block table and
   per-row lengths ride in as scalar-prefetch operands so the page grid
   dimension's BlockSpec index maps DMA exactly the pages the row owns
-  (same steering mechanism as the tile-skipping GEMM, DESIGN.md §3). Pages
-  are staged (and int8-dequantized) into VMEM scratch; the final grid step
-  runs the row's attention from VMEM. Bit-exact against ``..._ref``.
-* the pure-JAX **reference** (``paged_decode_attention_ref``) mirrors the
-  kernel's per-row compute (same ``_attend_one_row`` function, same casts)
-  so kernel-vs-reference comparisons are bitwise, not approximate.
+  (same steering mechanism as the tile-skipping GEMM, DESIGN.md §3).
+  Pages stream through an online softmax: running max, sum and output
+  accumulator live in ``(heads, hd)`` f32 VMEM scratch, so VMEM use does
+  not grow with ``max_len``. Pages past a row's length re-point the DMA
+  at its last valid page (no new copy) and skip their compute.
+* the pure-JAX **reference** (``paged_decode_attention_ref``) runs the
+  same ``_page_step`` over the same pages in the same order, so
+  kernel-vs-reference comparisons are bitwise, not approximate.
 
 All three accept bf16 page arrays or ``quant.Int8Pages`` containers
 (per-page scales dequantized after the gather — inside the kernel for the
@@ -39,7 +41,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ops import register_paged_attn
-from repro.kernels.ternary_gemm import CompilerParams
 from repro.paging.quant import Int8Pages, dequantize_rows
 
 NEG_INF = -1e30
@@ -57,30 +58,52 @@ def _page_geometry(pages: Pages):
     return shape
 
 
-def _attend_one_row(q, k, v, *, kv_heads: int, length, window: int):
-    """One row's decode attention, f32 in/out.
+def _page_step(q, k, v, m, l, acc, *, kv_heads: int, pos0, length,
+               window: int):
+    """One page of the online-softmax decode attention, f32 throughout.
 
-    q (H, hd); k/v (S, KV, hd); ``length`` = valid tokens (traced scalar,
-    includes the current token, whose position is ``length - 1``).
-    Shared verbatim between the Pallas kernel body and the pure-JAX
-    reference so the two are bit-exact by construction.
-    """
+    q (H, hd); k/v (ps, KV, hd) one page; m/l/acc (H, hd) the running max,
+    sum (both lane-replicated over hd) and output accumulator; ``pos0`` is
+    the page's first token position and ``length`` the row's valid-token
+    count (the current token sits at ``length - 1``). Shared verbatim by
+    the Pallas kernel body and the pure-JAX reference so the two are
+    bit-exact by construction. Masked keys contribute exact zeros, so a
+    page with no valid key leaves (m, l, acc) unchanged."""
     h, hd = q.shape
-    s_len = k.shape[0]
+    ps = k.shape[0]
     g = h // kv_heads
-    qg = q.reshape(kv_heads, g, hd)
-    scores = jnp.einsum("kgd,skd->kgs", qg, k,
-                        preferred_element_type=jnp.float32) \
+    s = jnp.einsum("kgd,skd->kgs", q.reshape(kv_heads, g, hd), k,
+                   preferred_element_type=jnp.float32).reshape(h, ps) \
         * (1.0 / math.sqrt(hd))
-    k_pos = jnp.arange(s_len)
-    mask = k_pos < length
+    k_pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (h, ps), 1)
+    valid = k_pos < length
     if window:
-        mask &= (length - 1 - k_pos) < window
-    scores = jnp.where(mask[None, None, :], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("kgs,skd->kgd", p, v,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(h, hd)
+        valid &= (length - 1 - k_pos) < window
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev, l_prev = m[:, :1], l[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jnp.einsum("kgs,skd->kgd", p.reshape(kv_heads, g, ps), v,
+                    preferred_element_type=jnp.float32).reshape(h, hd)
+    return (jnp.broadcast_to(m_new, (h, hd)),
+            jnp.broadcast_to(l_new, (h, hd)), alpha * acc + pv)
+
+
+def _page_live(t, ps: int, length, window: int):
+    """Whether page ``t`` of a row holds any key the query attends."""
+    live = t * ps < length
+    if window:
+        live &= (t + 1) * ps > length - window
+    return live
+
+
+def _load_page(pages, dtype=jnp.float32):
+    """One (ps, KV, hd) page (or its Int8 codes + scales) as f32."""
+    if isinstance(pages, tuple):
+        return dequantize_rows(pages[0], pages[1], dtype)
+    return pages.astype(dtype)
 
 
 def _gather(pages: Pages, block_table: jnp.ndarray, dtype) -> jnp.ndarray:
@@ -134,22 +157,35 @@ def paged_decode_attention_jax(q, k_pages: Pages, v_pages: Pages,
     return o.reshape(b, 1, h, hd)[:, 0].astype(q.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("window",))
 def paged_decode_attention_ref(q, k_pages: Pages, v_pages: Pages,
                                block_table, lengths, *, window: int = 0):
-    """Bit-exact mirror of the Pallas kernel: per-row gather into an f32
-    staging buffer, then the *same* ``_attend_one_row``. Reference only —
-    O(B) python loop, used by tests to pin the kernel bitwise."""
-    b = q.shape[0]
+    """Bit-exact mirror of the Pallas kernel: per row, the same
+    ``_page_step`` over the row's pages in block-table order, compiled as
+    one program like the interpret-mode kernel (op-by-op dispatch rounds
+    differently). Reference only — the B·T loop unrolls into the trace."""
+    b, h, hd = q.shape
+    _, ps, kv, _ = _page_geometry(k_pages)
+
+    def page(pages, pid):
+        if isinstance(pages, Int8Pages):
+            return _load_page((pages.codes[pid], pages.scales[pid]))
+        return _load_page(pages[pid])
+
     outs = []
     for i in range(b):
-        ks = _gather(k_pages, block_table[i][None],
-                     jnp.float32)[0].astype(jnp.float32)
-        vs = _gather(v_pages, block_table[i][None],
-                     jnp.float32)[0].astype(jnp.float32)
-        o = _attend_one_row(q[i].astype(jnp.float32), ks, vs,
-                            kv_heads=ks.shape[1], length=lengths[i],
-                            window=window)
-        outs.append(o.astype(q.dtype))
+        qi = q[i].astype(jnp.float32)
+        m = jnp.full((h, hd), NEG_INF, jnp.float32)
+        l = jnp.zeros((h, hd), jnp.float32)
+        acc = jnp.zeros((h, hd), jnp.float32)
+        length = jnp.asarray(lengths[i], jnp.int32)
+        for t in range(block_table.shape[1]):
+            pid = block_table[i, t]
+            m, l, acc = _page_step(
+                qi, page(k_pages, pid), page(v_pages, pid), m, l, acc,
+                kv_heads=kv, pos0=jnp.int32(t * ps), length=length,
+                window=window)
+        outs.append((acc / l).astype(q.dtype))
     return jnp.stack(outs)
 
 
@@ -157,31 +193,42 @@ def paged_decode_attention_ref(q, k_pages: Pages, v_pages: Pages,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _kernel(bt_ref, len_ref, q_ref, *refs, n_pages_seq: int, page_size: int,
-            kv_heads: int, window: int, quantized: bool):
+def _kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, kv_heads: int,
+            window: int, quantized: bool):
     b = pl.program_id(0)
     t = pl.program_id(1)
     if quantized:
         kc_ref, ks_ref, vc_ref, vs_ref = refs[:4]
-        o_ref, k_scr, v_scr = refs[4:]
-        k_page = dequantize_rows(kc_ref[0], ks_ref[0], jnp.float32)
-        v_page = dequantize_rows(vc_ref[0], vs_ref[0], jnp.float32)
+        o_ref, m_scr, l_scr, acc_scr = refs[4:]
     else:
         k_ref, v_ref = refs[:2]
-        o_ref, k_scr, v_scr = refs[2:]
-        k_page = k_ref[0].astype(jnp.float32)
-        v_page = v_ref[0].astype(jnp.float32)
-    # stage this row's t-th page into the VMEM sequence buffer
-    idx = (pl.dslice(t * page_size, page_size), slice(None), slice(None))
-    pl.store(k_scr, idx, k_page)
-    pl.store(v_scr, idx, v_page)
+        o_ref, m_scr, l_scr, acc_scr = refs[2:]
+    length = len_ref[b]
 
-    @pl.when(t == n_pages_seq - 1)
-    def _attend():
-        o = _attend_one_row(q_ref[0].astype(jnp.float32), k_scr[...],
-                            v_scr[...], kv_heads=kv_heads,
-                            length=len_ref[b], window=window)
-        o_ref[0] = o.astype(o_ref.dtype)
+    @pl.when(t == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(_page_live(t, page_size, length, window))
+    def _step():
+        if quantized:
+            k = _load_page((kc_ref[0], ks_ref[0]))
+            v = _load_page((vc_ref[0], vs_ref[0]))
+        else:
+            k, v = _load_page(k_ref[0]), _load_page(v_ref[0])
+        m, l, acc = _page_step(
+            q_ref[0].astype(jnp.float32), k, v, m_scr[...], l_scr[...],
+            acc_scr[...], kv_heads=kv_heads, pos0=t * page_size,
+            length=length, window=window)
+        m_scr[...] = m
+        l_scr[...] = l
+        acc_scr[...] = acc
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -200,10 +247,16 @@ def paged_decode_attention_pallas(q, k_pages: Pages, v_pages: Pages,
     t = block_table.shape[1]
     quantized = isinstance(k_pages, Int8Pages)
 
-    page_spec = pl.BlockSpec((1, ps, kv, hd),
-                             lambda i, j, bt, ln: (bt[i, j], 0, 0, 0))
-    scale_spec = pl.BlockSpec((1, ps, kv),
-                              lambda i, j, bt, ln: (bt[i, j], 0, 0))
+    def page_index(i, j, bt, ln):
+        # pages past the row's last valid one repeat that page's block
+        # index, so the pipeline issues no new DMA for them
+        return bt[i, jnp.minimum(j, jnp.maximum(ln[i] - 1, 0) // ps)]
+
+    page_spec = pl.BlockSpec(
+        (1, ps, kv, hd), lambda i, j, bt, ln: (page_index(i, j, bt, ln),
+                                               0, 0, 0))
+    scale_spec = pl.BlockSpec(
+        (1, ps, kv), lambda i, j, bt, ln: (page_index(i, j, bt, ln), 0, 0))
     in_specs = [pl.BlockSpec((1, h, hd), lambda i, j, bt, ln: (i, 0, 0))]
     if quantized:
         in_specs += [page_spec, scale_spec, page_spec, scale_spec]
@@ -218,15 +271,14 @@ def paged_decode_attention_pallas(q, k_pages: Pages, v_pages: Pages,
         grid=(b, t),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, h, hd), lambda i, j, bt, ln: (i, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((t * ps, kv, hd), jnp.float32),
-                        pltpu.VMEM((t * ps, kv, hd), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((h, hd), jnp.float32)] * 3,
     )
     return pl.pallas_call(
-        functools.partial(_kernel, n_pages_seq=t, page_size=ps, kv_heads=kv,
-                          window=window, quantized=quantized),
+        functools.partial(_kernel, page_size=ps, kv_heads=kv, window=window,
+                          quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
@@ -236,5 +288,5 @@ def paged_decode_attention_pallas(q, k_pages: Pages, v_pages: Pages,
 # registered lowering: the kernel wants explicit interpret resolution
 register_paged_attn(
     "pallas", priority=20,
-    predicate=lambda *a, **k: jax.default_backend() == "tpu",
+    predicate=lambda: jax.default_backend() == "tpu",
 )(paged_decode_attention_pallas)

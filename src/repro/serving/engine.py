@@ -94,6 +94,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels import autotune as autotune_lib
 from repro.kernels import ops as kops
 from repro.models import LM
 from repro.obs import clock as obs_clock
@@ -134,10 +135,11 @@ class ContinuousScheduler:
                 "state; use the static BatchedServer for it")
         assert cache in ("dense", "paged"), cache
         # paged_attn=None inherits cfg.paged_attn_impl; an explicit value
-        # overrides it for this engine only
-        if cache == "paged" and paged_attn is not None \
-                and paged_attn != cfg.paged_attn_impl:
-            cfg = dataclasses.replace(cfg, paged_attn_impl=paged_attn)
+        # overrides it for this engine only. "auto" resolves here, once, to
+        # the lowering this backend runs (visible on engine.cfg)
+        if cache == "paged":
+            impl = kops.resolve_paged_attn(paged_attn or cfg.paged_attn_impl)
+            cfg = dataclasses.replace(cfg, paged_attn_impl=impl)
         self.cfg = cfg
         self.cache_mode = cache
         # every ad-hoc `self.x = 0; self.x += 1` counter below is
@@ -408,7 +410,9 @@ class ContinuousScheduler:
                 params, prefill_ms=prefill_ms, decode_ms=(self.max_slots,),
                 verify_ms=((self.max_slots * (self.spec.k + 1),)
                            if self.spec else ()),
-                chunk_ms=chunk_ms)
+                chunk_ms=chunk_ms,
+                tp=(1 if self.mesh is None else
+                    dict(self.mesh.shape).get("model", 1)))
         else:
             self.fused_plans = {}
         if self.spec is not None:
@@ -463,16 +467,20 @@ class ContinuousScheduler:
             smax = min(self.sched.budget_for(
                 self.max_slots, self.spec.k if self.spec else 0),
                 self.max_len)
-            self._chunker.warmup(
-                self.params, self.pool,
-                [1 << i for i in range(smax.bit_length())])
+            with kops.tensor_parallel(self.mesh):
+                self._chunker.warmup(
+                    self.params, self.pool,
+                    [1 << i for i in range(smax.bit_length())])
         # per-(phase, M-bucket) modeled roofline aggregates over the
         # warmed plans — attached to this engine's measured kernel-phase
         # trace spans so a trace carries measured-vs-modeled utilization
         # side by side (DESIGN.md §15)
+        # (none where the modeled v5e peaks do not describe the chip)
         self._phase_model: Dict[tuple, Dict[str, float]] = {}
         self._modeled_memo: Dict[tuple, Optional[Dict[str, float]]] = {}
-        for key, plan in self.gemm_plans.items():
+        modeled = (self.gemm_plans.items() if autotune_lib.describes_device()
+                   else ())
+        for key, plan in modeled:
             if key[0] == "draft":
                 continue
             _, m, phase = key
@@ -989,7 +997,12 @@ class ContinuousScheduler:
         """One scheduler iteration: inject scheduled faults, expire
         deadlines, admit (+ prefill, or advance chunked prefills), decode
         (or the spec draft -> verify -> rollback round) under the
-        numerical guard, evict/quarantine."""
+        numerical guard, evict/quarantine. Model code traced here runs
+        its Pallas kernels per shard of this engine's mesh."""
+        with kops.tensor_parallel(self.mesh):
+            self._step()
+
+    def _step(self) -> None:
         self._step_no += 1
         t_step = obs_clock.now()
         faults = self._plan_faults()

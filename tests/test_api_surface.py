@@ -45,7 +45,7 @@ GOLDEN = {
         "fused_mlp_pallas",
         "pack_weights", "pack_weights_tiled",
         "ternary_gemm_pallas", "ternary_gemm_skip_pallas",
-        "ternary_gemm_skip_db_pallas", "DECODE_MODES",
+        "ternary_gemm_skip_db_pallas",
         "ternary_gemm_bitplane", "K_PER_WORD", "flash_attention_pallas",
         "paged_decode_attention", "register_paged_attn",
         "paged_attention_registry",

@@ -162,7 +162,8 @@ from repro.launch import steps as steps_lib
 from repro.distributed import sharding as shlib
 from repro.data import SyntheticLM
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_config("mixtral-8x22b", reduced=True, num_experts=4,
                  d_model=64, d_ff_expert=64, vocab_size=512, grad_accum=2)
 set_mesh(mesh)
@@ -192,10 +193,10 @@ def test_compressed_psum_shard_map():
     gradient approximates the true mean across the data axis."""
     res = _run_sub("""
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.distributed import compression
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 g_all = jnp.asarray(rng.standard_normal((8, 4096)), jnp.float32)
 
@@ -203,9 +204,9 @@ def sync(g_local, err):
     g, e = compression.compressed_psum({"g": g_local[0]}, {"g": err[0]}, "data")
     return g["g"][None], e["g"][None]
 
-f = shard_map(sync, mesh=mesh,
-              in_specs=(P("data", None), P("data", None)),
-              out_specs=(P("data", None), P("data", None)))
+f = jax.shard_map(sync, mesh=mesh,
+                  in_specs=(P("data", None), P("data", None)),
+                  out_specs=(P("data", None), P("data", None)))
 err = jnp.zeros((8, 4096))
 true_mean = jnp.mean(g_all, axis=0)
 # one round: coarse; with error feedback over rounds the bias shrinks
@@ -227,7 +228,8 @@ def test_dryrun_cell_multipod_small():
     res = _run_sub("""
 os.environ["REPRO_DRYRUN_DEVICES"] = "8"
 from repro.launch import dryrun
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 rec = dryrun.run_cell("granite-3-8b", "train_4k", mesh=mesh, reduced=True,
                       overrides={"grad_accum": 2})
 print(json.dumps({"status": rec["status"],

@@ -1,14 +1,13 @@
-"""Fused ternary kernel pass (DESIGN.md §12): LUT decode, double-buffered
+"""Fused ternary kernel pass (DESIGN.md §12): double-buffered
 tile-skipping, the fused MLP lowering, fusion autotune keys, rooflines.
 
 Every equality here is *bitwise* (``np.array_equal``), not allclose — the
-fused/LUT/double-buffered paths are pure scheduling changes over the same
+fused/double-buffered paths are pure scheduling changes over the same
 f32 accumulation order, so exact equality is the contract the registry
 relies on to dispatch them transparently.
 """
 from __future__ import annotations
 
-import importlib
 import os
 import tempfile
 
@@ -20,10 +19,6 @@ import pytest
 from repro.core import formats, weights
 from repro.kernels import ops
 from repro.kernels.autotune import Autotuner, BlockConfig, FusedBlockConfig
-
-# the package __init__ re-exports the ternary_gemm *function*, shadowing
-# the submodule attribute — import the kernel module explicitly
-tg = importlib.import_module("repro.kernels.ternary_gemm")
 
 
 def _rt(rng, k, n, density=0.25):
@@ -43,48 +38,6 @@ def _mlp_weights(fmt, k=256, ff=384, n=128, *, scale=True, bias=True,
         return weights.pack(w, fmt, scale=sc, bias=b, **kw)
 
     return pk(_rt(rng, k, ff)), pk(_rt(rng, ff, n)), pk(_rt(rng, k, ff))
-
-
-# ---------------------------------------------------------------------------
-# LUT decode == shift/mask decode, bitwise
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_lut_decode_bit_exact_dense(dtype):
-    rng = np.random.default_rng(0)
-    m, k, n = 16, 256, 128
-    packed = jnp.asarray(formats.pack_2bit(_rt(rng, k, n)))
-    scale = jnp.asarray(np.abs(rng.standard_normal(n)) + 0.5, jnp.float32)
-    bias = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    x = jnp.asarray(rng.standard_normal((m, k)), dtype)
-    kw = dict(block_m=16, block_n=64, block_k=64, interpret=True,
-              fuse_prelu=True, prelu_alpha=0.1)
-    y_lut = tg.ternary_gemm_pallas(x, packed, scale, bias, decode="lut", **kw)
-    y_shift = tg.ternary_gemm_pallas(x, packed, scale, bias, decode="shift",
-                                     **kw)
-    assert y_lut.dtype == x.dtype
-    assert np.array_equal(np.asarray(y_lut), np.asarray(y_shift))
-
-
-def test_lut_decode_bit_exact_skip():
-    rng = np.random.default_rng(1)
-    m, k, n = 16, 256, 128
-    w = formats.random_tile_ternary(rng, k, n, 64, 32, 0.125)
-    wc = weights.pack(w, "tiled", tile_k=64, tile_n=32)
-    x = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
-    kw = dict(block_m=16, block_n=32, block_k=64, interpret=True)
-    ys = [tg.ternary_gemm_skip_pallas(x, wc.packed, wc.kt_indices,
-                                      wc.kt_counts, decode=d, **kw)
-          for d in tg.DECODE_MODES]
-    assert np.array_equal(np.asarray(ys[0]), np.asarray(ys[1]))
-
-
-def test_nibble_lut_tables_match_code_map():
-    # lo nibble decodes codes (n & 3), hi nibble (n >> 2): 0,+1,-1,0
-    lo, hi = np.asarray(tg.NIBBLE_LUT_LO), np.asarray(tg.NIBBLE_LUT_HI)
-    for nib in range(16):
-        assert lo[nib] == tg._CODE_VAL[nib & 3]
-        assert hi[nib] == tg._CODE_VAL[nib >> 2]
 
 
 # ---------------------------------------------------------------------------

@@ -122,10 +122,8 @@ def test_paged_attn_registry_dispatch():
                                     impl="nope")
     # off-TPU, auto must resolve to the dense-bit-identical jax lowering
     if jax.default_backend() != "tpu":
-        cands = sorted(reg.values(), key=lambda pi: -pi.priority)
-        chosen = next(pi for pi in cands
-                      if pi.predicate(None, None, None, None, None))
-        assert chosen.impl == "jax"
+        assert kops.resolve_paged_attn("auto") == "jax"
+    assert kops.resolve_paged_attn("pallas") == "pallas"
 
 
 # ---------------------------------------------------------------------------
