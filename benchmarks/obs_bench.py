@@ -2,7 +2,9 @@
 
 The tracing contract is "low overhead when on, zero cost when off": one
 deque append per event, no dict/string work until export, and a
-``tracer=None`` engine takes exactly one attribute test per site. This
+``tracer=None`` engine takes one attribute test and one profiler
+annotation (which records nothing without a profiler session) per
+site. This
 module measures the contract:
 
 * ``obs_trace_overhead`` — one engine drains a closed-loop workload

@@ -79,51 +79,11 @@ def _ternary(rng, k: int, n: int, density: float = 0.5):
     return np.where(rng.random((k, n)) < density, w, 0).astype(np.int8)
 
 
-def _measure(op, reps: int = 3) -> float:
-    """Best-of-``reps`` eager wall time via ``ops.kernel_probe`` (lowering
-    through block_until_ready). The first call compiles and is discarded.
-    A nesting op (fused_mlp's chain impl dispatches probed ternary_gemms
-    inside it) reports *last*, so the final callback per invocation is
-    the outermost measurement."""
-    from repro.kernels import ops
-
-    best = None
-    for i in range(reps + 1):
-        times: List[float] = []
-        with ops.kernel_probe(lambda _plan, dt: times.append(dt)):
-            op()
-        assert times, "probe missed the dispatch"
-        if i and (best is None or times[-1] < best):
-            best = times[-1]
-    return best
-
-
-def _measured_fields(roofline: Dict, dt: float) -> Dict:
-    """Measured achieved-vs-peak columns next to the model's: the modeled
-    roofline says what the kernel *could* do on the reference part; these
-    say what this host actually did."""
-    flops = roofline["flops"]
-    return {
-        "measured_time_s": dt,
-        "measured_flops": flops / dt if dt > 0 else None,
-        # >1: slower than the model's bound — the gap is host dispatch,
-        # interpret-mode overhead, or unmodeled memory traffic
-        "measured_vs_model": (dt / roofline["model_time_s"]
-                              if roofline["model_time_s"] else None),
-        "measured_vs_peak": (flops / dt / roofline["peak_flops"]
-                             if dt > 0 else None),
-    }
-
-
-def kernel_report(quick: bool = False,
-                  measured: bool = False) -> Dict[str, Dict]:
+def kernel_report(quick: bool = False) -> Dict[str, Dict]:
     """Per-registered-kernel roofline: one representative plan per
     ``(format, impl)`` lowering in the GEMM registry plus one per fused-MLP
     impl, each entry carrying the plan's modeled ``roofline()`` dict
-    (achieved vs ceiling FLOP/s, HBM bytes from occupancy metadata).
-    ``measured=True`` additionally times each lowering eagerly through
-    ``ops.kernel_probe`` and reports measured achieved-vs-peak next to
-    the model (DESIGN.md §15)."""
+    (achieved vs ceiling FLOP/s, HBM bytes from occupancy metadata)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -149,10 +109,6 @@ def kernel_report(quick: bool = False,
             "occupancy": plan.occupancy,
             "roofline": plan.roofline(),
         }
-        if measured:
-            dt = _measure(lambda w=w, impl=impl:
-                          ops.ternary_gemm(x, w, impl=impl))
-            rec["measured"] = _measured_fields(rec["roofline"], dt)
         report[f"{fmt}/{impl}"] = rec
 
     wi = weights.pack(_ternary(rng, k, ff), "dense2bit")
@@ -167,17 +123,12 @@ def kernel_report(quick: bool = False,
                        "block_k2": plan.block_k2},
             "roofline": plan.roofline(),
         }
-        if measured:
-            dt = _measure(lambda impl=impl:
-                          ops.fused_mlp(x, wi, wo, wg, impl=impl))
-            rec["measured"] = _measured_fields(rec["roofline"], dt)
         report[f"fused_mlp/{impl}"] = rec
     return report
 
 
-def write_kernel_report(path: str, quick: bool = False,
-                        measured: bool = False) -> Dict[str, Dict]:
-    report = kernel_report(quick=quick, measured=measured)
+def write_kernel_report(path: str, quick: bool = False) -> Dict[str, Dict]:
+    report = kernel_report(quick=quick)
     doc = {"version": 1, "quick": quick, "kernels": report}
     d = os.path.dirname(path)
     if d:
@@ -189,24 +140,13 @@ def write_kernel_report(path: str, quick: bool = False,
 
 def print_kernel_report(report: Dict[str, Dict]) -> None:
     print("\n== kernel roofline ==")
-    has_measured = any("measured" in rec for rec in report.values())
-    cols = ("kernel,bound,arithmetic_intensity,achieved_gflops,"
-            "ceiling_gflops,headroom")
-    if has_measured:
-        cols += ",measured_ms,measured_gflops,measured_vs_model"
-    print(cols)
+    print("kernel,bound,arithmetic_intensity,achieved_gflops,"
+          "ceiling_gflops,headroom")
     for name, rec in sorted(report.items()):
         rl = rec["roofline"]
-        row = (f"{name},{rl['bound']},{rl['arithmetic_intensity']:.1f},"
-               f"{rl['achieved_flops'] / 1e9:.1f},"
-               f"{rl['ceiling_flops'] / 1e9:.1f},{rl['headroom']:.3f}")
-        if has_measured:
-            ms = rec.get("measured")
-            row += (",,," if ms is None else
-                    f",{ms['measured_time_s'] * 1e3:.3f},"
-                    f"{ms['measured_flops'] / 1e9:.2f},"
-                    f"{ms['measured_vs_model']:.1f}")
-        print(row)
+        print(f"{name},{rl['bound']},{rl['arithmetic_intensity']:.1f},"
+              f"{rl['achieved_flops'] / 1e9:.1f},"
+              f"{rl['ceiling_flops'] / 1e9:.1f},{rl['headroom']:.3f}")
 
 
 def main(out_dir: str = "experiments/dryrun"):
@@ -226,17 +166,12 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="small representative shapes (CI bench leg)")
-    ap.add_argument("--measured", action="store_true",
-                    help="time each lowering eagerly (ops.kernel_probe) "
-                         "and report measured achieved-vs-peak next to "
-                         "the modeled roofline")
     ap.add_argument("--json", default="",
                     help="write the per-kernel roofline report to this path")
     ap.add_argument("--out-dir", default="experiments/dryrun",
                     help="dry-run records for the model-level table")
     args = ap.parse_args()
     main(args.out_dir)
-    rep = (write_kernel_report(args.json, quick=args.quick,
-                               measured=args.measured) if args.json
-           else kernel_report(quick=args.quick, measured=args.measured))
+    rep = (write_kernel_report(args.json, quick=args.quick) if args.json
+           else kernel_report(quick=args.quick))
     print_kernel_report(rep)

@@ -1,29 +1,31 @@
 #!/usr/bin/env python
-"""Analyse a ``serve.py --trace`` export (DESIGN.md §15): step-time
-breakdown, prefill/decode interleave bubbles, the per-request TTFT
-attribution waterfall, and measured-vs-modeled kernel utilization.
+"""Analyse a ``serve.py --trace`` export or a ``jax.profiler`` trace of
+the engine (DESIGN.md §15).
 
-The input is the Chrome trace-event JSON the engine's ``obs.trace.Tracer``
-writes — the same file Perfetto renders visually; this gives the numeric
-summary. Sections:
+The Chrome trace-event JSON the engine's ``obs.trace.Tracer`` writes —
+the same file Perfetto renders visually — gives host-clock sections:
 
-  * **step breakdown** — engine-track complete spans (decode_step,
-    chunk_window, prefill, draft, verify) per engine pid: count, total
-    seconds, p50/p90/p99 duration.
+  * **step breakdown** — engine-track spans (``engine.step`` and the
+    ``engine.*`` phases nested in it) per name: count, total seconds,
+    p50/p90/p99 duration.
   * **interleave** — wall-clock span covered by the engine track, the
-    fraction busy inside kernel spans vs scheduling bubbles, and how the
-    busy time splits between prefill-side (prefill, chunk_window) and
-    decode-side (decode_step, draft, verify) work.
+    fraction inside steps vs between them, and how much of it the
+    prefill-side phases (prefill, chunk window and their readbacks) and
+    the decode-side phases (decode, draft, verify and their readbacks)
+    cover.
   * **TTFT waterfall** — per request: queue wait vs prefill vs (chunked)
     chunk count, worst first — where the first token actually went.
-  * **measured vs modeled** — kernel spans carry their plan's modeled
-    roofline (``model_time_s``, bytes, flops); compare against measured
-    wall time per span name: measured/modeled time ratio and achieved
-    fraction of the modeled bandwidth/compute ceiling.
+
+A profiler trace (a directory ``jax.profiler`` wrote, or its
+``.xplane.pb``) gives the device's side through ``repro.obs.xplane``:
+each engine program's device time, each span's host time, the host self
+time of a step, and each idle gap of the device put down to the program
+or the span it fell in.
 
 Usage:
   PYTHONPATH=src python scripts/trace_report.py TRACE.json [--json]
       [--top 8]
+  PYTHONPATH=src python scripts/trace_report.py PROFILE_DIR [--json]
 """
 from __future__ import annotations
 
@@ -35,13 +37,16 @@ from typing import Any, Dict, List
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.obs import xplane  # noqa: E402
 from repro.obs.metrics import percentiles  # noqa: E402
 from repro.obs.trace import load_trace, validate_events  # noqa: E402
 
 # engine-track span names by scheduler side; anything else on tid 0 is
 # still counted in the by-name breakdown, just not attributed to a side
-PREFILL_SIDE = ("prefill", "chunk_window")
-DECODE_SIDE = ("decode_step", "draft", "verify")
+PREFILL_SIDE = ("engine.prefill", "engine.prefill_readback",
+                "engine.chunk_window", "engine.chunk_readback")
+DECODE_SIDE = ("engine.decode", "engine.decode_readback", "engine.draft",
+               "engine.verify", "engine.verify_readback")
 
 
 def _engine_spans(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
@@ -51,8 +56,8 @@ def _engine_spans(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
 
 
 def _busy_us(spans: List[Dict[str, Any]]) -> int:
-    """Union length of [ts, ts+dur) intervals — overlapping spans (a
-    chunk_window inside the same step as a decode_step) count once."""
+    """Union length of [ts, ts+dur) intervals — nested spans (the
+    phases inside an ``engine.step``) count once."""
     ivs = sorted((e["ts"], e["ts"] + e["dur"]) for e in spans)
     busy, end = 0, None
     for lo, hi in ivs:
@@ -86,8 +91,8 @@ def interleave(spans: List[Dict[str, Any]]) -> Dict[str, Any]:
     dec = _busy_us([e for e in spans if e["name"] in DECODE_SIDE])
     return {"span_s": round(span_us / 1e6, 6),
             "busy_frac": round(busy / span_us, 4),
-            # scheduling bubbles: wall time on the engine track outside
-            # any kernel span — host bookkeeping, queue waits, idle ticks
+            # scheduling bubbles: wall time on the engine track between
+            # steps — the caller's own work, queue waits, idle ticks
             "bubble_frac": round(1.0 - busy / span_us, 4),
             "prefill_frac": round(pre / span_us, 4),
             "decode_frac": round(dec / span_us, 4)}
@@ -113,31 +118,6 @@ def ttft_waterfall(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     return rows
 
 
-def measured_vs_modeled(spans: List[Dict[str, Any]]) -> Dict[str, Any]:
-    out: Dict[str, Any] = {}
-    by_name: Dict[str, List[Dict[str, Any]]] = {}
-    for e in spans:
-        args = e.get("args") or {}
-        if "model_time_s" in args:
-            by_name.setdefault(e["name"], []).append(e)
-    for name, evs in sorted(by_name.items()):
-        measured = sum(e["dur"] for e in evs) / 1e6
-        modeled = sum(e["args"]["model_time_s"] for e in evs)
-        flops = sum(e["args"].get("modeled_flops", 0) for e in evs)
-        out[name] = {
-            "n": len(evs),
-            "measured_s": round(measured, 6),
-            "modeled_s": round(modeled, 6),
-            # >1: slower than the roofline model says it could be (host
-            # dispatch, unmodeled memory traffic); the gap IS the finding
-            "measured_vs_model": (round(measured / modeled, 3)
-                                  if modeled > 0 else None),
-            "achieved_flops": (round(flops / measured, 1)
-                               if measured > 0 and flops else None),
-        }
-    return out
-
-
 def report(path: str) -> Dict[str, Any]:
     doc = load_trace(path)
     events = doc["traceEvents"]
@@ -150,8 +130,28 @@ def report(path: str) -> Dict[str, Any]:
         "step_breakdown": step_breakdown(spans),
         "interleave": interleave(spans),
         "ttft_waterfall": ttft_waterfall(events),
-        "measured_vs_modeled": measured_vs_modeled(spans),
     }
+
+
+def is_profile(path: str) -> bool:
+    return os.path.isdir(path) or path.endswith(".xplane.pb")
+
+
+def print_profile(rep: Dict[str, Any]) -> None:
+    print(f"window={rep['window_s']:.4f}s busy={rep['busy_s']:.4f}s "
+          f"host step self time p50="
+          f"{rep['host_step_ms'] or float('nan'):.2f}ms")
+    print("\n== device programs ==")
+    for name, s in rep["programs"].items():
+        print(f"  {name:<28} n={s['n']:<6} total={s['total_s']:.4f}s "
+              f"p50={s['median_ms']:.3f}ms")
+    print("\n== host spans ==")
+    for name, s in rep["host_spans"].items():
+        print(f"  {name:<28} n={s['n']:<6} total={s['total_s']:.4f}s "
+              f"p50={s['median_ms']:.3f}ms")
+    print("\n== device idle gaps ==")
+    for label, sec in rep["idle_gaps"]:
+        print(f"  {label:<44} {sec:.4f}s")
 
 
 def _fmt_pct(v) -> str:
@@ -161,12 +161,20 @@ def _fmt_pct(v) -> str:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="summarise a serve.py --trace export")
-    ap.add_argument("trace", help="Chrome trace-event JSON from --trace")
+    ap.add_argument("trace", help="Chrome trace-event JSON from --trace, "
+                    "or a jax.profiler trace directory / .xplane.pb")
     ap.add_argument("--json", action="store_true",
                     help="emit the full report as JSON instead of text")
     ap.add_argument("--top", type=int, default=8,
                     help="TTFT waterfall rows shown in text mode")
     args = ap.parse_args(argv)
+    if is_profile(args.trace):
+        rep = xplane.summary(xplane.read(args.trace))
+        if args.json:
+            print(json.dumps(rep, indent=2))
+        else:
+            print_profile(rep)
+        return 0
     rep = report(args.trace)
     if args.json:
         print(json.dumps(rep, indent=2))
@@ -176,7 +184,7 @@ def main(argv=None) -> int:
           f"{rep['dropped']} dropped)")
     print("\n== step-time breakdown (engine track) ==")
     for name, s in rep["step_breakdown"].items():
-        print(f"  {name:<14} n={s['n']:<5} total={s['total_s']:.4f}s  "
+        print(f"  {name:<24} n={s['n']:<5} total={s['total_s']:.4f}s  "
               f"p50={s['p50'] * 1e3:.2f}ms p90={s['p90'] * 1e3:.2f}ms "
               f"p99={s['p99'] * 1e3:.2f}ms")
     il = rep["interleave"]
@@ -191,15 +199,6 @@ def main(argv=None) -> int:
         print(f"  rid={r['rid']:<4} ttft={r['ttft_s'] * 1e3:8.2f}ms  "
               f"queue={r.get('queue_wait_s', 0.0) * 1e3:8.2f}ms  "
               f"prefill={r.get('prefill_s', 0.0) * 1e3:8.2f}ms{chunks}")
-    mvm = rep["measured_vs_modeled"]
-    if mvm:
-        print("\n== measured vs modeled (kernel spans) ==")
-        for name, s in mvm.items():
-            ratio = s["measured_vs_model"]
-            print(f"  {name:<14} n={s['n']:<5} "
-                  f"measured={s['measured_s']:.4f}s "
-                  f"modeled={s['modeled_s']:.6f}s  "
-                  f"x{ratio if ratio is not None else 'n/a'} of model")
     return 0
 
 

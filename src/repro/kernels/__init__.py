@@ -4,7 +4,7 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fused_mlp import fused_mlp_pallas
 from repro.kernels.ops import (SERVING_PHASES, FusedMlpPlan, GemmPlan,
                                fused_mlp, fused_mlp_plan, fused_registry,
-                               kernel_probe, kernel_registry,
+                               kernel_registry,
                                paged_attention_registry,
                                paged_decode_attention, pack_weights,
                                pack_weights_tiled, precompute_fused_plans,
@@ -19,7 +19,7 @@ from repro.kernels.ternary_gemm_bitplane import ternary_gemm_bitplane
 
 __all__ = ["ternary_gemm", "ternary_gemm_plan", "GemmPlan",
            "register_kernel", "kernel_registry", "serving_phase",
-           "SERVING_PHASES", "kernel_probe",
+           "SERVING_PHASES",
            "fused_mlp", "fused_mlp_plan", "FusedMlpPlan",
            "register_fused", "fused_registry", "precompute_fused_plans",
            "fused_mlp_pallas",

@@ -70,7 +70,7 @@ def describes_device() -> bool:
             or jax.devices()[0].device_kind in MODELED_DEVICE_KINDS)
 
 
-def check_modeled_device() -> None:
+def check_roofline_device() -> None:
     """Raise on a TPU that is not a v5e: a roofline against v5e peaks
     would be wrong there, and no table of peaks per device exists yet."""
     if not describes_device():

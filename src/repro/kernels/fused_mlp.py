@@ -297,6 +297,7 @@ def fused_mlp_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, n2p), x.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        name="fused_mlp",
         interpret=interpret,
     )(xp, *operands)
     return y[:m, :n]

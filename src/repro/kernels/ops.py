@@ -63,7 +63,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import formats, weights
 from repro.kernels import ref
-from repro.obs import clock as obs_clock
 from repro.kernels import autotune as autotune_lib
 from repro.kernels.fused_mlp import ACTIVATIONS, _act, fused_mlp_pallas
 from repro.kernels.ternary_gemm import (K_PER_WORD, ternary_gemm_pallas,
@@ -79,7 +78,7 @@ __all__ = ["ternary_gemm", "ternary_gemm_plan", "GemmPlan", "KernelImpl",
            "pack_weights", "pack_weights_tiled",
            "serving_phase", "current_phase", "SERVING_PHASES",
            "tensor_parallel", "current_tp_mesh",
-           "kernel_probe", "SKIP_OCCUPANCY_CUTOFF",
+           "SKIP_OCCUPANCY_CUTOFF",
            "paged_decode_attention", "register_paged_attn",
            "paged_attention_registry", "resolve_paged_attn"]
 
@@ -141,39 +140,6 @@ def current_tp_mesh():
         return None
     return mesh
 
-
-# Optional kernel timing probe (DESIGN.md §15): a callback receiving
-# (plan, wall_seconds) for every *eager* ternary_gemm / fused_mlp
-# dispatch inside the scope. The measured time spans lowering through
-# block_until_ready, bracketed by a jax.profiler.TraceAnnotation so the
-# same region shows up in an XLA profile. Dispatches under jit tracing
-# are skipped — there is no wall time to measure at trace time, and the
-# probe must not bake a callback into a compiled computation.
-_KERNEL_PROBE: contextvars.ContextVar[Optional[Callable]] = \
-    contextvars.ContextVar("repro_kernel_probe", default=None)
-
-
-@contextlib.contextmanager
-def kernel_probe(cb: Callable[[Any, float], None]):
-    """``with kernel_probe(lambda plan, dt: ...):`` — time every eager
-    kernel dispatch in the scope against its plan (whose ``roofline()``
-    carries the modeled bytes/FLOPs/time for measured-vs-modeled
-    reporting; see ``benchmarks/roofline.py --measured``)."""
-    token = _KERNEL_PROBE.set(cb)
-    try:
-        yield
-    finally:
-        _KERNEL_PROBE.reset(token)
-
-
-def _probe_dispatch(probe: Callable, plan, tag: str, lower: Callable):
-    """Timed dispatch path shared by the two public ops."""
-    t0 = obs_clock.now()
-    with jax.profiler.TraceAnnotation(tag):
-        y = lower()
-        jax.block_until_ready(y)
-    probe(plan, obs_clock.now() - t0)
-    return y
 
 # Above this occupied-tile fraction the skipping grid saves too little to
 # justify the scalar-prefetch indirection; "auto" falls back to dense.
@@ -401,7 +367,7 @@ class GemmPlan:
         ceiling FLOP/s, arithmetic intensity, and remaining headroom.
         Emitted per registered kernel by ``benchmarks/roofline.py``.
         Raises on a TPU the modeled peaks do not describe."""
-        autotune_lib.check_modeled_device()
+        autotune_lib.check_roofline_device()
         t = self.traffic()
         ai = t["flops"] / max(t["bytes"], 1.0)
         ceiling = min(autotune_lib.PEAK_FLOPS, ai * autotune_lib.HBM_BW)
@@ -1049,7 +1015,7 @@ class FusedMlpPlan:
         fused kernel's (x and each weight once per M tile, h never leaves
         VMEM), and the modeled speedup ratio the CI bench gates on. Raises
         on a TPU the modeled peaks do not describe."""
-        autotune_lib.check_modeled_device()
+        autotune_lib.check_roofline_device()
         up, down = self.sub_plans()
         n_up = 2 if self.gated else 1
         unfused_bytes = n_up * up.traffic()["bytes"] \
@@ -1354,12 +1320,6 @@ def fused_mlp(x: jnp.ndarray, w_in: Any, w_out: Any, w_gate: Any = None,
             and (w_gate is None or w_gate.tp_dim == "n"):
         return _tp_fused(mesh, x, w_in, w_out, w_gate,
                          activation=activation, interpret=plan.interpret)
-    probe = _KERNEL_PROBE.get()
-    if probe is not None and not isinstance(x, jax.core.Tracer):
-        return _probe_dispatch(
-            probe, plan,
-            f"fused_mlp[{plan.impl} m={plan.m} k={plan.k} ff={plan.ff}]",
-            lambda: _FUSED[plan.impl].fn(plan, x, w_in, w_out, w_gate))
     return _FUSED[plan.impl].fn(plan, x, w_in, w_out, w_gate)
 
 
@@ -1537,12 +1497,4 @@ def ternary_gemm(
         return _tp_gemm(mesh, x, w, scale, bias, impl=plan.impl,
                         fuse_prelu=fuse_prelu, prelu_alpha=prelu_alpha,
                         interpret=plan.interpret)
-    probe = _KERNEL_PROBE.get()
-    if probe is not None and not isinstance(x, jax.core.Tracer):
-        return _probe_dispatch(
-            probe, plan,
-            f"ternary_gemm[{plan.format}/{plan.impl} m={plan.m} "
-            f"k={plan.k} n={plan.n}]",
-            lambda: _KERNELS[(plan.format, plan.impl)].lower(
-                plan, x, w, scale, bias))
     return _KERNELS[(plan.format, plan.impl)].lower(plan, x, w, scale, bias)

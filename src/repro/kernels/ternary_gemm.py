@@ -155,6 +155,7 @@ def ternary_gemm_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="ternary_gemm_dense",
         interpret=interpret,
     )(*operands)
 
@@ -279,6 +280,7 @@ def ternary_gemm_skip_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="ternary_gemm_skip",
         interpret=interpret,
     )(kt_indices, kt_counts, *operands)
 
@@ -448,5 +450,6 @@ def ternary_gemm_skip_db_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
+        name="ternary_gemm_skip_db",
         interpret=interpret,
     )(kt_indices, kt_counts, *operands)

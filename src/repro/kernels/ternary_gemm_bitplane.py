@@ -136,6 +136,7 @@ def ternary_gemm_bitplane(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="ternary_gemm_bitplane",
         interpret=interpret,
     )(*operands)
     return y[:m, :n]

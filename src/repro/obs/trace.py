@@ -19,8 +19,8 @@ Design constraints, in order:
    tracer's epoch.
 
 Track layout: each engine registers a *process* (``new_pid``); its
-scheduler-level spans (decode steps, chunk windows, kernel-phase spans
-with modeled roofline attributes) live on ``tid=0`` and every request
+phase spans (``engine.step`` and the ``engine.*`` spans nested in it,
+written through ``phase``) live on ``tid=0`` and every request
 gets its own thread track (``tid = rid + 1``) carrying the request's
 whole lifecycle — submit → admit → prefill/chunks → first token →
 decode → done/failed/preempted/quarantined — as one row. Spans whose
@@ -28,6 +28,12 @@ boundaries are only known after the fact (queue wait, TTFT components)
 are emitted retrospectively via ``complete()`` from the same clock
 stamps the metrics use, so trace-derived TTFT/TPOT agrees with
 ``Request.metrics()`` to microsecond rounding.
+
+``phase`` is the one span primitive of the engine. It always opens a
+``jax.profiler.TraceAnnotation`` (a ``StepTraceAnnotation`` for a step),
+which records only while a profiler session runs, on the profiler's own
+clock beside the device's programs, and costs well under a microsecond
+otherwise; with a ``Tracer`` it also records the span into the ring.
 """
 from __future__ import annotations
 
@@ -36,9 +42,11 @@ import contextlib
 import json
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from repro.obs import clock as obs_clock
 
-__all__ = ["Tracer", "load_trace", "validate_events"]
+__all__ = ["Tracer", "phase", "load_trace", "validate_events"]
 
 # tuple layout of one ring entry: (ph, name, cat, ts_us, dur_us, pid,
 # tid, args) — ph/dur/args semantics per trace-event phase
@@ -157,6 +165,25 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return len(doc["traceEvents"])
+
+
+def phase(name: str, tracer: Optional[Tracer] = None, pid: int = 0,
+          step_num: Optional[int] = None):
+    """Context manager around one phase of work: a profiler annotation
+    (a step annotation when ``step_num`` is given) and, with ``tracer``,
+    a span on the tracer's ``pid``/``tid 0`` track. Nested phases nest in
+    both sinks. With ``tracer=None`` nothing reads the clock."""
+    ann = (TraceAnnotation(name) if step_num is None
+           else StepTraceAnnotation(name, step_num=step_num))
+    if tracer is None:
+        return ann
+    return _both(ann, tracer.span(name, pid=pid))
+
+
+@contextlib.contextmanager
+def _both(ann, span):
+    with ann, span:
+        yield
 
 
 def load_trace(path: str) -> Dict[str, Any]:

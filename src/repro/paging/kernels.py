@@ -280,6 +280,7 @@ def paged_decode_attention_pallas(q, k_pages: Pages, v_pages: Pages,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="paged_decode_attention",
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
       *operands)

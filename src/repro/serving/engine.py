@@ -80,6 +80,15 @@ deadlines cancel requests wherever they are (queued or mid-decode). A
 OOM, slow steps, draft failures); the degradation ladder auto-disables
 speculation below a rolling acceptance floor and pauses admission under
 page-pool pressure. All of it surfaces in ``run()`` under ``faults{...}``.
+
+Observability (DESIGN.md §15): every step is an ``engine.step`` profiler
+step annotation with the work in it as nested ``engine.*`` spans
+(``obs.trace.phase``), and every jitted program has a stable name
+(``engine_prefill``, ``engine_chunk_window``, ``engine_decode``,
+``engine_draft``, ``engine_verify``), so a profiler trace puts each
+device program and each host gap down to a phase. Where each program is
+dispatched the engine counts the rows it computes and the rows that
+carry a token (``rows_computed.<phase>`` / ``rows_real.<phase>``).
 """
 from __future__ import annotations
 
@@ -94,11 +103,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.kernels import autotune as autotune_lib
 from repro.kernels import ops as kops
 from repro.models import LM
 from repro.obs import clock as obs_clock
 from repro.obs.metrics import MetricsRegistry, RunningStat, percentiles
+from repro.obs.trace import phase
 from repro.serving.faults import (FAIL_DEADLINE, FAIL_NUMERIC, FaultConfig,
                                   FaultInjector, ResilienceConfig)
 from repro.serving.queue import Request, RequestQueue
@@ -117,6 +126,10 @@ _pcts = percentiles
 # generous because serving steps legitimately vary (whole-prompt prefill
 # vs GEMV decode); the signal targets pathological stalls, not phase mix
 _STRAGGLER_FACTOR = 8.0
+
+# the phases whose programs compute token rows, each with a pair of row
+# counters (see ``ContinuousScheduler.__init__``)
+ROW_PHASES = ("prefill", "chunk", "decode", "verify")
 
 
 class ContinuousScheduler:
@@ -218,6 +231,13 @@ class ContinuousScheduler:
         # _STRAGGLER_FACTOR) flags anomalous steps through the same
         # registry mechanism the train supervisor's watchdog uses
         self._step_time = self.metrics.ewma("step_time_s", alpha=0.3)
+        # (rows computed, rows that carry a token) per phase, counted
+        # where each program is dispatched; held as attributes so the hot
+        # path does no lookup by name
+        (self._rows_prefill, self._rows_chunk, self._rows_decode,
+         self._rows_verify) = [
+            (self.metrics.counter(f"rows_computed.{ph}"),
+             self.metrics.counter(f"rows_real.{ph}")) for ph in ROW_PHASES]
         if cache == "paged":
             from repro.paging import PagePool
             self.pool = PagePool(self.model, max_slots, max_len,
@@ -296,26 +316,27 @@ class ContinuousScheduler:
                     self._dev_table,
                     tp_lib.replicated_sharding(self._dev_table, self.mesh))
 
-        def prefill(params, toks):
+        # each jit's function name names its program in a profiler trace
+        def engine_prefill(params, toks):
+            # paged: a page-aligned cache length, the pool scatters whole
+            # pages
+            length = (max_len if cache == "dense"
+                      else -(-toks.shape[1] // page_size) * page_size)
             cache_, logits = self.model.prefill(params, {"tokens": toks},
-                                                max_len)
+                                                length)
             return cache_["layers"], jnp.argmax(logits[:, -1],
                                                 axis=-1).astype(jnp.int32)
 
-        def prefill_paged(params, toks):
-            # page-aligned cache length: the pool scatters whole pages
-            pad = -(-toks.shape[1] // page_size) * page_size
-            cache_, logits = self.model.prefill(params, {"tokens": toks},
-                                                pad)
-            return cache_["layers"], jnp.argmax(logits[:, -1],
-                                                axis=-1).astype(jnp.int32)
-
-        def decode(params, layers, pos, toks, nan_mask):
+        def engine_decode(params, layers, pos, toks, nan_mask, table=None):
             # free slots keep decoding garbage; clamp their write position
             # so it can never run past the cache (live rows are bounded by
-            # the submit-time prompt+budget <= max_len assertion)
+            # the submit-time prompt+budget <= max_len assertion). Paged,
+            # their block tables are all-zero, so the clamped garbage
+            # writes land in the pool's reserved trash page 0
             cache_ = {"layers": layers,
                       "pos": jnp.minimum(pos, max_len - 1)}
+            if table is not None:
+                cache_["block_table"] = table
             logits, new_cache = self.model.decode_step(params, cache_,
                                                        toks[:, None])
             # §11 numerical guard: fault injection corrupts masked rows
@@ -326,23 +347,11 @@ class ContinuousScheduler:
             nxt = jnp.argmax(row, axis=-1).astype(jnp.int32)
             return new_cache["layers"], new_cache["pos"], nxt, ok
 
-        def decode_paged(params, layers, table, pos, toks, nan_mask):
-            # free slots' block tables are all-zero, so their clamped
-            # garbage writes land in the pool's reserved trash page 0
-            cache_ = {"layers": layers,
-                      "pos": jnp.minimum(pos, max_len - 1),
-                      "block_table": table}
-            logits, new_cache = self.model.decode_step(params, cache_,
-                                                       toks[:, None])
-            row = jnp.where(nan_mask[:, None], jnp.nan, logits[:, 0, :])
-            ok = jnp.all(jnp.isfinite(row), axis=-1)
-            nxt = jnp.argmax(row, axis=-1).astype(jnp.int32)
-            return new_cache["layers"], new_cache["pos"], nxt, ok
-
-        self._prefill = jax.jit(prefill if cache == "dense"
-                                else prefill_paged)
-        self._decode = jax.jit(decode, donate_argnums=(1,))
-        self._decode_paged = jax.jit(decode_paged, donate_argnums=(1,))
+        self._prefill = jax.jit(engine_prefill)
+        # one program; the paged path calls it as ``_decode_paged`` (the
+        # attribute the benchmark's altered-token test swaps)
+        self._decode = self._decode_paged = jax.jit(engine_decode,
+                                                    donate_argnums=(1,))
 
     # ------------------------------------------------------------------
     def load(self, params) -> None:
@@ -441,11 +450,11 @@ class ContinuousScheduler:
             self._draft_insert = jax.jit(dlm.insert_cache,
                                          donate_argnums=(0,))
 
-            def draft_prefill(dp, toks):
+            def engine_draft_prefill(dp, toks):
                 c, _ = dlm.prefill(dp, {"tokens": toks}, self.max_len)
                 return c["layers"]
 
-            self._draft_prefill = jax.jit(draft_prefill)
+            self._draft_prefill = jax.jit(engine_draft_prefill)
             self._draft_round = spec_lib.make_draft_round(
                 self.draft, self.max_len, self.spec.k)
             self._verify = spec_lib.make_verify_step(
@@ -471,47 +480,6 @@ class ContinuousScheduler:
                 self._chunker.warmup(
                     self.params, self.pool,
                     [1 << i for i in range(smax.bit_length())])
-        # per-(phase, M-bucket) modeled roofline aggregates over the
-        # warmed plans — attached to this engine's measured kernel-phase
-        # trace spans so a trace carries measured-vs-modeled utilization
-        # side by side (DESIGN.md §15)
-        # (none where the modeled v5e peaks do not describe the chip)
-        self._phase_model: Dict[tuple, Dict[str, float]] = {}
-        self._modeled_memo: Dict[tuple, Optional[Dict[str, float]]] = {}
-        modeled = (self.gemm_plans.items() if autotune_lib.describes_device()
-                   else ())
-        for key, plan in modeled:
-            if key[0] == "draft":
-                continue
-            _, m, phase = key
-            agg = self._phase_model.setdefault(
-                (phase, m), {"gemms": 0, "modeled_flops": 0.0,
-                             "modeled_bytes": 0.0, "model_time_s": 0.0})
-            rl = plan.roofline()
-            agg["gemms"] += 1
-            agg["modeled_flops"] += rl["flops"]
-            agg["modeled_bytes"] += rl["bytes"]
-            agg["model_time_s"] += rl["model_time_s"]
-
-    def _modeled(self, phase: str, m: int) -> Optional[Dict[str, float]]:
-        """Modeled roofline aggregate for one kernel-phase span: the
-        warmed plan bucket that dispatch would hit for ``m`` rows (the
-        smallest planned bucket >= m, or the largest available).
-        Memoized — the decode path asks the same (phase, m) every step
-        and the answer is fixed once ``load()`` builds the buckets."""
-        memo = getattr(self, "_modeled_memo", None)
-        if memo is not None and (phase, m) in memo:
-            return memo[(phase, m)]
-        buckets = sorted(mb for ph, mb in
-                         getattr(self, "_phase_model", {}) if ph == phase)
-        if not buckets:
-            out = None
-        else:
-            mb = next((b for b in buckets if b >= m), buckets[-1])
-            out = dict(self._phase_model[(phase, mb)], m_bucket=mb)
-        if memo is not None:
-            memo[(phase, m)] = out
-        return out
 
     def submit(self, prompt: np.ndarray, max_new: int, *,
                deadline_s: Optional[float] = None,
@@ -578,37 +546,29 @@ class ContinuousScheduler:
         for req, _, _ in group:
             req.admit_t = t_admit       # slot granted; prefill starts now
         prompts = np.stack([r.prompt for r, _, _ in group])
-        with kops.serving_phase("prefill"):
-            req_layers, toks_dev = self._prefill(
-                self.params, jnp.asarray(prompts))
-        self.prefill_steps += 1
-        tr = self.tracer
-        if tr is not None:
-            # host wall time of the dispatched (async) forward; the
-            # np.asarray(toks_dev) below is the sync point, so the span
-            # closes there — measured next to the plans' modeled roofline
-            args = {"batch": len(group),
-                    "prompt_len": int(prompts.shape[1]),
-                    "m": int(prompts.size)}
-            model = self._modeled("prefill", prompts.size)
-            if model:
-                args.update(model)
-            np.asarray(toks_dev)
-            tr.complete("prefill", t_admit, obs_clock.now(), cat="kernel",
-                        pid=self._trace_pid, args=args)
-        if self.cache_mode == "paged":
-            self.pool.insert([a for _, _, a in group], req_layers)
-        else:
-            self.pool.insert([s for _, s, _ in group], req_layers)
-        if self.spec is not None:
-            # the draft keeps its own dense KV cache of the same stream
+        tr, pid = self.tracer, self._trace_pid
+        with phase("engine.prefill", tr, pid):
             with kops.serving_phase("prefill"):
-                draft_layers = self._draft_prefill(self.draft.params,
-                                                   jnp.asarray(prompts))
-            self._draft_layers = self._draft_insert(
-                self._draft_layers, draft_layers,
-                jnp.asarray([s for _, s, _ in group]))
-        toks = np.asarray(toks_dev)
+                req_layers, toks_dev = self._prefill(
+                    self.params, jnp.asarray(prompts))
+            if self.cache_mode == "paged":
+                self.pool.insert([a for _, _, a in group], req_layers)
+            else:
+                self.pool.insert([s for _, s, _ in group], req_layers)
+            if self.spec is not None:
+                # the draft keeps its own dense KV cache of the same stream
+                with kops.serving_phase("prefill"):
+                    draft_layers = self._draft_prefill(self.draft.params,
+                                                       jnp.asarray(prompts))
+                self._draft_layers = self._draft_insert(
+                    self._draft_layers, draft_layers,
+                    jnp.asarray([s for _, s, _ in group]))
+        self.prefill_steps += 1
+        computed, real = self._rows_prefill
+        computed.inc(prompts.size)
+        real.inc(prompts.size)
+        with phase("engine.prefill_readback", tr, pid):
+            toks = np.asarray(toks_dev)
         now = obs_clock.now()
         for (req, slot, _), tok in zip(group, toks):
             req.slot = slot
@@ -897,34 +857,42 @@ class ContinuousScheduler:
             return
         spec_active = self.spec is not None and not self.spec_disabled
         k = self.spec.k if spec_active else 0
-        tpots = [r.slo.tpot_target_s for r in self._live.values()
-                 if r.slo is not None
-                 and getattr(r.slo, "tpot_target_s", None) is not None]
-        jobs, meta = plan_chunks(
-            list(self._prefills.items()), cfg=self.sched,
-            budget=self.sched.budget_for(self.max_slots, k),
-            n_decode_tokens=len(self._live) * (1 + k),
-            max_len=self.max_len, now=obs_clock.now(),
-            step_s=self._step_ema,
-            tpot_floor=min(tpots) if tpots else None)
+        tr, pid = self.tracer, self._trace_pid
+        with phase("engine.plan_chunks", tr, pid):
+            tpots = [r.slo.tpot_target_s for r in self._live.values()
+                     if r.slo is not None
+                     and getattr(r.slo, "tpot_target_s", None) is not None]
+            jobs, meta = plan_chunks(
+                list(self._prefills.items()), cfg=self.sched,
+                budget=self.sched.budget_for(self.max_slots, k),
+                n_decode_tokens=len(self._live) * (1 + k),
+                max_len=self.max_len, now=obs_clock.now(),
+                step_s=self._step_ema,
+                tpot_floor=min(tpots) if tpots else None)
         self._chunk_meta = meta
         if not jobs:
             return
         t_window = obs_clock.now()
-        greedy, ok = self._chunker.advance(self.params, self.pool, jobs)
+        with phase("engine.chunk_window", tr, pid):
+            greedy_dev, ok_dev = self._chunker.advance(self.params,
+                                                       self.pool, jobs)
         self.chunk_steps += 1
+        computed, real = self._rows_chunk
+        computed.inc(greedy_dev.size)
+        real.inc(sum(c for _, _, c in jobs))
+        with phase("engine.chunk_readback", tr, pid):
+            greedy = np.asarray(greedy_dev)
+            ok = np.asarray(ok_dev)
         now = obs_clock.now()
+        with phase("engine.commit", tr, pid):
+            self._commit_chunks(jobs, greedy, ok, t_window, now)
+
+    def _commit_chunks(self, jobs, greedy, ok, t_window: float,
+                       now: float) -> None:
+        """Commit one chunk window's results: advance each row's prefill
+        frontier; a row whose prompt completes takes its first token and
+        joins the decode batch (spec mode also prefills the draft)."""
         tr = self.tracer
-        if tr is not None:
-            args = {"rows": len(jobs),
-                    "tokens": sum(c for _, _, c in jobs)}
-            args.update(meta)
-            model = self._modeled("chunk", len(jobs) * max(
-                c for _, _, c in jobs))
-            if model:
-                args.update(model)
-            tr.complete("chunk_window", t_window, now, cat="kernel",
-                        pid=self._trace_pid, args=args)
         completed = []
         for i, (slot, req, c) in enumerate(jobs):
             if not ok[i]:
@@ -1004,11 +972,18 @@ class ContinuousScheduler:
 
     def _step(self) -> None:
         self._step_no += 1
+        with phase("engine.step", self.tracer, self._trace_pid,
+                   step_num=self._step_no):
+            self._step_phases()
+
+    def _step_phases(self) -> None:
         t_step = obs_clock.now()
+        tr, pid = self.tracer, self._trace_pid
         faults = self._plan_faults()
-        self._expire_deadlines()
-        self._depth_stat.push(self.queue.depth())
-        self._admit()
+        with phase("engine.admit", tr, pid):
+            self._expire_deadlines()
+            self._depth_stat.push(self.queue.depth())
+            self._admit()
         if self._chunker is not None:
             self._run_chunks()
         # a draft fault (or the acceptance-floor ladder) downgrades this
@@ -1023,67 +998,71 @@ class ContinuousScheduler:
                 self.tracer.instant("draft_fallback", pid=self._trace_pid,
                                     args={"step": self._step_no})
         if self.cache_mode == "paged":
-            self._grow_paged(1 + (self.spec.k
-                                  if spec_active and not draft_down else 0))
+            with phase("engine.grow_pages", tr, pid):
+                self._grow_paged(1 + (self.spec.k if spec_active
+                                      and not draft_down else 0))
         if not self._live:
             if self._prefills:       # chunk-only step: still real work
                 self._note_step_time(t_step)
             return
         self._live_stat.push(len(self._live) + len(self._prefills))
+        with phase("engine.upload", tr, pid):
+            self._upload()
+        if spec_active and not draft_down:
+            self._step_spec(faults)
+            self._note_step_time(t_step)
+            return
+        mask = self._nan_mask(faults)
+        with phase("engine.decode", tr, pid):
+            with kops.serving_phase("decode"):
+                if self.cache_mode == "paged":
+                    out = self._decode_paged(
+                        self.params, self.pool.layers, self._dev_pos,
+                        self._dev_tok, mask, self._dev_table)
+                else:
+                    out = self._decode(self.params, self.pool.layers,
+                                       self._dev_pos, self._dev_tok, mask)
+                self.pool.layers, self._dev_pos, self._dev_tok, ok_dev = out
+        self.decode_steps += 1
+        with phase("engine.decode_readback", tr, pid):
+            toks = np.asarray(self._dev_tok)
+            ok = np.asarray(ok_dev)
+        committed = 0
+        with phase("engine.commit", tr, pid):
+            for slot in list(self._live):
+                req = self._live[slot]
+                if not ok[slot]:
+                    self._quarantine(slot)
+                    continue
+                if self.spec is not None:
+                    # keep the draft-round re-sync feed consistent across
+                    # plain-decode fallback rounds (spec.draft docstring)
+                    self._prev_tok[slot] = self._tok[slot]
+                    self._dirty = True
+                req.tokens.append(int(toks[slot]))
+                committed += 1
+                self._pos[slot] += 1
+                self._tok[slot] = toks[slot]
+                if req.done:
+                    self._evict(slot)
+        computed, real = self._rows_decode
+        computed.inc(self.max_slots)
+        real.inc(committed)
+        self._note_step_time(t_step)
+
+    def _upload(self) -> None:
+        """Push the host mirrors the device is behind on: positions and
+        tokens after admit/evict events, the block table after page
+        growth."""
         if self._dirty:
             self._dev_pos = jnp.asarray(self._pos)
             self._dev_tok = jnp.asarray(self._tok)
             if self.spec is not None:
                 self._dev_prev = jnp.asarray(self._prev_tok)
             self._dirty = False
-        if spec_active and not draft_down:
-            self._step_spec(faults)
-            self._note_step_time(t_step)
-            return
-        mask = self._nan_mask(faults)
-        t_decode = obs_clock.now()
-        with kops.serving_phase("decode"):
-            if self.cache_mode == "paged":
-                if self.pool.table_dirty:
-                    self._dev_table = jnp.asarray(self.pool.table)
-                    self.pool.table_dirty = False
-                self.pool.layers, self._dev_pos, self._dev_tok, ok_dev = \
-                    self._decode_paged(self.params, self.pool.layers,
-                                       self._dev_table, self._dev_pos,
-                                       self._dev_tok, mask)
-            else:
-                self.pool.layers, self._dev_pos, self._dev_tok, ok_dev = \
-                    self._decode(self.params, self.pool.layers,
-                                 self._dev_pos, self._dev_tok, mask)
-        self.decode_steps += 1
-        toks = np.asarray(self._dev_tok)
-        ok = np.asarray(ok_dev)
-        tr = self.tracer
-        if tr is not None:
-            # the np.asarray reads above are the sync point, so this span
-            # covers dispatch + device execution of the decode forward
-            args = {"live": len(self._live), "m": self.max_slots}
-            model = self._modeled("decode", self.max_slots)
-            if model:
-                args.update(model)
-            tr.complete("decode_step", t_decode, obs_clock.now(),
-                        cat="kernel", pid=self._trace_pid, args=args)
-        for slot in list(self._live):
-            req = self._live[slot]
-            if not ok[slot]:
-                self._quarantine(slot)
-                continue
-            if self.spec is not None:
-                # keep the draft-round re-sync feed consistent across
-                # plain-decode fallback rounds (spec.draft docstring)
-                self._prev_tok[slot] = self._tok[slot]
-                self._dirty = True
-            req.tokens.append(int(toks[slot]))
-            self._pos[slot] += 1
-            self._tok[slot] = toks[slot]
-            if req.done:
-                self._evict(slot)
-        self._note_step_time(t_step)
+        if self.cache_mode == "paged" and self.pool.table_dirty:
+            self._dev_table = jnp.asarray(self.pool.table)
+            self.pool.table_dirty = False
 
     @property
     def _step_ema(self) -> float:
@@ -1130,52 +1109,47 @@ class ContinuousScheduler:
         from the draft's own cache, verify the (slots, k+1) window in one
         target forward, emit the accepted prefix + bonus token, roll the
         target cache back past the rejected tail."""
-        from repro.spec import rollback as rb
         k = self.spec.k
-        tr = self.tracer
-        t_draft = obs_clock.now()
-        with kops.serving_phase("decode"):       # draft GEMMs are M=slots
-            self._draft_layers, drafts = self._draft_round(
-                self.draft.params, self._draft_layers, self._dev_pos,
-                self._dev_prev, self._dev_tok)
-        if tr is not None:
-            # draft plans are keyed separately (("draft",)+key) and are
-            # excluded from _phase_model, so this span carries measured
-            # shape args only — no modeled roofline
-            jax.block_until_ready(drafts)
-            tr.complete("draft", t_draft, obs_clock.now(), cat="kernel",
-                        pid=self._trace_pid,
-                        args={"live": len(self._live), "k": k,
-                              "m": self.max_slots})
-        window = jnp.concatenate([self._dev_tok[:, None], drafts], axis=1)
-        mask = self._nan_mask(faults)
-        t_verify = obs_clock.now()
-        with kops.serving_phase("verify"):
-            if self.cache_mode == "paged":
-                if self.pool.table_dirty:
-                    self._dev_table = jnp.asarray(self.pool.table)
-                    self.pool.table_dirty = False
-                self.pool.layers, greedy, n_acc, _, ok_dev = self._verify(
-                    self.params, self.pool.layers, self._dev_table,
-                    self._dev_pos, window, mask)
-            else:
-                self.pool.layers, greedy, n_acc, _, ok_dev = self._verify(
-                    self.params, self.pool.layers, self._dev_pos, window,
-                    mask)
+        tr, pid = self.tracer, self._trace_pid
+        with phase("engine.draft", tr, pid):
+            with kops.serving_phase("decode"):   # draft GEMMs are M=slots
+                self._draft_layers, drafts = self._draft_round(
+                    self.draft.params, self._draft_layers, self._dev_pos,
+                    self._dev_prev, self._dev_tok)
+        with phase("engine.verify", tr, pid):
+            window = jnp.concatenate([self._dev_tok[:, None], drafts],
+                                     axis=1)
+            mask = self._nan_mask(faults)
+            with kops.serving_phase("verify"):
+                if self.cache_mode == "paged":
+                    self.pool.layers, greedy, n_acc, _, ok_dev = \
+                        self._verify(self.params, self.pool.layers,
+                                     self._dev_table, self._dev_pos, window,
+                                     mask)
+                else:
+                    self.pool.layers, greedy, n_acc, _, ok_dev = \
+                        self._verify(self.params, self.pool.layers,
+                                     self._dev_pos, window, mask)
         self.decode_steps += 1
         self.spec_rounds += 1
-        greedy = np.asarray(greedy)
-        n_acc = np.asarray(n_acc)
-        ok = np.asarray(ok_dev)
-        if tr is not None:
-            # the np.asarray reads above are the sync point
-            args = {"live": len(self._live), "k": k,
-                    "m": self.max_slots * (k + 1)}
-            model = self._modeled("verify", self.max_slots * (k + 1))
-            if model:
-                args.update(model)
-            tr.complete("verify", t_verify, obs_clock.now(), cat="kernel",
-                        pid=self._trace_pid, args=args)
+        with phase("engine.verify_readback", tr, pid):
+            greedy = np.asarray(greedy)
+            n_acc = np.asarray(n_acc)
+            ok = np.asarray(ok_dev)
+        with phase("engine.commit", tr, pid):
+            emitted = self._commit_spec(greedy, n_acc, ok)
+        computed, real = self._rows_verify
+        computed.inc(greedy.size)
+        real.inc(emitted)
+
+    def _commit_spec(self, greedy, n_acc, ok) -> int:
+        """Commit one verify window: each live slot takes its accepted
+        drafts plus the bonus token and rolls back past the rejected
+        tail; returns the tokens committed."""
+        from repro.spec import rollback as rb
+        k = self.spec.k
+        tr, pid = self.tracer, self._trace_pid
+        total = 0
         round_slots = 0
         round_accepted = 0
         for slot in list(self._live):
@@ -1202,6 +1176,7 @@ class ContinuousScheduler:
                 if req.done:                      # budget / EOS mid-window
                     break
             self.spec_emitted += emitted
+            total += emitted
             self._pos[slot] += emitted
             self._tok[slot] = int(greedy[slot, emitted - 1])
             self._prev_tok[slot] = (int(greedy[slot, emitted - 2])
@@ -1209,13 +1184,16 @@ class ContinuousScheduler:
             self._dirty = True
             if req.done:
                 self._evict(slot)                 # release() drops all pages
-            elif self.cache_mode == "paged":
-                self.spec_page_reclaims += rb.rollback_paged(
-                    self.pool, slot, int(self._pos[slot]))
-            else:
-                # dense rollback is length bookkeeping only — the _pos
-                # update above IS the rollback (see spec.rollback)
-                rb.rollback_dense(self.pool, slot, int(self._pos[slot]))
+                continue
+            with phase("engine.rollback", tr, pid):
+                if self.cache_mode == "paged":
+                    self.spec_page_reclaims += rb.rollback_paged(
+                        self.pool, slot, int(self._pos[slot]))
+                else:
+                    # dense rollback is length bookkeeping only — the _pos
+                    # update above IS the rollback (see spec.rollback)
+                    rb.rollback_dense(self.pool, slot,
+                                      int(self._pos[slot]))
         # degradation rung 1 (DESIGN.md §11): rolling acceptance floor.
         # A draft that stops agreeing with the target makes every round
         # cost a k+1-wide verify for ~1 emitted token — worse than plain
@@ -1237,6 +1215,7 @@ class ContinuousScheduler:
                         "spec decoding disabled: rolling acceptance %.3f "
                         "< floor %.3f over %d rounds", mean, floor,
                         self._accept_ring.maxlen)
+        return total
 
     # ------------------------------------------------------------------
     def has_work(self) -> bool:
@@ -1272,7 +1251,15 @@ class ContinuousScheduler:
                    "draft_fallbacks": self.draft_fallbacks,
                    "injected": (dict(self.injector.injected)
                                 if self.injector else {})},
+            "r0": self._row_counts(),
         }
+
+    def _row_counts(self) -> Dict[str, Dict[str, int]]:
+        """Rows computed and rows that carried a token, per phase."""
+        return {ph: {"computed": c.value, "real": r.value}
+                for ph, (c, r) in zip(ROW_PHASES, (
+                    self._rows_prefill, self._rows_chunk,
+                    self._rows_decode, self._rows_verify))}
 
     def run(self) -> Dict[str, Any]:
         """Drain the queue completely; return the metrics JSON dict."""
@@ -1407,6 +1394,10 @@ class ContinuousScheduler:
             "tok_per_s": round(gen / wall, 2) if wall > 0 else None,
             "prefill_steps": self.prefill_steps - p0,
             "decode_steps": self.decode_steps - d0,
+            # rows the span's programs computed and rows that carried a
+            # token, per phase: 1 - real / computed is the pad share
+            "rows": {ph: {k: v - snap["r0"][ph][k] for k, v in n.items()}
+                     for ph, n in self._row_counts().items()},
             "ttft_s": {"mean": float(np.mean(ttfts)) if ttfts else None,
                        "max": float(np.max(ttfts)) if ttfts else None},
             # exact percentile aggregates over the span's terminal

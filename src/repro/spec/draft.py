@@ -215,7 +215,7 @@ def make_draft_round(draft: DraftModel, max_len: int, k: int):
     (pos 0) compute garbage into rows the next admission overwrites."""
     dlm = draft.model
 
-    def round_(params, layers, pos, prev_tok, tok):
+    def engine_draft(params, layers, pos, prev_tok, tok):
         pos_c = jnp.minimum(pos, max_len - 1 - k)
         cache = {"layers": layers, "pos": jnp.maximum(pos_c - 1, 0)}
         _, cache = dlm.decode_step(params, cache, prev_tok[:, None])
@@ -226,4 +226,5 @@ def make_draft_round(draft: DraftModel, max_len: int, k: int):
             drafts.append(cur)
         return cache["layers"], jnp.stack(drafts, axis=1)
 
-    return jax.jit(round_, donate_argnums=(1,))
+    # the function name names the program in a profiler trace
+    return jax.jit(engine_draft, donate_argnums=(1,))

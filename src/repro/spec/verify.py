@@ -75,21 +75,17 @@ def make_verify_step(model, max_len: int, k: int, *, paged: bool = False,
             out = out + (ok,)
         return out
 
-    if guard:
-        if paged:
-            fn = jax.jit(lambda params, layers, table, pos, window, mask:
-                         verify(params, layers, pos, window, table, mask),
-                         donate_argnums=(1,))
-        else:
-            fn = jax.jit(lambda params, layers, pos, window, mask:
-                         verify(params, layers, pos, window, None, mask),
-                         donate_argnums=(1,))
+    # the function name names the program in a profiler trace
+    if paged and guard:
+        def engine_verify(params, layers, table, pos, window, mask):
+            return verify(params, layers, pos, window, table, mask)
     elif paged:
-        fn = jax.jit(lambda params, layers, table, pos, window:
-                     verify(params, layers, pos, window, table),
-                     donate_argnums=(1,))
+        def engine_verify(params, layers, table, pos, window):
+            return verify(params, layers, pos, window, table)
+    elif guard:
+        def engine_verify(params, layers, pos, window, mask):
+            return verify(params, layers, pos, window, None, mask)
     else:
-        fn = jax.jit(lambda params, layers, pos, window:
-                     verify(params, layers, pos, window),
-                     donate_argnums=(1,))
-    return fn
+        def engine_verify(params, layers, pos, window):
+            return verify(params, layers, pos, window)
+    return jax.jit(engine_verify, donate_argnums=(1,))

@@ -39,7 +39,7 @@ GOLDEN = {
     "repro.kernels": {
         "ternary_gemm", "ternary_gemm_plan", "GemmPlan",
         "register_kernel", "kernel_registry", "serving_phase",
-        "SERVING_PHASES", "kernel_probe",
+        "SERVING_PHASES",
         "fused_mlp", "fused_mlp_plan", "FusedMlpPlan",
         "register_fused", "fused_registry", "precompute_fused_plans",
         "fused_mlp_pallas",
@@ -75,7 +75,7 @@ GOLDEN = {
                          "CheckpointCorruptError"},
     "repro.obs": {
         "clock", "trace", "metrics",
-        "Tracer", "load_trace", "validate_events",
+        "Tracer", "phase", "load_trace", "validate_events",
         "MetricsRegistry", "Counter", "Gauge", "Histogram", "Ewma",
         "RunningStat", "percentiles",
     },

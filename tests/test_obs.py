@@ -2,7 +2,9 @@
 ring-buffer tracer and its Perfetto-loadable export, the metrics
 registry, the golden metrics-JSON schema (byte-compatibility lock for
 ``run()``/``collect_metrics``/``run_open_loop``), trace-vs-metrics
-TTFT/TPOT agreement, and the kernel probe."""
+TTFT/TPOT agreement, the engine's phase spans in a profiler trace and
+their reduction (``obs.xplane``), and the row counters."""
+import collections
 import json
 
 import jax
@@ -214,7 +216,8 @@ TOP_LEVEL_KEYS = {
     "engine", "max_slots", "max_len", "mesh", "cache", "spec",
     "concurrency", "planned_gemms", "per_request", "submitted", "drained",
     "generated_tokens", "wall_s", "tok_per_s", "prefill_steps",
-    "decode_steps", "ttft_s", "latency", "sched", "queue_depth", "faults",
+    "decode_steps", "rows", "ttft_s", "latency", "sched", "queue_depth",
+    "faults",
 }
 PER_REQUEST_KEYS = {
     "rid", "prompt_len", "gen_len", "ttft_s", "queue_wait_s", "prefill_s",
@@ -257,6 +260,9 @@ def test_metrics_json_golden_schema(drained):
     assert set(m["queue_depth"]) == {"max", "mean"}
     assert set(m["concurrency"]) == {"peak", "mean"}
     assert m["cache"]["mode"] == "dense" and "nbytes" in m["cache"]
+    assert set(m["rows"]) == {"prefill", "chunk", "decode", "verify"}
+    for block in m["rows"].values():
+        assert set(block) == {"computed", "real"}
     json.dumps(m)                                 # serializable end-to-end
 
 
@@ -363,9 +369,18 @@ def test_engine_kernel_spans_emitted(traced_run):
     _, metrics, doc = traced_run
     evs = doc["traceEvents"]
     decode_spans = [e for e in evs
-                    if e["ph"] == "X" and e["name"] == "decode_step"]
+                    if e["ph"] == "X" and e["name"] == "engine.decode"]
     assert len(decode_spans) == metrics["decode_steps"]
     assert all(e["tid"] == 0 for e in decode_spans)
+    # every phase span nests inside an engine.step span
+    steps = [(e["ts"], e["ts"] + e["dur"]) for e in evs
+             if e["ph"] == "X" and e["name"] == "engine.step"]
+    phases = [e for e in evs if e["ph"] == "X" and e["tid"] == 0
+              and e["name"].startswith("engine.")
+              and e["name"] != "engine.step"]
+    assert phases and all(
+        any(a <= e["ts"] and e["ts"] + e["dur"] <= b for a, b in steps)
+        for e in phases)
     counters = [e for e in evs if e["ph"] == "C" and e["name"] == "sched"]
     assert counters and all(
         {"queue_depth", "live_slots", "prefilling"} <= set(e["args"])
@@ -384,7 +399,7 @@ def test_trace_report_end_to_end(traced_run, tmp_path):
     with open(path, "w") as f:
         json.dump(doc, f)
     rep = trace_report.report(path)
-    assert rep["step_breakdown"]["decode_step"]["n"] == \
+    assert rep["step_breakdown"]["engine.decode"]["n"] == \
         metrics["decode_steps"]
     il = rep["interleave"]
     assert 0.0 < il["busy_frac"] <= 1.0
@@ -398,41 +413,209 @@ def test_trace_report_end_to_end(traced_run, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# kernel probe
+# engine phases: row counters, profiler spans, tracer parity
 # ---------------------------------------------------------------------------
 
-def test_kernel_probe_times_eager_dispatch():
-    from repro.core import weights
-    from repro.kernels import ops
-    rng = np.random.default_rng(0)
-    w = weights.pack(rng.integers(-1, 2, size=(64, 32)).astype(np.int8))
-    x = np.asarray(rng.normal(size=(4, 64)), np.float32)
-    seen = []
-    with ops.kernel_probe(lambda plan, dt: seen.append((plan, dt))):
-        y1 = ops.ternary_gemm(jax.numpy.asarray(x), w)
-    assert len(seen) == 1
-    plan, dt = seen[0]
-    assert plan.m == 4 and dt > 0
-    assert "model_time_s" in plan.roofline()
-    # same dispatch outside the scope: no callback, identical result
-    y2 = ops.ternary_gemm(jax.numpy.asarray(x), w)
-    np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
-    assert len(seen) == 1
+def _phase_engine(mode="chunked", tracer=None):
+    """A small paged engine: chunked prefill, or grouped admission with a
+    k=2 speculative round."""
+    from repro.serving.sched import SchedConfig
+    from repro.spec import SpecConfig
+    cfg = get_config("ternary-paper", reduced=True, num_layers=4)
+    kw = ({"sched": SchedConfig(chunk_tokens=8, admission="fifo")}
+          if mode == "chunked" else
+          {"spec": SpecConfig(draft="layer_skip", k=2, draft_layers=2)})
+    eng = ContinuousScheduler(cfg, max_slots=3, max_len=48, cache="paged",
+                              page_size=8, n_pages=24, tracer=tracer, **kw)
+    eng.load(eng.model.init(jax.random.PRNGKey(0)))
+    return eng
 
 
-def test_kernel_probe_skips_traced_dispatch():
-    """Under jit tracing there is no wall time to measure — the probe
-    must not fire (and must not bake a callback into the jaxpr)."""
-    from repro.core import weights
-    from repro.kernels import ops
-    rng = np.random.default_rng(0)
-    w = weights.pack(rng.integers(-1, 2, size=(64, 32)).astype(np.int8))
-    x = np.asarray(rng.normal(size=(4, 64)), np.float32)
-    seen = []
-    fn = jax.jit(lambda a: ops.ternary_gemm(a, w))
-    with ops.kernel_probe(lambda plan, dt: seen.append(dt)):
-        fn(x).block_until_ready()
-    assert seen == []
+PHASE_PROMPTS = (13, 5, 20, 9)
+PHASE_GENS = (3, 6, 2, 4)
+
+
+def _submit_phase_work(eng):
+    return [eng.submit((np.arange(n, dtype=np.int32) * 7 + n) % 50 + 1, g)
+            for n, g in zip(PHASE_PROMPTS, PHASE_GENS)]
+
+
+def test_row_counters_count_window_and_decode_rows():
+    eng = _phase_engine()
+    windows = []
+    pack = eng._chunker.pack_window
+
+    def spy(jobs):
+        out = pack(jobs)
+        windows.append((out[2].shape, sum(c for _, _, c in jobs)))
+        return out
+
+    eng._chunker.pack_window = spy
+    reqs = _submit_phase_work(eng)
+    m = eng.run()
+    rows = m["rows"]
+    # a window computes rows x S, pad rows included; its real rows are
+    # the chunk lengths, which add up to every prompt once
+    assert all(r == eng.max_slots for (r, _), _ in windows)
+    assert rows["chunk"]["computed"] == sum(r * s for (r, s), _ in windows)
+    assert rows["chunk"]["real"] == sum(c for _, c in windows) \
+        == sum(PHASE_PROMPTS)
+    # a decode step computes every slot; real rows committed a token (a
+    # request's first token comes from its last chunk)
+    assert rows["decode"]["computed"] == eng.max_slots * m["decode_steps"]
+    assert rows["decode"]["real"] == sum(len(r.tokens) - 1 for r in reqs)
+    assert rows["prefill"] == rows["verify"] == {"computed": 0, "real": 0}
+    snap = eng.metrics.snapshot()
+    assert snap["rows_computed.chunk"] == rows["chunk"]["computed"]
+    assert snap["rows_real.decode"] == rows["decode"]["real"]
+
+
+def test_row_counters_grouped_prefill_and_verify():
+    eng = _phase_engine("spec")
+    reqs = _submit_phase_work(eng)
+    m = eng.run()
+    rows, k = m["rows"], eng.spec.k
+    assert rows["prefill"] == {"computed": sum(PHASE_PROMPTS),
+                               "real": sum(PHASE_PROMPTS)}
+    assert rows["verify"]["computed"] == (eng.max_slots * (k + 1)
+                                          * m["spec"]["rounds"])
+    # every token after the first comes out of a verify window
+    assert rows["verify"]["real"] == sum(len(r.tokens) - 1 for r in reqs)
+    assert rows["verify"]["real"] < rows["verify"]["computed"]
+
+
+@pytest.mark.parametrize("mode", ["chunked", "spec"])
+def test_tracer_changes_no_tokens_and_no_device_reads(mode, monkeypatch):
+    """With a Tracer the engine makes the same tokens with the same
+    device-to-host reads and syncs in every step as with tracer=None."""
+    counts = {"reads": 0, "syncs": 0}
+    to_host, sync = np.asarray, jax.block_until_ready
+
+    def counting_read(x, *a, **kw):
+        counts["reads"] += isinstance(x, jax.Array)
+        return to_host(x, *a, **kw)
+
+    def counting_sync(x):
+        counts["syncs"] += 1
+        return sync(x)
+
+    monkeypatch.setattr(np, "asarray", counting_read)
+    monkeypatch.setattr(jax, "block_until_ready", counting_sync)
+    runs = []
+    for tracer in (None, Tracer()):
+        eng = _phase_engine(mode, tracer=tracer)
+        reqs = _submit_phase_work(eng)
+        per_step = []
+        while eng.has_work():
+            before = dict(counts)
+            eng.step()
+            per_step.append((counts["reads"] - before["reads"],
+                             counts["syncs"] - before["syncs"]))
+        runs.append(([list(r.tokens) for r in reqs], per_step))
+    assert runs[0][1] and all(r > 0 for r, _ in runs[0][1])
+    assert runs[0] == runs[1]
+    assert len(tracer) > 0
+
+
+def test_engine_phase_spans_reach_a_profiler_trace(tmp_path):
+    """On the CPU too the engine's spans land in the jax.profiler trace,
+    nested in one engine.step per step, and obs.xplane reads them."""
+    from repro.obs import xplane
+    eng = _phase_engine()
+    _submit_phase_work(eng)
+    eng.step()                         # outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        steps = 0
+        while eng.has_work():
+            eng.step()
+            steps += 1
+    finally:
+        jax.profiler.stop_trace()
+    prof = xplane.read(str(tmp_path))
+    names = collections.Counter(n for _, _, n in prof.host)
+    assert names["engine.step"] == steps
+    assert names["engine.decode"] == names["engine.decode_readback"] > 0
+    assert names["engine.chunk_window"] == names["engine.chunk_readback"] > 0
+    for n in ("engine.admit", "engine.plan_chunks", "engine.grow_pages",
+              "engine.upload", "engine.commit"):
+        assert names[n] > 0, n
+    step_spans = [(a, b) for a, b, n in prof.host if n == "engine.step"]
+    for a, b, n in prof.host:
+        if n != "engine.step":
+            assert any(s <= a and b <= e for s, e in step_spans), n
+    rep = xplane.summary(prof)
+    assert rep["host_spans"]["engine.step"]["n"] == steps
+    assert len(xplane.step_self_times(prof.host)) == steps
+    assert 0 < rep["host_step_ms"] <= rep["host_spans"]["engine.step"][
+        "total_s"] * 1e3
+
+
+MS = 1_000_000
+
+
+def _synthetic_profile():
+    """Two steps on one chip: a chunk window whose device run has a
+    bubble inside it, then a decode whose readback waits on the device,
+    then a stretch with the host between steps."""
+    from repro.obs import xplane
+    chip = "/device:TPU:0"
+    modules = {chip: [(0, 40 * MS, "jit_engine_chunk_window(812)"),
+                      (50 * MS, 60 * MS, "jit_engine_decode(77)"),
+                      (75 * MS, 80 * MS, "jit__insert_impl(5)")]}
+    ops = {chip: [(0, 10 * MS, "%fusion.1 = f32[] fusion()"),
+                  (20 * MS, 40 * MS, "%paged_decode_attention.2 = x"),
+                  (50 * MS, 60 * MS, "%ternary_gemm_dense.3 = x"),
+                  (75 * MS, 80 * MS, "%copy.4 = x")]}
+    host = [(0, 70 * MS, "engine.step"),
+            (0, 2 * MS, "engine.chunk_window"),
+            (2 * MS, 41 * MS, "engine.chunk_readback"),
+            (41 * MS, 50 * MS, "engine.commit"),
+            (50 * MS, 51 * MS, "engine.decode"),
+            (51 * MS, 61 * MS, "engine.decode_readback"),
+            (61 * MS, 65 * MS, "engine.commit"),
+            (70 * MS, 100 * MS, "engine.step"),
+            (72 * MS, 74 * MS, "engine.upload")]
+    return xplane.Profile(host, modules, ops)
+
+
+def test_xplane_program_times_by_phase():
+    from repro.obs import xplane
+    prof = _synthetic_profile()
+    times = xplane.program_times(prof.modules["/device:TPU:0"])
+    assert times == {"engine_chunk_window": [0.040],
+                     "engine_decode": [0.010],
+                     "jit__insert_impl": [0.005]}
+    rep = xplane.summary(prof)
+    assert rep["programs"]["engine_chunk_window"]["median_ms"] == \
+        pytest.approx(40.0)
+    assert rep["window_s"] == pytest.approx(0.100)
+    assert rep["busy_s"] == pytest.approx(0.045)
+
+
+def test_xplane_step_self_time_leaves_out_readbacks():
+    from repro.obs import xplane
+    prof = _synthetic_profile()
+    # step 1: 70 ms less 39 + 10 ms of readbacks; step 2: 30 ms
+    assert xplane.step_self_times(prof.host) == pytest.approx([0.021,
+                                                               0.030])
+    assert xplane.summary(prof)["host_step_ms"] == pytest.approx(21.0)
+
+
+def test_xplane_gaps_go_to_the_program_or_the_innermost_span():
+    from repro.obs import xplane
+    prof = _synthetic_profile()
+    gaps = {label.split(" (")[0] if not label.startswith("in-program")
+            else label.rsplit(" (", 1)[0]: s
+            for label, s in xplane.idle_gaps(
+                prof.ops["/device:TPU:0"], prof.modules["/device:TPU:0"],
+                prof.host, 0, 100 * MS)}
+    # 10-20 ms: a bubble inside the chunk window's program, not host time
+    assert gaps["in-program (engine_chunk_window)"] == pytest.approx(0.010)
+    # 40-50 ms: the host commits the window; 60-75 and 80-100 ms have
+    # their middles in a step but in none of its phases
+    assert gaps["engine.commit"] == pytest.approx(0.010)
+    assert gaps["engine.step"] == pytest.approx(0.015 + 0.020)
 
 
 # ---------------------------------------------------------------------------

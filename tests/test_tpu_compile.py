@@ -7,8 +7,16 @@ accepts (3-D vector ops, 8-bit iotas, casts Mosaic lacks, too much VMEM).
 The topology is described inside a module fixture, never at import, so
 every test worker collects the same tests and only the one running this
 file loads the TPU library; where it cannot be described, the tests skip.
+
+Each compiled kernel's instruction name (its ``pallas_call`` name) is also
+checked against the benchmark's kernel classes (``bench/kernels/``), the
+patterns a device trace's kernel time is summed by.
 """
+import glob
 import importlib
+import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +66,26 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+KERNEL_CLASSES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                              os.pardir, "bench", "kernels")
+
+
+def _kernel_classes(compiled):
+    """The class of each Pallas call in ``compiled``: the first class file
+    (in name order) with a pattern in the call's instruction name."""
+    classes = []
+    for f in sorted(glob.glob(os.path.join(KERNEL_CLASSES, "*.json"))):
+        with open(f) as fh:
+            pats = json.load(fh)["patterns"]
+        classes.append((os.path.basename(f)[:-5], re.compile("|".join(pats))))
+    names = [re.match(r"\s*(?:ROOT\s+)?%?([^\s=]+)\s*=", ln).group(1)
+             for ln in compiled.as_text().splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert names
+    return {next((c for c, pat in classes if pat.search(n)), None)
+            for n in names}
+
+
 # every block shape the autotuner can pick (decode widens the grid)
 BLOCKS = sorted(set(autotune.CANDIDATE_BLOCKS
                     + autotune.DECODE_CANDIDATE_BLOCKS))
@@ -69,8 +97,9 @@ def test_dense_compiles_at_autotuner_blocks(sds, bm, bn, bk):
     def f(x, w, s):
         return tg.ternary_gemm_pallas(x, w, s, None, block_m=bm, block_n=bn,
                                       block_k=bk, interpret=False)
-    _compile(f, sds((bm, K), BF16), sds((K // 16, N), U32),
-             sds((N,), jnp.float32))
+    compiled = _compile(f, sds((bm, K), BF16), sds((K // 16, N), U32),
+                        sds((N,), jnp.float32))
+    assert _kernel_classes(compiled) == {"gemm"}
 
 
 @pytest.mark.parametrize("m", [8, 128])
@@ -83,9 +112,10 @@ def test_skip_compiles(sds, kernel, m):
     def f(x, w, idx, cnt, s):
         return fn(x, w, idx, cnt, s, None, block_m=m, block_n=bn,
                   block_k=bk, interpret=False)
-    _compile(f, sds((m, K), BF16), sds((K // 16, N), U32),
-             sds((N // bn, K // bk), jnp.int32), sds((N // bn,), jnp.int32),
-             sds((N,), jnp.float32))
+    compiled = _compile(f, sds((m, K), BF16), sds((K // 16, N), U32),
+                        sds((N // bn, K // bk), jnp.int32),
+                        sds((N // bn,), jnp.int32), sds((N,), jnp.float32))
+    assert _kernel_classes(compiled) == {"gemm"}
 
 
 @pytest.mark.parametrize("m", [8, 128])
@@ -95,10 +125,11 @@ def test_fused_mlp_gated_compiles(sds, m):
                                    scale_g=sg, n=K, ff=FF, block_m=m,
                                    block_k1=512, block_k2=512,
                                    interpret=False)
-    _compile(f, sds((m, K), BF16), sds((K // 16, FF), U32),
-             sds((FF // 16, K), U32), sds((K // 16, FF), U32),
-             sds((FF,), jnp.float32), sds((K,), jnp.float32),
-             sds((FF,), jnp.float32))
+    compiled = _compile(f, sds((m, K), BF16), sds((K // 16, FF), U32),
+                        sds((FF // 16, K), U32), sds((K // 16, FF), U32),
+                        sds((FF,), jnp.float32), sds((K,), jnp.float32),
+                        sds((FF,), jnp.float32))
+    assert _kernel_classes(compiled) == {"gemm"}
 
 
 @pytest.mark.parametrize("factorized", [False, True])
@@ -108,8 +139,9 @@ def test_bitplane_compiles(sds, factorized):
                                         block_n=128, block_k=512,
                                         factorized=factorized,
                                         interpret=False)
-    _compile(f, sds((128, K), BF16), sds((K // 8, N), jnp.uint8),
-             sds((K // 8, N), jnp.uint8), sds((N,), jnp.float32))
+    compiled = _compile(f, sds((128, K), BF16), sds((K // 8, N), jnp.uint8),
+                        sds((K // 8, N), jnp.uint8), sds((N,), jnp.float32))
+    assert _kernel_classes(compiled) == {"gemm"}
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
@@ -135,7 +167,7 @@ def test_paged_attention_compiles_at_max_len_1024(sds, kv_dtype):
                                                     interpret=False)
         compiled = _compile(f, q, sds(pages, BF16), sds(pages, BF16), table,
                             lengths)
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernel_classes(compiled) == {"attn"}
 
 
 def test_tp_sharded_packed_linear_runs_per_shard(topo):
