@@ -104,10 +104,12 @@ def prefill_logits(cfg, params, prompts, mesh=None):
 
 
 def check_paged_attention(cfg, seed: int) -> None:
-    """The Pallas paged kernel against the jax gather lowering, at the
-    engine's shapes: every slot at a different length up to max_len."""
+    """The Pallas paged kernels against the jax gather lowering, at the
+    engine's shapes: every slot at a different length up to max_len, one
+    token a row (decode) and a window of tokens a row (chunked prefill)."""
     import jax
     import jax.numpy as jnp
+    from repro.kernels import ops as kops
     from repro.paging import kernels as pk
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     pages_per_row = MAX_LEN // PAGE_SIZE
@@ -125,6 +127,16 @@ def check_paged_attention(cfg, seed: int) -> None:
             pk.paged_decode_attention_jax(q, k_pages, v_pages, table,
                                           lengths),
             f"paged attention pallas vs jax, lengths {lengths.tolist()}")
+    # a chunk window of S tokens a row, against its flattened rows
+    s = 2 * PAGE_SIZE
+    qw = jax.random.normal(kq, (SLOTS, s, cfg.num_heads, cfg.head_dim),
+                           jnp.bfloat16)
+    first = jnp.minimum(lengths, MAX_LEN - s + 1)
+    compare(kops.paged_window_attention(qw, k_pages, v_pages, table, first,
+                                        impl="pallas"),
+            kops.paged_window_attention(qw, k_pages, v_pages, table, first,
+                                        impl="jax"),
+            f"paged window attention ({s} tokens) pallas vs jax")
 
 
 def compare(got, ref, label: str) -> None:

@@ -6,7 +6,8 @@ from repro.kernels.ops import (SERVING_PHASES, FusedMlpPlan, GemmPlan,
                                fused_mlp, fused_mlp_plan, fused_registry,
                                kernel_registry,
                                paged_attention_registry,
-                               paged_decode_attention, pack_weights,
+                               paged_decode_attention,
+                               paged_window_attention, pack_weights,
                                pack_weights_tiled, precompute_fused_plans,
                                register_fused, register_kernel,
                                register_paged_attn, serving_phase,
@@ -27,6 +28,6 @@ __all__ = ["ternary_gemm", "ternary_gemm_plan", "GemmPlan",
            "ternary_gemm_pallas", "ternary_gemm_skip_pallas",
            "ternary_gemm_skip_db_pallas",
            "ternary_gemm_bitplane", "K_PER_WORD", "flash_attention_pallas",
-           "paged_decode_attention", "register_paged_attn",
-           "paged_attention_registry",
+           "paged_decode_attention", "paged_window_attention",
+           "register_paged_attn", "paged_attention_registry",
            "Autotuner", "BlockConfig", "FusedBlockConfig", "get_tuner"]

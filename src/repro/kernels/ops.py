@@ -79,8 +79,9 @@ __all__ = ["ternary_gemm", "ternary_gemm_plan", "GemmPlan", "KernelImpl",
            "serving_phase", "current_phase", "SERVING_PHASES",
            "tensor_parallel", "current_tp_mesh",
            "SKIP_OCCUPANCY_CUTOFF",
-           "paged_decode_attention", "register_paged_attn",
-           "paged_attention_registry", "resolve_paged_attn"]
+           "paged_decode_attention", "paged_window_attention",
+           "register_paged_attn", "paged_attention_registry",
+           "resolve_paged_attn"]
 
 # Serving-phase tag consumed at trace time: prefill GEMMs are M=B·L
 # GEMM-shaped, decode GEMMs are M=slots GEMV-shaped, verify GEMMs
@@ -652,22 +653,26 @@ class PagedAttnImpl:
     priority: int
     predicate: Callable[..., bool]
     fn: Callable
+    window_fn: Optional[Callable] = None
 
 
 _PAGED_ATTN: Dict[str, PagedAttnImpl] = {}
 
 
 def register_paged_attn(impl: str, *, priority: int = 0,
-                        predicate: Optional[Callable] = None):
+                        predicate: Optional[Callable] = None,
+                        window: Optional[Callable] = None):
     """Decorator registering a paged decode-attention lowering under
     ``impl``. ``predicate()`` says whether the lowering is admissible on
     the current backend; it gates ``impl="auto"`` selection (highest
-    admissible priority wins)."""
+    admissible priority wins). ``window``, where given, is the lowering's
+    multi-token window form (``paged_window_attention``); without it a
+    window flattens into single-query rows of ``fn``."""
 
     def deco(fn):
         _PAGED_ATTN[impl] = PagedAttnImpl(
             impl=impl, priority=priority,
-            predicate=predicate or (lambda: True), fn=fn)
+            predicate=predicate or (lambda: True), fn=fn, window_fn=window)
         return fn
 
     return deco
@@ -729,16 +734,49 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, lengths, *,
                      window=window, interpret=interpret)
 
 
+def paged_window_attention(q, k_pages, v_pages, block_table, lengths, *,
+                           window: int = 0, impl: str = "auto",
+                           interpret: Optional[bool] = None):
+    """Attention of an S-token window per row over block-table-indexed KV
+    pages (chunked prefill, speculative verify).
+
+    q (B, S, H, hd); pages and block_table (B, T) as in
+    ``paged_decode_attention``; lengths (B,) int32 valid-token count of
+    each row's first window token, whose K/V is already in the pages:
+    token ``j`` attends the keys at positions ``< lengths + j``. Returns
+    (B, S, H, hd). A lowering with a window form (the Pallas kernel) walks
+    each row's pages once for all S tokens; one without (the ``jax``
+    gather) runs the window as (B·S) single-query rows, each row's block
+    table repeated."""
+    chosen = _PAGED_ATTN[resolve_paged_attn(impl)]
+    if chosen.window_fn is None:
+        b, s = q.shape[:2]
+        o = paged_decode_attention(
+            q.reshape(b * s, *q.shape[2:]), k_pages, v_pages,
+            jnp.repeat(block_table, s, axis=0),
+            (lengths[:, None] + jnp.arange(s)).reshape(-1), window=window,
+            impl=chosen.impl, interpret=interpret)
+        return o.reshape(q.shape)
+    mesh = current_tp_mesh()
+    if mesh is not None:
+        return _tp_paged_attention(mesh, chosen.window_fn, q, k_pages,
+                                   v_pages, block_table, lengths,
+                                   window=window, interpret=interpret)
+    return chosen.window_fn(q, k_pages, v_pages, block_table, lengths,
+                            window=window, interpret=interpret)
+
+
 def _tp_paged_attention(mesh, fn, q, k_pages, v_pages, block_table, lengths,
                         *, window, interpret):
-    """A Pallas paged lowering per shard: q heads and the pages' KV-head
-    axis split over ``"model"`` (the layout ``distributed.tp.
+    """A Pallas paged lowering per shard: q heads (the axis before
+    ``head_dim``, for decode rows and windows alike) and the pages'
+    KV-head axis split over ``"model"`` (the layout ``distributed.tp.
     cache_sharding`` places), block table and lengths replicated."""
     ntp = dict(mesh.shape)["model"]
     kv = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
-    if q.shape[1] % ntp or kv % ntp:
+    if q.shape[-2] % ntp or kv % ntp:
         raise ValueError(
-            f"paged attention: {q.shape[1]} query / {kv} KV heads do not "
+            f"paged attention: {q.shape[-2]} query / {kv} KV heads do not "
             f"split {ntp} ways over the mesh's 'model' axis")
 
     def page_spec(a):
@@ -746,7 +784,7 @@ def _tp_paged_attention(mesh, fn, q, k_pages, v_pages, block_table, lengths,
         return P(None, None, "model", None) if a.ndim == 4 \
             else P(None, None, "model")
 
-    heads = P(None, "model", None)
+    heads = P(*[None] * (q.ndim - 2), "model", None)
     return jax.shard_map(
         lambda qq, kk, vv, bt, ln: fn(qq, kk, vv, bt, ln, window=window,
                                       interpret=interpret),

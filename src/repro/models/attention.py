@@ -256,7 +256,7 @@ def attn_apply(params, x: jnp.ndarray, cfg: ModelConfig, *,
     * paged decode: cache = {"k_pages", "v_pages"} + block_table (B, T) +
       cache_pos (B,) vector (DESIGN.md §9); prefill never sees a paged
       cache — the page pool scatters prefilled dense rows into pages.
-      Multi-token verify windows flatten to a (B·S) row batch.
+      Multi-token windows attend through ``kops.paged_window_attention``.
     * cross-attention: kv_override = (k, v) precomputed from the encoder.
     """
     kv, hd = cfg.num_kv_heads, cfg.head_dim
@@ -476,11 +476,13 @@ def _paged_decode(params, x, cfg: ModelConfig, q, k, v, cache,
     guarantees this); free slots' block tables are all-zero, so their
     garbage writes land in the reserved trash page 0 and are never read.
 
-    A multi-token verify window (S > 1, DESIGN.md §10) scatters all S
-    tokens first — the engine's ``ensure_append`` horizon made every page
-    in positions cache_pos..cache_pos+S-1 privately owned — then flattens
-    the window into a (B·S) row batch whose per-row ``lengths`` encode
-    causality within the window (token j sees valid tokens < pos+j+1).
+    A multi-token window (S > 1: speculative verify, DESIGN.md §10, and
+    chunked prefill, §14) scatters all S tokens first — the engine's
+    ``ensure_append`` horizon made every page in positions
+    cache_pos..cache_pos+S-1 privately owned — then attends through
+    ``kops.paged_window_attention``: token j sees valid tokens < pos+j+1.
+    The Pallas lowering walks each row's pages once for all S tokens; the
+    ``jax`` lowering flattens the window into (B·S) single-query rows.
     """
     from repro.paging.quant import Int8Pages, quantize_rows
 
@@ -525,11 +527,10 @@ def _paged_decode(params, x, cfg: ModelConfig, q, k, v, cache,
         else:
             k_pages = k_pages.at[pids, offs].set(k.astype(k_pages.dtype))
             v_pages = v_pages.at[pids, offs].set(v.astype(v_pages.dtype))
-        o = kops.paged_decode_attention(
-            q.reshape(b * sq, h, cfg.head_dim), k_pages, v_pages,
-            jnp.repeat(block_table, sq, axis=0), (pos2d + 1).reshape(-1),
-            window=cfg.sliding_window, impl=cfg.paged_attn_impl)
-        o_seq = o.reshape(b, sq, h, cfg.head_dim)
+        o_seq = kops.paged_window_attention(
+            q, k_pages, v_pages, block_table,
+            jnp.broadcast_to(pos + 1, (b,)), window=cfg.sliding_window,
+            impl=cfg.paged_attn_impl)
     y = linear_apply(params["o"],
                      o_seq.reshape(*x.shape[:-1], h * cfg.head_dim),
                      cfg)

@@ -32,7 +32,10 @@ that exist are (max_slots, pow2), and ``warmup`` compiles them all at
 The forward traces under ``ops.serving_phase("chunk")``: flattened GEMM
 M = P·S rows — bigger than decode's GEMV, smaller than a grouped
 prefill — gets its own autotune phase so chunk plans never thrash the
-decode or prefill entries.
+decode or prefill entries. Paged attention is not flattened on the chip:
+``ops.paged_window_attention`` walks each row's pages once for all S
+tokens (one grid step per row and page); off the chip its ``jax``
+lowering runs the window as P·S single-query rows.
 """
 from __future__ import annotations
 

@@ -47,8 +47,8 @@ GOLDEN = {
         "ternary_gemm_pallas", "ternary_gemm_skip_pallas",
         "ternary_gemm_skip_db_pallas",
         "ternary_gemm_bitplane", "K_PER_WORD", "flash_attention_pallas",
-        "paged_decode_attention", "register_paged_attn",
-        "paged_attention_registry",
+        "paged_decode_attention", "paged_window_attention",
+        "register_paged_attn", "paged_attention_registry",
         "Autotuner", "BlockConfig", "FusedBlockConfig", "get_tuner",
     },
     "repro.serving": {

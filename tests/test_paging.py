@@ -3,7 +3,9 @@
 Pins the three correctness contracts:
 
 1. the Pallas paged decode-attention kernel is **bit-exact** vs its
-   pure-JAX reference across page-size / window / GQA / kv-dtype variants;
+   pure-JAX reference across page-size / window / GQA / kv-dtype variants,
+   and the Pallas window kernel matches that reference over the window's
+   flattened rows;
 2. the ``jax`` lowering (the engine's off-TPU path) is **bit-identical**
    to ``models.attention.naive_attention`` on the gathered cache — the
    foundation of the paged-vs-dense token-exactness guarantee;
@@ -26,9 +28,11 @@ from repro.kernels import ops as kops
 from repro.launch import serve
 from repro.models import LM, attention
 from repro.paging import Int8Pages, PagePool, PrefixCache, page_keys
+from repro.paging import kernels as pk
 from repro.paging.kernels import (paged_decode_attention_jax,
                                   paged_decode_attention_pallas,
-                                  paged_decode_attention_ref)
+                                  paged_decode_attention_ref,
+                                  paged_window_attention_pallas)
 from repro.serving import ContinuousScheduler, SlotPool
 
 
@@ -88,6 +92,130 @@ def test_paged_kernel_bitexact_vs_ref(heads, kv_heads, page_size, window,
                                          window=window)
     np.testing.assert_allclose(np.asarray(out_jax), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def _window_case(heads, kv_heads, page_size, s, kv_dtype, seed=0):
+    """A (B=3, S) window over 4 pages a row: row 0 mid-sequence, row 1
+    ending on the last page, row 2 a pad row (all-zeros block table, the
+    window at position 0, as the chunker packs it)."""
+    rng = np.random.default_rng(seed)
+    b, p, t, hd = 3, 10, 4, 16
+    q = jnp.asarray(rng.standard_normal((b, s, heads, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((p, page_size, kv_heads, hd)),
+                     jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((p, page_size, kv_heads, hd)),
+                     jnp.float32)
+    if kv_dtype == "int8":
+        kp, vp = Int8Pages.quantize(kp), Int8Pages.quantize(vp)
+    elif kv_dtype == "bf16":
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    table = jnp.asarray(rng.integers(1, p, size=(b, t)), jnp.int32)
+    table = table.at[2].set(0)
+    lengths = jnp.asarray([page_size + 2, t * page_size - s + 1, 1],
+                          jnp.int32)
+    return q, kp, vp, table, lengths
+
+
+def _flat_ref(q, kp, vp, table, lengths, window):
+    """The window as (B·S) single-query rows through the decode
+    kernel's reference: token j of row b has ``lengths[b] + j`` keys."""
+    b, s, h, hd = q.shape
+    return paged_decode_attention_ref(
+        q.reshape(b * s, h, hd), kp, vp, jnp.repeat(table, s, axis=0),
+        (lengths[:, None] + jnp.arange(s)).reshape(-1),
+        window=window).reshape(q.shape)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 4), (4, 2)])
+@pytest.mark.parametrize("page_size,s", [(4, 3), (8, 5)])
+@pytest.mark.parametrize("window", [0, 5])
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+def test_paged_window_kernel_matches_flattened_ref(heads, kv_heads,
+                                                   page_size, s, window,
+                                                   kv_dtype):
+    """The window kernel (interpret off-TPU) == the decode reference over
+    the B·S flattened rows: GQA groups of 1 and 2, windows that cross a
+    page boundary with S not dividing the page size, a pad row, sliding
+    windows, f32 and int8 pages. Each (row, page) does all S tokens'
+    matmuls at once, so accumulation order may differ in the last bit."""
+    q, kp, vp, table, lengths = _window_case(heads, kv_heads, page_size, s,
+                                             kv_dtype)
+    out = paged_window_attention_pallas(q, kp, vp, table, lengths,
+                                        window=window)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_flat_ref(q, kp, vp, table, lengths,
+                                              window)),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_paged_window_kernel_query_tiles(monkeypatch):
+    """Past the VMEM budget the query axis splits into tiles (grid
+    (B, tiles, T)); each tile's page walk and causal offsets still match
+    the flattened reference."""
+    monkeypatch.setattr(pk, "WINDOW_VMEM_BUDGET", 1)
+    assert pk._window_tile(8, 4, 2, 16) == 4       # 16 rows a tile
+    assert pk._window_tile(5, 2, 2, 16) == 5       # no aligned divisor
+    q, kp, vp, table, lengths = _window_case(8, 2, 4, 8, "f32", seed=3)
+    lengths = lengths.at[1].set(6)
+    for window in (0, 5):
+        out = paged_window_attention_pallas(q, kp, vp, table, lengths,
+                                            window=window)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_flat_ref(q, kp, vp, table,
+                                                  lengths, window)),
+            rtol=2e-5, atol=2e-5)
+
+
+def test_paged_window_jax_lowering_is_the_flattened_decode():
+    """The ``jax`` lowering has no window form: the window entry runs
+    today's flatten (block table repeated, lengths + j) through
+    ``paged_decode_attention_jax``, bit for bit — what paged-vs-dense
+    token exactness on CPU rests on. The Pallas entry agrees to 2e-5."""
+    reg = kops.paged_attention_registry()
+    assert reg["jax"].window_fn is None
+    assert reg["pallas"].window_fn is paged_window_attention_pallas
+    q, kp, vp, table, lengths = _window_case(4, 2, 4, 3, "bf16", seed=1)
+    q = q.astype(jnp.bfloat16)
+    b, s, h, hd = q.shape
+    got = kops.paged_window_attention(q, kp, vp, table, lengths, impl="jax")
+    want = paged_decode_attention_jax(
+        q.reshape(b * s, h, hd), kp, vp, jnp.repeat(table, s, axis=0),
+        (lengths[:, None] + jnp.arange(s)).reshape(-1)).reshape(q.shape)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # f32 pages: the jax lowering rounds probabilities to the pages' dtype
+    q, kp, vp, table, lengths = _window_case(4, 2, 4, 3, "f32", seed=1)
+    np.testing.assert_allclose(
+        np.asarray(kops.paged_window_attention(q, kp, vp, table, lengths,
+                                               impl="pallas")),
+        np.asarray(kops.paged_window_attention(q, kp, vp, table, lengths,
+                                               impl="jax")),
+        rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("sq", [1, 3])
+def test_paged_decode_dispatch_by_window_width(monkeypatch, sq):
+    """``_paged_decode`` takes the decode entry for one token a row and
+    the window entry for S > 1."""
+    cfg = _cfg()
+    calls = []
+    for name in ("paged_decode_attention", "paged_window_attention"):
+        fn = getattr(kops, name)
+        monkeypatch.setattr(
+            kops, name, lambda *a, _n=name, _f=fn, **k: (
+                calls.append(_n), _f(*a, **k))[1])
+    params, _ = attention.attn_init(jax.random.PRNGKey(0), cfg)
+    b, ps, t = 2, 4, 3
+    cache = attention.init_paged_kv_cache(cfg, 1 + b * t, ps,
+                                          dtype=jnp.float32)
+    table = 1 + jnp.arange(b * t, dtype=jnp.int32).reshape(b, t)
+    pos = jnp.asarray([2, 5], jnp.int32)
+    x = jnp.ones((b, sq, cfg.d_model), jnp.float32)
+    q = jnp.ones((b, sq, cfg.num_heads, cfg.head_dim), jnp.float32)
+    kv = jnp.ones((b, sq, cfg.num_kv_heads, cfg.head_dim), jnp.float32)
+    attention._paged_decode(params, x, cfg, q, kv, kv, cache, pos, table)
+    assert calls[0] == ("paged_decode_attention" if sq == 1
+                        else "paged_window_attention")
+    assert ("paged_window_attention" in calls) == (sq > 1)
 
 
 def test_paged_jax_impl_bitexact_vs_naive():

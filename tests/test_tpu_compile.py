@@ -170,6 +170,52 @@ def test_paged_attention_compiles_at_max_len_1024(sds, kv_dtype):
     assert _kernel_classes(compiled) == {"attn"}
 
 
+@pytest.mark.parametrize("b,s", [(32, 32), (8, 128)])
+def test_paged_window_attention_compiles_at_mistral_widths(sds, b, s):
+    """Chunk windows at Mistral-NeMo widths (32 heads of 128, 8 KV heads,
+    128-token pages, 8 a row, bf16): 32 rows of 32 tokens, and 8 rows of
+    128, which splits its queries into tiles. The kernel stays in the
+    ``attn`` class, beside the decode kernel."""
+    h, kv, hd, ps, t = 32, 8, 128, 128, 8
+    pages = (b * t + 1, ps, kv, hd)
+
+    def f(q, kp, vp, bt, ln):
+        return pk.paged_window_attention_pallas(q, kp, vp, bt, ln,
+                                                interpret=False)
+    compiled = _compile(f, sds((b, s, h, hd), BF16), sds(pages, BF16),
+                        sds(pages, BF16), sds((b, t), jnp.int32),
+                        sds((b,), jnp.int32))
+    assert _kernel_classes(compiled) == {"attn"}
+
+
+def test_tp_paged_window_attention_runs_per_shard(topo):
+    """Under a 2-way ``"model"`` axis the window kernel runs per shard on
+    half the KV heads: q's head axis (the one before ``head_dim``) splits
+    with the pages' KV-head axis."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    b, s, h, kv, hd, ps, t = 4, 4, 32, 8, 128, 128, 2
+
+    def placed(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    pages = placed((b * t + 1, ps, kv, hd), BF16,
+                   P(None, None, "model", None))
+    args = (placed((b, s, h, hd), BF16, P(None, None, "model", None)),
+            pages, pages, placed((b, t), jnp.int32, P()),
+            placed((b,), jnp.int32, P()))
+    with ops.tensor_parallel(mesh):
+        lowered = jax.jit(lambda *a: ops.paged_window_attention(
+            *a, impl="pallas", interpret=False)).lower(*args)
+    calls = [ln for ln in lowered.compile().as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    rows = s * h // kv
+    assert any(f"bf16[{b},{kv // 2},{rows},{hd}]" in ln for ln in calls), \
+        calls
+    assert not any(f"bf16[{b},{kv},{rows},{hd}]" in ln for ln in calls), \
+        calls
+
+
 def test_tp_sharded_packed_linear_runs_per_shard(topo):
     """A column-split packed linear on a 2x2 mesh compiles to a Pallas
     custom call on the per-shard (K, N/2) problem, not on all of N."""
