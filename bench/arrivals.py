@@ -14,6 +14,10 @@ A mix is one of two kinds:
   first ``ramp`` requests get outputs spread evenly up to the longest, so
   the slots finish at staggered times from the start.
 
+The warm-up before the window lasts ``warmup_steps`` decode steps of the
+engine: the window opens at the same point of the mix on every run of a
+seed, whatever the speed of its first steps.
+
 Every seed gets the same work: requests are drawn in blocks of ``block``,
 and within a block the prompt and output lengths are allotted to their
 weights exactly (largest remainder) and the Poisson gaps are the block's
@@ -39,7 +43,7 @@ class Mix:
     backlog: int = 0
     ramp: int = 0
     block: int = 64
-    warmup_s: float = 0.0
+    warmup_steps: int = 0
 
     def __post_init__(self):
         if self.kind not in ("poisson", "backlog"):

@@ -9,8 +9,9 @@ checks. Then, on the same prompts and served tokens, the float32 reference
 gives two readings: the widest gap of a served token (the program, whose
 largest over many seeds is the limit's lower end) and the widest gap of the
 token the float8 control puts first (the control, whose smallest is the
-upper end). One JSON line per seed on standard output. Needs the cell's
-chips, as ``run.py`` does.
+upper end). The reference is the cell's family's (``spec.load_family``).
+One JSON line per seed on standard output. Needs the cell's chips, as
+``run.py`` does.
 """
 import time
 
@@ -35,7 +36,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import harness
-    import reference
     import spec
     cell = spec.load_cell(args.workload, root=ROOT)
     os.environ.setdefault("REPRO_AUTOTUNE_CACHE",
@@ -49,21 +49,29 @@ def main(argv=None) -> int:
     harness.compile_cache_dir(ROOT)
     counter = harness.CompileCounter()
     for seed in [int(s) for s in args.seeds.split(",")]:
-        t = time.monotonic()
-        run = harness.Run(cell, seed, peaks=peaks)
-        run.setup()
-        w = run.window(args.seconds, counter)
-        run.drain(w)
-        sample = run.sample(w, cell.engine["check_requests"])
-        seqs = [(r.req.prompt, list(r.req.tokens)) for r in sample]
-        run.free()
-        ctl, srv = reference.control_gaps(cell.config, seed, seqs)
-        print(json.dumps({"workload": cell.name, "seed": seed,
-                          "program_gap": max(srv), "control_gap": max(ctl),
-                          "per_request": {"program": srv, "control": ctl},
-                          "checked_tokens": sum(len(s) for _, s in seqs),
-                          "seconds": time.monotonic() - t}), flush=True)
+        print(json.dumps(readings(cell, seed, peaks, args.seconds, counter)),
+              flush=True)
     return 0
+
+
+def readings(cell, seed: int, peaks, seconds: float, counter):
+    """Serve one window of ``cell`` at ``seed`` and read both ends of its
+    output check with the family's reference."""
+    import harness
+    t = time.monotonic()
+    run = harness.Run(cell, seed, peaks=peaks)
+    run.setup()
+    w = run.window(seconds, counter)
+    run.drain(w)
+    sample = run.sample(w, cell.engine["check_requests"])
+    seqs = [(r.req.prompt, list(r.req.tokens)) for r in sample]
+    run.free()
+    ctl, srv = cell.family.control_gaps(cell.config, seed, seqs)
+    return {"workload": cell.name, "seed": seed,
+            "program_gap": max(srv), "control_gap": max(ctl),
+            "per_request": {"program": srv, "control": ctl},
+            "checked_tokens": sum(len(s) for _, s in seqs),
+            "seconds": time.monotonic() - t}
 
 
 if __name__ == "__main__":

@@ -5,11 +5,14 @@ packed (``quantization="ternary_packed"``), fills the weights on the device
 from the seed in one jitted call (``gen``), builds the continuous-batching
 engine over the paged KV cache with chunked prefill as the cell's file
 states, compiles the decode step and every chunk window (``engine.load``),
-and runs the cell's traffic for its warm-up time. The window then drives
-``ContinuousScheduler.submit`` and ``.step()`` for ``seconds`` from one
-thread; every output token is stamped on the host clock when the step that
-made it returns. After the window the served tokens of a sample of finished
-requests are checked against the plain float32 reference (``reference``).
+and runs the cell's traffic for its warm-up's number of decode steps. The
+window then drives ``ContinuousScheduler.submit`` and ``.step()`` for
+``seconds`` from one thread; every output token is stamped on the host
+clock when the step that made it returns. After the window
+the served tokens of a sample of finished requests are checked against
+the plain float32 reference. What is particular to the model's family
+(its ``ModelConfig``, its seeded weights, the work a step asks for and its
+reference) comes from the cell's family module (``spec.load_family``).
 """
 from __future__ import annotations
 
@@ -17,6 +20,7 @@ import dataclasses
 import gc
 import json
 import os
+import shutil
 import sys
 import tempfile
 import time
@@ -27,7 +31,6 @@ import numpy as np
 import arrivals
 import reduce
 import spec as spec_lib
-import work as work_lib
 
 GRACE_S = 60.0       # how long past the window an answer may still come
 
@@ -92,32 +95,15 @@ class CompileCounter:
             self.n += 1
 
 
-def model_config(c: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    s = c["serving"]
-    return ModelConfig(
-        name=str(c.get("model_type", "model")), family="dense",
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=bool(c.get("tie_word_embeddings", False)),
-        quantization=s["quantization"],
-        ternary_min_dim=int(s["ternary_min_dim"]), dtype=s["dtype"],
-        param_dtype=s["param_dtype"], cache_dtype=s["cache_dtype"],
-        fused_mlp=s.get("fused_mlp", "auto"))
-
-
 def _leaf_name(path) -> str:
     return "/".join(str(getattr(p, "key", getattr(p, "name", p)))
                     for p in path)
 
 
-def make_params(model, seed: int):
+def make_params(model, seed: int, leaf: Callable):
     """Every leaf of the model's parameter tree, drawn on the device from
-    the seed in one jitted call (no host copy, no float projection)."""
+    the seed in one jitted call (no host copy, no float projection) by the
+    family's ``leaf(root, name, shape, layer, k_in)``."""
     import jax
     import jax.numpy as jnp
 
@@ -134,17 +120,23 @@ def make_params(model, seed: int):
         for n, s in zip(names, sds):
             if n.startswith("block"):
                 out.append(jax.vmap(
-                    lambda l, n=n, s=s: gen.leaf(root, n, s.shape[1:], l,
-                                                 k_in.get(n, 0)))(
+                    lambda l, n=n, s=s: leaf(root, n, s.shape[1:], l,
+                                             k_in.get(n, 0)))(
                     jnp.arange(s.shape[0])))
             else:
-                out.append(gen.leaf(root, n, s.shape, None, k_in.get(n, 0)))
+                out.append(leaf(root, n, s.shape, None, k_in.get(n, 0)))
         for o, s in zip(out, sds):
             assert o.shape == s.shape and o.dtype == s.dtype, (o, s)
         return out
 
     leaves = jax.jit(build)(gen.root_key(seed))
     return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def _counters(engine) -> Dict[str, int]:
+    """The engine registry's counters (its integer readings), by name."""
+    return {k: v for k, v in engine.metrics.snapshot().items()
+            if isinstance(v, (int, np.integer)) and not isinstance(v, bool)}
 
 
 @dataclasses.dataclass
@@ -164,8 +156,9 @@ class Window:
     steps: int
     compiles: int
     occupancy: List[float]
-    work: work_lib.StepWork
+    work: Any                      # the family's work accumulator
     submit_lag_s: float
+    trace_dir: Optional[str] = None
     trace: Optional[Dict[str, Any]] = None
 
 
@@ -198,9 +191,10 @@ class Run:
         from repro.serving import ContinuousScheduler
         from repro.serving.sched import SchedConfig
         c, e = self.cell.config, self.cell.engine
-        self.cfg = model_config(c)
+        family = self.cell.family
+        self.cfg = family.model_config(c)
         model = LM(self.cfg)
-        params = make_params(model, self.seed)
+        params = make_params(model, self.seed, family.leaf)
         jax.block_until_ready(params)
         self.engine = ContinuousScheduler(
             self.cfg, max_slots=e["max_slots"], max_len=e["max_len"],
@@ -211,11 +205,11 @@ class Run:
         self.engine.load(params)
         del params
         self.source = arrivals.Source(self.mix, c["vocab_size"], self.seed)
-        self.shapes = work_lib.Shapes.from_config(c)
         # the warm-up traffic: the same loop as the window, not counted
-        t = self.clock()
-        self.start_arrivals(t)
-        self.pump(until=t + self.mix.warmup_s)
+        self.start_arrivals(self.clock())
+        eng = self.engine
+        self.pump(until=float("inf"),
+                  stop=lambda: eng.decode_steps >= self.mix.warmup_steps)
 
     # ------------------------------------------------------------------
     def start_arrivals(self, t: float) -> None:
@@ -249,14 +243,16 @@ class Run:
             self.next_arrival = t + self.pending.gap_s
         return lag
 
-    def _step(self, acc: Optional[work_lib.StepWork]) -> int:
+    def _step(self, acc: Any) -> int:
         """One engine step; stamps the tokens it made and, with ``acc``,
-        adds the work it asked of the chip. Returns tokens made."""
+        adds the work it asked of the chip and hands it the step's
+        counter deltas. Returns tokens made."""
         import jax
         eng = self.engine
         before = [(r, len(r.req.tokens), r.req.prefill_pos)
                   for r in self.live]
         d0, c0 = eng.decode_steps, eng.chunk_steps
+        seen = _counters(eng) if acc is not None else None
         with jax.profiler.TraceAnnotation("bench.engine_step"):
             eng.step()
         t = self.clock()
@@ -291,18 +287,23 @@ class Run:
                 att_pad = (rows - len(chunks)) * s * (s + 1) // 2 + sum(
                     (s - c) * p + s * (s + 1) // 2 - c * (c + 1) // 2
                     for p, c in chunks)
-                acc.forward(rows * s, att_real + att_pad)
+                # each row reads its slot's pages once for its S queries:
+                # p + S keys for a job, the S of the trash page for a pad
+                keys = sum(p for p, _ in chunks) + rows * s
+                acc.forward(rows * s, att_real + att_pad, keys)
                 acc.useful(real, att_real)
             if decoded:
                 att = sum(dec_rows) + sum(p + 1 for p in prefilling) + (
                     rows - len(dec_rows) - len(prefilling))
                 acc.forward(rows, att)
                 acc.useful(len(dec_rows), sum(dec_rows))
+            acc.counters({k: v - seen.get(k, 0)
+                          for k, v in _counters(eng).items()})
         self.live = [r for r in self.live if not r.req.terminal]
         return made
 
     def pump(self, until: float, window=None,
-             acc: Optional[work_lib.StepWork] = None,
+             acc: Any = None,
              occupancy: Optional[List[float]] = None,
              stop: Optional[Callable[[], bool]] = None) -> Dict[str, float]:
         """Drive arrivals and steps until the clock passes ``until`` at a
@@ -338,7 +339,7 @@ class Run:
         if trace:
             tdir = tempfile.mkdtemp(prefix="bench-trace-")
             jax.profiler.start_trace(tdir)
-        acc = work_lib.StepWork(self.shapes, self.peaks)
+        acc = self.cell.family.work(self.cell.config, self.peaks)
         occ: List[float] = []
         n_compiles = counter.n
         t0 = self.clock()
@@ -347,23 +348,31 @@ class Run:
                             acc=acc, occupancy=occ)
         t1 = got["t"]
         compiles = counter.n - n_compiles
-        reduced = None
         if trace:
             jax.profiler.stop_trace()
-            reduced = reduce.reduce_dir(tdir)
-            import shutil
-            shutil.rmtree(tdir, ignore_errors=True)
         return Window(t0=t0, t1=t1, tokens=got["tokens"], steps=got["steps"],
                       compiles=compiles, occupancy=occ, work=acc,
-                      submit_lag_s=got["lag"], trace=reduced)
+                      submit_lag_s=got["lag"], trace_dir=tdir)
+
+    def read_trace(self, w: Window) -> None:
+        """Reduce the window's trace into ``w.trace`` and delete it. Called
+        after ``drain``, so that the requests in flight at the close are
+        served before, not after, the minute or more the read takes."""
+        if w.trace_dir is None:
+            return
+        w.trace = reduce.reduce_dir(w.trace_dir)
+        shutil.rmtree(w.trace_dir, ignore_errors=True)
+        w.trace_dir = None
 
     def drain(self, w: Window) -> None:
         """Keep the load on until every request that arrived in the window
-        has finished, or ``GRACE_S`` has passed."""
+        has finished, or ``GRACE_S`` of serving has passed. The grace runs
+        from the drain's start: a traced run stops its profiler between
+        the close and the drain, which can take longer than the grace."""
         if self.mix.kind == "backlog":
             return
         pending = [r for r in self.recs if r.in_window]
-        self.pump(until=w.t1 + GRACE_S, window=None,
+        self.pump(until=self.clock() + GRACE_S, window=None,
                   stop=lambda: all(r.req.terminal for r in pending))
         self.t_stop = self.clock()
 
@@ -394,8 +403,8 @@ class Run:
             out["itl_p95_ms"] = 1e3 * arrivals.nearest_rank(gaps, 0.95)
             log(f"requests in window {len(recs)}; ttft p50 "
                 f"{1e3 * arrivals.nearest_rank(ttft, 0.5):.1f} ms; itl p50 "
-                f"{1e3 * arrivals.nearest_rank(gaps, 0.5):.2f} ms over "
-                f"{len(gaps)} gaps")
+                f"{1e3 * arrivals.nearest_rank(gaps, 0.5):.2f} ms, longest "
+                f"{1e3 * max(gaps):.1f} ms, over {len(gaps)} gaps")
         return out
 
     def context(self, w: Window) -> Dict[str, Any]:
@@ -427,7 +436,6 @@ class Run:
 def check_output(run: Run, w: Window) -> Dict[str, Dict[str, float]]:
     """Compare a sample of the window's finished requests with the plain
     reference; returns each number compared beside its limit."""
-    import reference
     e = run.cell.engine
     recs = run.window_recs(w)
     sample = run.sample(w, e["check_requests"])
@@ -435,7 +443,7 @@ def check_output(run: Run, w: Window) -> Dict[str, Dict[str, float]]:
     failed = sum(1 for r in recs if r.req.state != "done")
     short = sum(1 for r in sample if len(r.req.tokens) != r.req.max_new)
     run.free()
-    gap = max(reference.served_gaps(run.cell.config, run.seed, seqs)) \
+    gap = max(run.cell.family.served_gaps(run.cell.config, run.seed, seqs)) \
         if seqs else None
     return {
         "max_logit_gap": {"value": gap, "limit": e["max_logit_gap"]},
@@ -475,6 +483,7 @@ def execute(cell: spec_lib.Cell, seed: int, seconds: float, trace: bool,
         f"{w.compiles} compilations inside the window, submit lag "
         f"{w.submit_lag_s * 1e3:.1f} ms")
     run.drain(w)
+    run.read_trace(w)
     recs = run.window_recs(w)
     if trace:
         ctx = run.context(w)

@@ -13,7 +13,16 @@ found by its name, so a new cell, mix or metric is added by adding files:
 * ``bench/metrics/<metric>.py``: one per-layer metric's reader, a function
   ``read(ctx)`` that returns a number or ``None``. A metric split by the
   end-to-end metric it moves (``mfu.batch``, ``mfu.chat``) falls back to
-  its family's reader (``mfu.py``) when it has no file of its own.
+  its family's reader (``mfu.py``) when it has no file of its own;
+* ``bench/families/<family>.py``: what is particular to one model family,
+  named by the configuration's top-level ``"family"`` key (``"dense"``
+  without it). The module exports ``model_config(c)``, the program's
+  ``ModelConfig``; ``leaf(root, name, shape, layer, k_in)``, one seeded
+  weight; ``work(c, peaks)``, the window's work accumulator (``.gemm``,
+  ``.attn``, ``.useful_ops``, ``forward(m, attended, keys)``,
+  ``useful(tokens, attended)``, ``counters(deltas)``); and the plain
+  reference, ``served_gaps(c, seed, seqs)`` and
+  ``control_gaps(c, seed, seqs)``.
 """
 from __future__ import annotations
 
@@ -39,6 +48,7 @@ class Cell:
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
     bench_dir: str
+    family: Any
 
     def reader(self, metric: str) -> Callable[[Dict[str, Any]], Optional[float]]:
         return load_reader(self.bench_dir, metric)
@@ -75,14 +85,35 @@ def load_cell(name: str, root: str = ROOT,
     e2e = [m for m in spec["end_to_end"] if applies(m, name)]
     names = [m["name"] for m in e2e]
     per_layer = [m for m in spec["per_layer"] if applies(m, name, names)]
+    config = _read_json(os.path.join(root, cfg_entry["file"]))
     return Cell(
         name=name, chips=int(w["chips"]), config_name=w["config"],
-        config=_read_json(os.path.join(root, cfg_entry["file"])),
+        config=config,
         traffic_name=w["traffic"],
         traffic=_read_json(os.path.join(bench_dir, "traffic",
                                         w["traffic"] + ".json")),
         engine=_read_json(os.path.join(bench_dir, "cells", name + ".json")),
-        end_to_end=e2e, per_layer=per_layer, bench_dir=bench_dir)
+        end_to_end=e2e, per_layer=per_layer, bench_dir=bench_dir,
+        family=load_family(bench_dir, config.get("family", "dense")))
+
+
+def _load_module(path: str, prefix: str, name: str):
+    mod_name = prefix + name.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    if spec is None or spec.loader is None:
+        raise FileNotFoundError(path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(bench_dir: str, family: str):
+    """The module ``bench/families/<family>.py``."""
+    path = os.path.join(bench_dir, "families", family + ".py")
+    if not os.path.exists(path):
+        raise FileNotFoundError(f"no module for model family {family!r}: "
+                                f"{path} does not exist")
+    return _load_module(path, "bench_family_", family)
 
 
 def load_reader(bench_dir: str, metric: str):
@@ -91,10 +122,4 @@ def load_reader(bench_dir: str, metric: str):
                           metric.rsplit(".", 1)[0] + ".py")
     if not os.path.exists(path) and os.path.exists(family):
         path = family
-    mod_name = "bench_metric_" + metric.replace(".", "_").replace("-", "_")
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    if spec is None or spec.loader is None:
-        raise FileNotFoundError(path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(path, "bench_metric_", metric).read

@@ -23,7 +23,9 @@ TINY = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 4,
                     "dtype": "bfloat16", "param_dtype": "float32",
                     "cache_dtype": "bfloat16"}}
 MIX = {"kind": "poisson", "rate": 30, "prompt_lens": [8, 16],
-       "output_lens": [16, 24], "block": 16, "warmup_s": 0.3}
+       "output_lens": [16, 24], "block": 16, "warmup_steps": 4}
+BATCH = {"kind": "backlog", "backlog": 4, "ramp": 4, "prompt_lens": [8],
+         "output_lens": [8, 16], "block": 8, "warmup_steps": 3}
 LIMIT = 0.05
 CELL = {"max_slots": 4, "max_len": 48, "page_size": 8, "n_pages": 25,
         "chunk_tokens": 8, "step_token_budget": 12, "admission": "fifo",
@@ -62,7 +64,9 @@ def root(tmp_path_factory):
     b = os.path.join(r, "bench")
     for rel, body in (("configs/tiny.json", TINY),
                       ("traffic/tinychat.json", MIX),
-                      ("cells/tiny.chat.json", CELL)):
+                      ("traffic/tinybatch.json", BATCH),
+                      ("cells/tiny.chat.json", CELL),
+                      ("cells/tiny.batch.json", CELL)):
         with open(os.path.join(b, rel), "w") as f:
             json.dump(body, f)
     with open(os.path.join(b, "metrics", "slot_share.tiny.py"), "w") as f:
@@ -72,9 +76,9 @@ def root(tmp_path_factory):
     bj["configs"].append({"name": "tiny", "source": "test",
                           "file": "bench/configs/tiny.json", "reduced": [],
                           "why": "test"})
-    bj["workloads"].append({"name": "tiny.chat", "config": "tiny",
-                            "traffic": "tinychat", "chips": 1,
-                            "why": "test"})
+    for name, mix in (("tiny.chat", "tinychat"), ("tiny.batch", "tinybatch")):
+        bj["workloads"].append({"name": name, "config": "tiny",
+                                "traffic": mix, "chips": 1, "why": "test"})
     bj["end_to_end"].append({"name": "ttft_p50_tiny_ms", "unit": "ms",
                              "better": "lower", "bound": 0.1,
                              "source": "host_clock",
@@ -153,3 +157,65 @@ def test_altered_tokens_fail_the_check(root):
     checks = harness.check_output(run, w)
     assert not harness.passed(checks)
     assert checks["max_logit_gap"]["value"] > LIMIT
+
+
+def test_traced_run_drains_before_reading_the_trace(root, monkeypatch):
+    """Every request of the window is served before the trace is read."""
+    import reduce
+    order = []
+    drain = harness.Run.drain
+
+    def drain_and_note(self, w):
+        drain(self, w)
+        order.append(("drained", all(r.req.terminal for r in self.recs
+                                     if r.in_window)))
+
+    def read(tdir):
+        order.append(("read", os.path.isdir(tdir)))
+        return {"busy_s": 1.0, "window_s": 1.0, "kernels": {},
+                "device_ops": [], "idle_gaps": []}
+
+    monkeypatch.setattr(harness.Run, "drain", drain_and_note)
+    monkeypatch.setattr(reduce, "reduce_dir", read)
+    cell = spec.load_cell("tiny.chat", root=root)
+    out = harness.execute(cell, 2**32 + 13, 1.0, True, time.monotonic(),
+                          CPU, PEAKS)
+    assert order == [("drained", True), ("read", True)]
+    assert out["correct"] and out["failed"] == 0
+    assert out["device"]["busy_s"] == 1.0
+    assert "slot_share.tiny" in out["metrics"]
+
+
+def test_drain_grace_runs_from_its_start(root):
+    """A pause between the close and the drain (a traced run stopping its
+    profiler) leaves the window's requests their whole grace."""
+    offset = [0.0]
+    cell = spec.load_cell("tiny.chat", root=root)
+    run = harness.Run(cell, 2**32 + 17, peaks=PEAKS,
+                      clock=lambda: time.monotonic() + offset[0])
+    run.setup()
+    w = run.window(1.0, harness.CompileCounter())
+    run._submit(run.source.take(), w.t1, True)     # one still in flight
+    offset[0] += harness.GRACE_S + 1.0
+    run.drain(w)
+    recs = run.window_recs(w)
+    assert recs and all(r.req.state == "done" for r in recs)
+
+
+def test_backlog_warms_up_by_decode_steps(root):
+    """A closed loop opens its window after the same number of decode
+    steps on every run, however long they took."""
+    cell = spec.load_cell("tiny.batch", root=root)
+    run = harness.Run(cell, 2**32 + 19, peaks=PEAKS)
+    run.setup()
+    assert run.engine.decode_steps == BATCH["warmup_steps"]
+
+
+def test_open_loop_warms_up_by_decode_steps(root):
+    """An open loop counts its warm-up in decode steps too, serving the
+    arrivals that come meanwhile."""
+    cell = spec.load_cell("tiny.chat", root=root)
+    run = harness.Run(cell, 2**32 + 23, peaks=PEAKS)
+    run.setup()
+    assert run.engine.decode_steps == MIX["warmup_steps"]
+    assert run.recs and not any(r.in_window for r in run.recs)

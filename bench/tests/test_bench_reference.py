@@ -5,10 +5,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import _paths  # noqa: F401
+import _paths
 import gen
 import harness
 import reference
+import spec
 
 TINY = {"hidden_size": 128, "intermediate_size": 256, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
@@ -23,9 +24,9 @@ SEED = 2**33 + 5
 @pytest.fixture(scope="module")
 def program():
     from repro.models import LM
-    cfg = harness.model_config(TINY)
-    model = LM(cfg)
-    return model, harness.make_params(model, SEED)
+    dense = spec.load_family(_paths.BENCH, "dense")
+    model = LM(dense.model_config(TINY))
+    return model, harness.make_params(model, SEED, dense.leaf)
 
 
 def test_stacked_leaves_equal_per_layer_draws(program):

@@ -10,7 +10,7 @@ import arrivals
 CHAT = {"kind": "poisson", "rate": 4.0,
         "prompt_lens": [128, 256, 512, 1024, 2048],
         "prompt_weights": [0.3, 0.3, 0.2, 0.15, 0.05],
-        "output_lens": [64, 128, 256], "block": 64, "warmup_s": 1}
+        "output_lens": [64, 128, 256], "block": 64, "warmup_steps": 1}
 BATCH = {"kind": "backlog", "backlog": 8, "ramp": 8,
          "prompt_lens": [128, 256], "output_lens": [256, 512], "block": 16}
 

@@ -41,6 +41,21 @@ def test_paged_attention_counts_the_attended_cache():
     assert nbytes == 2 * 300 * 8 * 128 * 2 + 2 * 2 * 32 * 128 * 2
 
 
+def test_window_rows_read_their_slot_pages_once():
+    """A chunk window's queries share their slot's pages: the keys are
+    read once a row, while every query's attention is counted."""
+    ops, nbytes = work.paged_attention(rows=128, attended=128 * 1000,
+                                       heads=32, kv_heads=8, head_dim=128,
+                                       keys=1064)
+    assert ops == 4 * 128 * 1000 * 32 * 128
+    assert nbytes == 2 * 1064 * 8 * 128 * 2 + 2 * 128 * 32 * 128 * 2
+    assert work.paged_attention(2, 300, 32, 8, 128) == \
+        work.paged_attention(2, 300, 32, 8, 128, keys=300)
+    w = work.StepWork(MISTRAL, PEAKS)
+    w.forward(128, 128 * 1000, keys=1064)
+    assert w.attn.bytes == 40 * nbytes
+
+
 def test_useful_work_leaves_pad_rows_out():
     w = work.StepWork(MISTRAL, PEAKS)
     w.useful(tokens=10, attended=1000)

@@ -8,10 +8,14 @@ The same logical work is counted whatever lowering runs it:
 * the fused MLP is the gate and up GEMMs (K = d, N = ff) and the down GEMM
   (K = ff, N = d) with the hidden activation kept on chip: bf16 in and out
   once, weights once;
-* paged decode attention reads, for each query row, the K and V of the
-  tokens it attends (``2 * len * kv_heads * head_dim`` cache elements) and
-  writes its output; it is ``4 * len * heads * head_dim`` operations
-  (scores and the weighted sum).
+* paged attention is ``4 * len * heads * head_dim`` operations for each
+  query row (scores and the weighted sum over the ``len`` tokens it
+  attends). It reads the K and V of each slot row's keys once
+  (``2 * keys * kv_heads * head_dim`` cache elements): a decode row
+  attends its own keys, and the query rows of a chunk window share their
+  slot's pages, so a window's row of S tokens after a prefix of p reads
+  p + S keys, not one copy of them for each of its S queries. Each query
+  row's input and output are read and written once.
 
 A step's least time on the chip is the larger of its operations over the
 peak rate and its bytes over the memory bandwidth (``least_time``).
@@ -19,7 +23,7 @@ peak rate and its bytes over the memory bandwidth (``least_time``).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Dict, Optional
 
 BF16 = 2
 
@@ -54,10 +58,13 @@ def fused_mlp(m: int, d: int, ff: int):
 
 
 def paged_attention(rows: int, attended: int, heads: int, kv_heads: int,
-                    head_dim: int, kv_bytes: int = BF16):
-    """``rows`` query rows attending ``attended`` tokens in all."""
+                    head_dim: int, kv_bytes: int = BF16,
+                    keys: Optional[int] = None):
+    """``rows`` query rows attending ``attended`` tokens in all, reading
+    ``keys`` cached tokens (``attended`` when each row has its own)."""
+    keys = attended if keys is None else keys
     ops = 4.0 * attended * heads * head_dim
-    nbytes = (2 * attended * kv_heads * head_dim * kv_bytes
+    nbytes = (2 * keys * kv_heads * head_dim * kv_bytes
               + 2 * rows * heads * head_dim * BF16)
     return ops, nbytes
 
@@ -94,7 +101,8 @@ class StepWork:
     """Sums the kernel work and the useful model operations of the steps
     of a window. A forward over ``m`` token rows runs every projection and
     the head at M = m, the fused MLP at M = m, and paged attention over
-    ``m`` query rows."""
+    ``m`` query rows that read ``keys`` cached tokens (``attended`` when
+    every row is its own slot's)."""
 
     def __init__(self, shapes: Shapes, peaks: Dict[str, float]):
         self.s = shapes
@@ -103,7 +111,8 @@ class StepWork:
         self.attn = Work()
         self.useful_ops = 0.0
 
-    def forward(self, m: int, attended: int) -> None:
+    def forward(self, m: int, attended: int,
+                keys: Optional[int] = None) -> None:
         s, p = self.s, self.peaks
         hq = s.heads * s.head_dim
         hkv = s.kv_heads * s.head_dim
@@ -112,7 +121,8 @@ class StepWork:
         self.gemm.add(*fused_mlp(m, s.d, s.ff), p, times=s.layers)
         self.gemm.add(*gemm(m, s.d, s.vocab), p)
         self.attn.add(*paged_attention(m, attended, s.heads, s.kv_heads,
-                                       s.head_dim), p, times=s.layers)
+                                       s.head_dim, keys=keys), p,
+                      times=s.layers)
 
     def useful(self, tokens: int, attended: int) -> None:
         """Real tokens (pad rows excluded) and the context they attend."""
@@ -120,3 +130,7 @@ class StepWork:
         self.useful_ops += (2.0 * s.matmul_params * tokens
                             + 4.0 * s.layers * s.heads * s.head_dim
                             * attended)
+
+    def counters(self, deltas: Dict[str, int]) -> None:
+        """The engine's counters over one step, by name. The shapes and
+        row counts above are all a dense step needs."""
