@@ -52,6 +52,15 @@ class ModelConfig:
     norm_eps: float = 1e-5
     rope_theta: float = 10000.0
     sliding_window: int = 0      # 0 -> full attention
+    layer_windows: Tuple[int, ...] = ()   # per-layer attention window (0 =
+                                          # full); () -> sliding_window
+                                          # everywhere. Pages are held for
+                                          # the whole context: the window
+                                          # is a mask, never a rolling cache
+    qk_norm: bool = False        # RMSNorm over head_dim on q and k
+    sublayer_norm: str = "pre"   # pre: norm a sub-layer's input | post: its
+                                 # output, before the residual add
+    rope_window_only: bool = False   # RoPE in window layers only
     attn_impl: str = "flash"     # flash | naive
     attn_block_q: int = 512
     attn_block_kv: int = 1024
@@ -68,6 +77,14 @@ class ModelConfig:
                                  # DP shard count) — dispatch becomes local
                                  # gathers + expert all-to-all instead of
                                  # global-token all-reduces (§Perf D1)
+    layer_ffns: Tuple[str, ...] = ()  # per-layer 'mlp' | 'moe' | 'none';
+                                      # () -> moe_every/moe_offset
+    moe_scoring: str = "softmax"  # router scores: softmax | sigmoid
+    routed_scaling: float = 1.0   # routed gates' scale after they are
+                                  # renormalised to sum 1
+    experts_held: int = 0        # packed serving: experts this device holds
+                                 # (0 -> all num_experts), routed over all
+    expert_offset: int = 0       # global id of the first expert held
 
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
@@ -128,6 +145,14 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.num_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        for name in ("layer_windows", "layer_ffns"):
+            v = getattr(self, name)
+            if v and len(v) != self.num_layers:
+                raise ValueError(f"{name} has {len(v)} entries for "
+                                 f"{self.num_layers} layers")
+            object.__setattr__(self, name, tuple(v))
+        if self.sliding_window and self.layer_windows:
+            raise ValueError("give sliding_window or layer_windows, not both")
 
     # ------------------------------------------------------------------
     @property
@@ -150,8 +175,15 @@ class ModelConfig:
             return "attn" if (i % self.attn_period == self.attn_offset) else "ssm"
         return "attn"
 
+    def layer_window(self, i: int) -> int:
+        """Attention window of decoder layer i (0 = full attention)."""
+        return self.layer_windows[i] if self.layer_windows \
+            else self.sliding_window
+
     def layer_ffn(self, i: int) -> str:
         """'moe', 'mlp' or 'none' for decoder layer i."""
+        if self.layer_ffns:
+            return self.layer_ffns[i]
         if self.d_ff == 0 and self.num_experts == 0:
             return "none"
         if self.num_experts and (i % self.moe_every == self.moe_offset):
@@ -223,9 +255,13 @@ class ModelConfig:
     # ------------------------------------------------------------------
     def reduced(self, **overrides) -> "ModelConfig":
         """Tiny same-family config for CPU smoke tests."""
+        n_layers = (min(self.num_layers, 4) if not self.attn_period
+                    else self.attn_period)
         changes = dict(
-            num_layers=min(self.num_layers, 4) if not self.attn_period
-            else self.attn_period,
+            num_layers=n_layers,
+            layer_windows=tuple(min(w, 64) for w in
+                                self.layer_windows[:n_layers]),
+            layer_ffns=self.layer_ffns[:n_layers],
             d_model=128,
             num_heads=4,
             num_kv_heads=min(self.num_kv_heads, 2) if self.num_kv_heads else 0,
@@ -255,7 +291,8 @@ class ModelConfig:
         """(supported, reason-if-not). long_500k needs sub-quadratic attention."""
         if shape_name == "long_500k":
             subquad = (self.family in ("ssm", "hybrid")
-                       or self.sliding_window > 0)
+                       or self.sliding_window > 0
+                       or (self.layer_windows and all(self.layer_windows)))
             if not subquad:
                 return False, ("full quadratic attention; 500k decode cache "
                                "infeasible (see DESIGN.md §4)")

@@ -796,6 +796,46 @@ def _tp_paged_attention(mesh, fn, q, k_pages, v_pages, block_table, lengths,
 
 
 # ---------------------------------------------------------------------------
+# Grouped expert GEMM (MoE): rows sorted by held expert
+# ---------------------------------------------------------------------------
+# Two lowerings of one product (``repro.kernels.moe_gemm``): the Pallas
+# kernel (``pallas_call`` name ``moe_expert_gemm``), and the XLA product over
+# the same sorted layout for backends without it; the caller picks, as the
+# packed linears do (``models.layers._use_pallas_gemm``).
+
+def _moe_gemm_impls():
+    from repro.kernels import moe_gemm
+    return {"pallas": moe_gemm.moe_expert_gemm_pallas,
+            "xla": moe_gemm.moe_expert_gemm_xla}
+
+
+def moe_expert_gemm(x, w: weights.Dense2Bit, tile_group, tile_row,
+                    n_active, *, block_m: int, impl: str,
+                    interpret: Optional[bool] = None):
+    """Y = X @ (bank of each row's expert) * scale over expert-sorted rows.
+
+    x (M, K) with M a multiple of ``block_m``, each tile of ``block_m``
+    rows one expert's; ``w`` a stacked ``Dense2Bit`` bank (E, K/16, N);
+    tile_group / tile_row (M / block_m,) int32 and n_active (1,) int32
+    describe the tiles (``models.moe`` builds them). ``impl``: ``pallas``
+    or ``xla``."""
+    impls = _moe_gemm_impls()
+    if impl not in impls:
+        raise ValueError(f"no moe_expert_gemm impl {impl!r}; "
+                         f"available: {sorted(impls)}")
+    words = w.packed
+    kp = words.shape[-2] * 16              # K padded to whole words
+    if x.shape[1] != kp:
+        x = jnp.pad(x, ((0, 0), (0, kp - x.shape[1])))
+    kw = {}
+    if impl == "pallas":
+        kw["interpret"] = (_auto_interpret() if interpret is None
+                           else interpret)
+    return impls[impl](x, words, w.scale, tile_group, tile_row, n_active,
+                       block_m=block_m, **kw)
+
+
+# ---------------------------------------------------------------------------
 # The planner
 # ---------------------------------------------------------------------------
 

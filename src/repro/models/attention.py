@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops as kops
-from repro.models.layers import FSDP, MODEL, linear_apply, linear_init, rope
+from repro.models.layers import (FSDP, MODEL, linear_apply, linear_init,
+                                 norm_apply, norm_init, rope)
 
 NEG_INF = -1e30
 
@@ -31,8 +32,12 @@ def attn_init(key, cfg: ModelConfig, cross: bool = False):
     wk, sk = linear_init(ks[1], cfg, d, kv * hd, FSDP, MODEL)
     wv, sv = linear_init(ks[2], cfg, d, kv * hd, FSDP, MODEL)
     wo, so = linear_init(ks[3], cfg, h * hd, d, MODEL, FSDP)
-    return ({"q": wq, "k": wk, "v": wv, "o": wo},
-            {"q": sq, "k": sk, "v": sv, "o": so})
+    params = {"q": wq, "k": wk, "v": wv, "o": wo}
+    specs = {"q": sq, "k": sk, "v": sv, "o": so}
+    if cfg.qk_norm:
+        for name in ("q_norm", "k_norm"):
+            params[name], specs[name] = norm_init(None, cfg, hd)
+    return params, specs
 
 
 def _split_heads(x, n, hd):
@@ -262,9 +267,13 @@ def attn_apply(params, x: jnp.ndarray, cfg: ModelConfig, *,
     kv, hd = cfg.num_kv_heads, cfg.head_dim
     h = cfg.num_heads + cfg.head_pad
     q = _split_heads(linear_apply(params["q"], x, cfg), h, hd)
+    if "q_norm" in params:
+        q = norm_apply(params["q_norm"], q, cfg)
     if kv_override is None:
         k = _split_heads(linear_apply(params["k"], x, cfg), kv, hd)
         v = _split_heads(linear_apply(params["v"], x, cfg), kv, hd)
+        if "k_norm" in params:
+            k = norm_apply(params["k_norm"], k, cfg)
         if cfg.rope_theta:
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)

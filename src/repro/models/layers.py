@@ -194,7 +194,9 @@ def embed_init(key, cfg: ModelConfig):
 
 
 def embed_apply(params, tokens: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    return params["table"].astype(_dtype(cfg))[tokens]
+    # gather, then cast: casting the whole table first would write a
+    # table-sized temporary on every call
+    return params["table"][tokens].astype(_dtype(cfg))
 
 
 def unembed_init(key, cfg: ModelConfig):

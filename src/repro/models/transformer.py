@@ -5,7 +5,10 @@ and compile time are O(1) in depth — required for 61-80 layer dry-runs).
 Heterogeneous stacks (jamba's 1:7 attn:mamba interleave with MoE every 2nd
 layer) scan over *period groups*: the layer pattern repeats with period p
 (jamba: 8), params are stacked over L/p groups, and the scan body unrolls the
-p distinct blocks.
+p distinct blocks. Leading layers that break the period (a dense first layer
+before a stack of MoE layers) run once, unrolled, before the scan: they are
+``lead{i}`` in the parameter tree (unstacked) and in the cache tree (a
+leading axis of 1, like one scan group).
 
 Public API (all functional):
     m = LM(cfg)
@@ -17,7 +20,8 @@ Public API (all functional):
 """
 from __future__ import annotations
 
-from typing import Any, Dict
+import dataclasses
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,21 +56,53 @@ def _stack_specs(spec_tree):
         is_leaf=lambda x: isinstance(x, P))
 
 
+def split_period(kinds) -> Tuple[int, int]:
+    """(n_lead, period) of a layer sequence: ``n_lead`` leading layers run
+    once, then the rest repeats with ``period``. The split unrolls the
+    fewest layers (n_lead + period), fewer leading layers first, so a
+    stack that repeats from its first layer, two periods or more, keeps
+    n_lead = 0 and its smallest period."""
+    n = len(kinds)
+    for total in range(1, n + 1):
+        for lead in range(total):
+            p = total - lead
+            if (n - lead) % p == 0 and all(
+                    kinds[i] == kinds[lead + (i - lead) % p]
+                    for i in range(lead, n)):
+                return lead, p
+    return 0, n
+
+
 class LM:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
-        kinds = [(cfg.layer_kind(i), cfg.layer_ffn(i))
+        kinds = [(cfg.layer_kind(i), cfg.layer_ffn(i), cfg.layer_window(i))
                  for i in range(cfg.num_layers)]
-        # smallest period p with L % p == 0 and kinds periodic
-        p = 1
-        while p <= cfg.num_layers:
-            if cfg.num_layers % p == 0 and all(
-                    kinds[i] == kinds[i % p] for i in range(cfg.num_layers)):
-                break
-            p += 1
+        lead, p = split_period(kinds)
+        self.n_lead = lead
         self.period = p
-        self.n_groups = cfg.num_layers // p
-        self.block_kinds = kinds[:p]          # [(mixer, ffn)] * period
+        self.n_groups = (cfg.num_layers - lead) // p
+        self.lead_kinds = [k[:2] for k in kinds[:lead]]
+        self.lead_windows = [k[2] for k in kinds[:lead]]
+        self.block_kinds = [k[:2] for k in kinds[lead:lead + p]]
+        self.block_windows = [k[2] for k in kinds[lead:lead + p]]
+        self._layer_cfgs: Dict[int, ModelConfig] = {}
+
+    def layer_cfg(self, window: int) -> ModelConfig:
+        """The config one attention layer runs under: the stack's, with its
+        own window, and without RoPE in a full layer of a stack that
+        rotates its window layers only."""
+        cfg = self.cfg
+        if window not in self._layer_cfgs:
+            theta = (0.0 if cfg.rope_window_only and not window
+                     else cfg.rope_theta)
+            if window == cfg.sliding_window and theta == cfg.rope_theta:
+                self._layer_cfgs[window] = cfg
+            else:
+                self._layer_cfgs[window] = dataclasses.replace(
+                    cfg, sliding_window=window, layer_windows=(),
+                    rope_theta=theta)
+        return self._layer_cfgs[window]
 
     # ------------------------------------------------------------------
     # Init
@@ -100,7 +136,11 @@ class LM:
         params["embed"], specs["embed"] = layers.embed_init(keys[0], cfg)
         cross = cfg.is_encdec
 
-        # decoder blocks, stacked over groups per period-offset
+        # leading layers, once each; then the blocks, stacked over groups
+        # per period-offset
+        for i, (kind, ffn) in enumerate(self.lead_kinds):
+            params[f"lead{i}"], specs[f"lead{i}"] = self._block_init(
+                jax.random.fold_in(keys[6], i), kind, ffn, cross)
         for j, (kind, ffn) in enumerate(self.block_kinds):
             ps, ss = [], None
             for g in range(self.n_groups):
@@ -151,16 +191,25 @@ class LM:
     # ------------------------------------------------------------------
     def _apply_block(self, bparams, x, kind: str, ffn: str, *,
                      positions, causal=True, cache=None, cache_pos=None,
-                     enc_out=None, block_table=None):
+                     enc_out=None, block_table=None, window=None,
+                     token_mask=None):
+        """One layer -> (x, new_cache, aux loss, MoE stats (2,) int32:
+        (row, held expert) pairs computed and held experts with a row)."""
         cfg = self.cfg
-        h = layers.norm_apply(bparams["norm1"], x, cfg)
+        lcfg = self.layer_cfg(cfg.sliding_window if window is None
+                              else window)
+        post = cfg.sublayer_norm == "post"
+        h = x if post else layers.norm_apply(bparams["norm1"], x, cfg)
         if kind == "attn":
             h, new_cache = attention.attn_apply(
-                bparams["mixer"], h, cfg, positions=positions, causal=causal,
-                cache=cache, cache_pos=cache_pos, block_table=block_table)
+                bparams["mixer"], h, lcfg, positions=positions,
+                causal=causal, cache=cache, cache_pos=cache_pos,
+                block_table=block_table)
         else:
             h, new_cache = ssm.ssm_apply(
                 bparams["mixer"], h, cfg, cache=cache, cache_pos=cache_pos)
+        if post:
+            h = layers.norm_apply(bparams["norm1"], h, cfg)
         x = x + h
         if enc_out is not None and "cross" in bparams:
             hc = layers.norm_apply(bparams["norm_cross"], x, cfg)
@@ -174,57 +223,101 @@ class LM:
                 kv_override=(ek, ev))
             x = x + hc
         aux = jnp.zeros((), jnp.float32)
+        stats = jnp.zeros((2,), jnp.int32)
         if ffn != "none":
-            h2 = layers.norm_apply(bparams["norm2"], x, cfg)
+            h2 = x if post else layers.norm_apply(bparams["norm2"], x, cfg)
             if ffn == "moe":
-                h2, aux = moe.moe_apply(bparams["ffn"], h2, cfg)
+                h2, aux, stats = moe.moe_apply(bparams["ffn"], h2, cfg,
+                                               token_mask=token_mask)
             else:
                 h2 = layers.mlp_apply(bparams["ffn"], h2, cfg)
+            if post:
+                h2 = layers.norm_apply(bparams["norm2"], h2, cfg)
             x = x + h2
-        return x, new_cache, aux
+        return x, new_cache, aux, stats
 
     # ------------------------------------------------------------------
     # Stacked decoder
     # ------------------------------------------------------------------
     def _run_stack(self, params, x, *, positions, causal=True,
                    caches=None, cache_pos=None, enc_out=None,
-                   block_table=None):
-        """caches: dict block{j} -> stacked (n_groups, ...) cache trees.
-        ``block_table`` (paged decode) is layer-invariant, so it rides into
-        the scan body as a closure constant rather than a sliced xs leaf."""
+                   block_table=None, token_mask=None):
+        """caches: dict block{j} -> stacked (n_groups, ...) cache trees,
+        lead{i} -> (1, ...) trees. ``block_table`` (paged decode) is
+        layer-invariant, so it rides into the scan body as a closure
+        constant rather than a sliced xs leaf. ``token_mask`` (B, S) marks
+        the rows that carry a request: MoE layers route only those.
+        Returns (x, new caches, summed aux loss, summed MoE stats)."""
         cfg = self.cfg
+        kw = dict(positions=positions, causal=causal, cache_pos=cache_pos,
+                  enc_out=enc_out, block_table=block_table,
+                  token_mask=token_mask)
+        new_caches = {}
+        aux_lead = jnp.zeros((), jnp.float32)
+        stats_lead = jnp.zeros((2,), jnp.int32)
+        for i, (kind, ffn) in enumerate(self.lead_kinds):
+            c = caches.get(f"lead{i}") if caches is not None else None
+            if c is not None:
+                c = jax.tree.map(lambda a: a[0], c)
+            x, nc, aux, stats = self._apply_block(
+                params[f"lead{i}"], x, kind, ffn, cache=c,
+                window=self.lead_windows[i], **kw)
+            aux_lead += aux
+            stats_lead += stats
+            if nc is not None:
+                new_caches[f"lead{i}"] = jax.tree.map(lambda a: a[None], nc)
 
+        # The stacked caches ride in the scan's carry: each layer reads its
+        # group's slice and writes its new cache back in place, so no
+        # second cache-sized stack is built. A layer whose new cache has
+        # another form (the 'opt' layout's new tokens) emits it per group.
         def body(carry, xs):
-            x = carry
+            x, stacked = carry
+            stacked = dict(stacked)
             aux_total = jnp.zeros((), jnp.float32)
-            new_caches = {}
+            stats_total = jnp.zeros((2,), jnp.int32)
+            emitted = {}
             for j, (kind, ffn) in enumerate(self.block_kinds):
-                c = xs[f"cache{j}"] if caches is not None else None
-                x, nc, aux = self._apply_block(
-                    xs[f"block{j}"], x, kind, ffn, positions=positions,
-                    causal=causal, cache=c, cache_pos=cache_pos,
-                    enc_out=enc_out, block_table=block_table)
+                key = f"cache{j}"
+                c = None
+                if key in stacked:
+                    c = jax.tree.map(lambda a: a[xs["g"]], stacked[key])
+                x, nc, aux, stats = self._apply_block(
+                    xs[f"block{j}"], x, kind, ffn, cache=c,
+                    window=self.block_windows[j], **kw)
                 aux_total += aux
-                if nc is not None:
-                    new_caches[f"cache{j}"] = nc
-            return x, (new_caches, aux_total)
+                stats_total += stats
+                if nc is None:
+                    continue
+                if (jax.tree_util.tree_structure(nc)
+                        == jax.tree_util.tree_structure(c)):
+                    stacked[key] = jax.tree.map(
+                        lambda a, n: a.at[xs["g"]].set(n.astype(a.dtype)),
+                        stacked[key], nc)
+                else:
+                    emitted[key] = nc
+            return (x, stacked), (emitted, aux_total, stats_total)
 
         # Remat only where there is a backward pass to save memory for —
         # wrapping the serving scans in jax.checkpoint makes XLA route the
         # full stacked KV cache through f32 select/convert chains every
-        # layer step (measured +150 GB/chip/step on decode_32k; see
-        # EXPERIMENTS.md §Perf iteration A1).
+        # layer step (measured +150 GB/chip/step on decode_32k).
         if cfg.remat == "full" and caches is None:
             body = jax.checkpoint(body, prevent_cse=False)
 
         xs = {f"block{j}": params[f"block{j}"]
               for j in range(len(self.block_kinds))}
+        xs["g"] = jnp.arange(self.n_groups)
+        stacked = {f"cache{j}": caches[f"cache{j}"]
+                   for j in range(len(self.block_kinds))
+                   if caches is not None and f"cache{j}" in caches}
+        (x, stacked), (emitted, auxs, stats) = jax.lax.scan(
+            body, (x, stacked), xs)
         if caches is not None:
-            xs.update({f"cache{j}": caches[f"cache{j}"]
-                       for j in range(len(self.block_kinds))
-                       if f"cache{j}" in caches})
-        x, (new_caches, auxs) = jax.lax.scan(body, x, xs)
-        return x, new_caches, jnp.sum(auxs)
+            new_caches.update(stacked)
+            new_caches.update(emitted)
+        return (x, new_caches, aux_lead + jnp.sum(auxs),
+                stats_lead + jnp.sum(stats, axis=0))
 
     def _run_encoder(self, params, enc_x):
         cfg = self.cfg
@@ -232,8 +325,9 @@ class LM:
             jnp.arange(enc_x.shape[1]), enc_x.shape[:2])
 
         def body(x, bp):
-            x, _, _ = self._apply_block(bp, x, "attn", "mlp",
-                                        positions=positions, causal=False)
+            x, _, _, _ = self._apply_block(bp, x, "attn", "mlp",
+                                           positions=positions,
+                                           causal=False)
             return x, None
 
         if cfg.remat == "full":
@@ -269,8 +363,8 @@ class LM:
             enc_out = self._run_encoder(params, batch["enc_embeds"].astype(x.dtype))
         positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
         x = _shard_act(x, P(("pod", "data"), None, None))
-        x, _, aux = self._run_stack(params, x, positions=positions,
-                                    causal=True, enc_out=enc_out)
+        x, _, aux, _ = self._run_stack(params, x, positions=positions,
+                                       causal=True, enc_out=enc_out)
         x = layers.norm_apply(params["final_norm"], x, cfg)
         return x, n_front, aux
 
@@ -303,17 +397,25 @@ class LM:
     # ------------------------------------------------------------------
     # Serving
     # ------------------------------------------------------------------
+    def _cache_slots(self):
+        """(cache key, mixer kind, leading layer count) of every entry of
+        the cache tree: leading layers, then the scanned blocks."""
+        return ([(f"lead{i}", kind, 1)
+                 for i, (kind, _) in enumerate(self.lead_kinds)]
+                + [(f"cache{j}", kind, self.n_groups)
+                   for j, (kind, _) in enumerate(self.block_kinds)])
+
     def init_cache(self, batch: int, max_len: int, dtype=None):
         cfg = self.cfg
         dtype = jnp.dtype(cfg.cache_dtype) if dtype is None else dtype
         caches = {}
-        for j, (kind, _) in enumerate(self.block_kinds):
+        for key, kind, n in self._cache_slots():
             if kind == "attn":
                 one = attention.init_kv_cache(cfg, batch, max_len, dtype)
             else:
                 one = ssm.init_ssm_cache(cfg, batch, dtype)
-            caches[f"cache{j}"] = jax.tree.map(
-                lambda x: jnp.broadcast_to(x, (self.n_groups, *x.shape)), one)
+            caches[key] = jax.tree.map(
+                lambda x, n=n: jnp.broadcast_to(x, (n, *x.shape)), one)
         return {"layers": caches, "pos": jnp.zeros((), jnp.int32)}
 
     def init_paged_cache(self, n_pages: int, page_size: int, batch: int,
@@ -326,14 +428,14 @@ class LM:
         cfg = self.cfg
         dtype = jnp.dtype(cfg.cache_dtype) if dtype is None else dtype
         caches = {}
-        for j, (kind, _) in enumerate(self.block_kinds):
+        for key, kind, n in self._cache_slots():
             if kind == "attn":
                 one = attention.init_paged_kv_cache(
                     cfg, n_pages, page_size, dtype, kv_dtype)
             else:
                 one = ssm.init_ssm_cache(cfg, batch, dtype)
-            caches[f"cache{j}"] = jax.tree.map(
-                lambda x: jnp.broadcast_to(x, (self.n_groups, *x.shape)), one)
+            caches[key] = jax.tree.map(
+                lambda x, n=n: jnp.broadcast_to(x, (n, *x.shape)), one)
         return {"layers": caches}
 
     @staticmethod
@@ -360,7 +462,7 @@ class LM:
             # is an in-place write instead of a GSPMD select over the whole
             # cache shard (§Perf iteration A2)
             seq_ax, hd_ax = None, MODEL
-        for j, (kind, _) in enumerate(self.block_kinds):
+        for key, kind, _ in self._cache_slots():
             if kind == "attn":
                 if cfg.cache_layout == "opt":
                     # K (.., B, KV, S, hd) / V (.., B, KV, hd, S): seq
@@ -378,7 +480,7 @@ class LM:
             else:
                 one = {"state": P(None, ("pod", "data"), None, None, None),
                        "conv": P(None, ("pod", "data"), None, MODEL)}
-            caches[f"cache{j}"] = one
+            caches[key] = one
         return {"layers": caches, "pos": P()}
 
     def prefill(self, params, batch, max_len: int, cache_dtype=jnp.bfloat16):
@@ -391,7 +493,7 @@ class LM:
         positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
         x = _shard_act(x, P(("pod", "data"), None, None))
         cache0 = self.init_cache(x.shape[0], max_len, cache_dtype)
-        x, new_caches, _ = self._run_stack(
+        x, new_caches, _, _ = self._run_stack(
             params, x, positions=positions, causal=True,
             caches=cache0["layers"], cache_pos=None, enc_out=enc_out)
         x = layers.norm_apply(params["final_norm"], x, cfg)
@@ -425,7 +527,7 @@ class LM:
             return cache_len <= cfg.sliding_window      # rolling cache
         return False
 
-    def decode_step(self, params, cache, tokens):
+    def decode_step(self, params, cache, tokens, with_stats: bool = False):
         """tokens: (B, S) -> (logits (B,S,V), updated cache). S is 1 for
         plain decode; S > 1 is a speculative-decoding *verify window*
         (DESIGN.md §10): the S tokens sit at consecutive positions
@@ -436,16 +538,30 @@ class LM:
         at the same position) or a (B,) vector (continuous batching: each
         slot decodes at its own position; K/V writes scatter per slot).
         A ``cache["block_table"]`` entry switches attention layers to the
-        paged cache path (pages + block tables, DESIGN.md §9)."""
+        paged cache path (pages + block tables, DESIGN.md §9). A
+        ``cache["token_mask"]`` (B, S) bool marks the tokens that carry a
+        request (the engine's pad lanes are False): MoE layers route only
+        those. ``with_stats`` adds a third result, (2,) int32 summed over
+        the MoE layers: (row, held expert) pairs computed and held experts
+        that got a row."""
         cfg = self.cfg
         pos = cache["pos"]
         sq = tokens.shape[1]
         if sq > 1 and self._decode_window_unrolled(cache):
             lgs, cur = [], cache
+            mask = cache.get("token_mask")
+            stats = jnp.zeros((2,), jnp.int32)
             for j in range(sq):
-                lg, cur = self.decode_step(params, cur, tokens[:, j:j + 1])
+                if mask is not None:
+                    cur = dict(cur, token_mask=mask[:, j:j + 1])
+                lg, cur, st = self.decode_step(params, cur,
+                                               tokens[:, j:j + 1], True)
+                stats = stats + st
                 lgs.append(lg)
-            return jnp.concatenate(lgs, axis=1), cur
+            if mask is not None:
+                cur = dict(cur, token_mask=mask)
+            out = jnp.concatenate(lgs, axis=1), cur
+            return out + (stats,) if with_stats else out
         positions_src = pos[:, None] if jnp.ndim(pos) else pos
         x = layers.embed_apply(params["embed"], tokens, cfg)
         if sq == 1:
@@ -453,11 +569,12 @@ class LM:
         else:
             positions = jnp.broadcast_to(positions_src + jnp.arange(sq),
                                          tokens.shape)
-        x, new_caches, _ = self._run_stack(
+        x, new_caches, _, stats = self._run_stack(
             params, x, positions=positions, causal=True,
             caches=cache["layers"], cache_pos=pos,
             enc_out=cache.get("enc_out"),
-            block_table=cache.get("block_table"))
+            block_table=cache.get("block_table"),
+            token_mask=cache.get("token_mask"))
         x = layers.norm_apply(params["final_norm"], x, cfg)
         logits = self._logits(params, x)
         # delta-mode commit (§Perf A7): the scan emitted per-layer K/V
@@ -491,6 +608,8 @@ class LM:
             else:
                 committed[key] = nc
         new_cache = dict(cache, layers=committed, pos=pos + sq)
+        if with_stats:
+            return logits, new_cache, stats
         return logits, new_cache
 
 

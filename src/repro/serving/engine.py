@@ -112,6 +112,7 @@ from repro.serving.faults import (FAIL_DEADLINE, FAIL_NUMERIC, FaultConfig,
                                   FaultInjector, ResilienceConfig)
 from repro.serving.queue import Request, RequestQueue
 from repro.serving.sched import ChunkRunner, SchedConfig, SLOQueue
+from repro.serving.sched.chunker import checks_and_stats
 from repro.serving.sched.slo import plan_chunks
 from repro.serving.slots import SlotPool
 
@@ -130,6 +131,8 @@ _STRAGGLER_FACTOR = 8.0
 # the phases whose programs compute token rows, each with a pair of row
 # counters (see ``ContinuousScheduler.__init__``)
 ROW_PHASES = ("prefill", "chunk", "decode", "verify")
+# the phases whose programs route rows through MoE layers with a token mask
+MOE_PHASES = ("chunk", "decode")
 
 
 class ContinuousScheduler:
@@ -195,6 +198,11 @@ class ContinuousScheduler:
                     "sliding-window caches: a rejected window write "
                     "overwrites the oldest live entry, which rollback "
                     "cannot restore")
+            if self.model.n_lead or any(cfg.layer_windows):
+                raise ValueError(
+                    "speculative decoding needs a stack without leading "
+                    "layers or per-layer windows: the draft is a slice "
+                    "of whole scan groups")
         self.spec = spec
         # ---- chunked prefill + SLO admission (DESIGN.md §14) ----
         if sched is not None and sched.chunked:
@@ -238,6 +246,15 @@ class ContinuousScheduler:
          self._rows_verify) = [
             (self.metrics.counter(f"rows_computed.{ph}"),
              self.metrics.counter(f"rows_real.{ph}")) for ph in ROW_PHASES]
+        # MoE layers' (row, held expert) pairs computed and held experts
+        # with a row, summed over the layers; each program returns them
+        # in the tail of its finite-check vector, read back with it
+        self._has_moe = any(ffn == "moe" for _, ffn in
+                            self.model.lead_kinds + self.model.block_kinds)
+        self._experts_chunk, self._experts_decode = [
+            (self.metrics.counter(f"expert_rows.{ph}"),
+             self.metrics.counter(f"experts_active.{ph}"))
+            for ph in MOE_PHASES] if self._has_moe else (None, None)
         if cache == "paged":
             from repro.paging import PagePool
             self.pool = PagePool(self.model, max_slots, max_len,
@@ -256,6 +273,8 @@ class ContinuousScheduler:
         self._prev_tok = np.zeros(max_slots, np.int32)
         self._dev_pos = jnp.zeros(max_slots, jnp.int32)
         self._dev_tok = jnp.zeros(max_slots, jnp.int32)
+        # rows of the decode batch that carry a request (MoE routing mask)
+        self._dev_live = jnp.zeros(max_slots, jnp.bool_)
         self._dev_prev = jnp.zeros(max_slots, jnp.int32)
         self._dirty = False           # host mirrors newer than device state
         self._finished: List[Request] = []
@@ -305,12 +324,12 @@ class ContinuousScheduler:
             self.pool.layers = tp_lib.device_put_cache(
                 self.pool.layers, cfg, self.mesh)
             (self._dev_pos, self._dev_tok, self._dev_prev,
-             self._no_nan) = jax.device_put(
+             self._no_nan, self._dev_live) = jax.device_put(
                 (self._dev_pos, self._dev_tok, self._dev_prev,
-                 self._no_nan),
+                 self._no_nan, self._dev_live),
                 tp_lib.replicated_sharding(
                     (self._dev_pos, self._dev_tok, self._dev_prev,
-                     self._no_nan), self.mesh))
+                     self._no_nan, self._dev_live), self.mesh))
             if cache == "paged":
                 self._dev_table = jax.device_put(
                     self._dev_table,
@@ -327,25 +346,29 @@ class ContinuousScheduler:
             return cache_["layers"], jnp.argmax(logits[:, -1],
                                                 axis=-1).astype(jnp.int32)
 
-        def engine_decode(params, layers, pos, toks, nan_mask, table=None):
+        def engine_decode(params, layers, pos, toks, nan_mask, live,
+                          table=None):
             # free slots keep decoding garbage; clamp their write position
             # so it can never run past the cache (live rows are bounded by
             # the submit-time prompt+budget <= max_len assertion). Paged,
             # their block tables are all-zero, so the clamped garbage
-            # writes land in the pool's reserved trash page 0
+            # writes land in the pool's reserved trash page 0. Rows not
+            # ``live`` route to no expert
             cache_ = {"layers": layers,
-                      "pos": jnp.minimum(pos, max_len - 1)}
+                      "pos": jnp.minimum(pos, max_len - 1),
+                      "token_mask": live[:, None]}
             if table is not None:
                 cache_["block_table"] = table
-            logits, new_cache = self.model.decode_step(params, cache_,
-                                                       toks[:, None])
+            logits, new_cache, stats = self.model.decode_step(
+                params, cache_, toks[:, None], with_stats=True)
             # §11 numerical guard: fault injection corrupts masked rows
             # *before* the finite check (all-false mask = bitwise no-op);
             # a non-finite row quarantines its slot instead of committing
             row = jnp.where(nan_mask[:, None], jnp.nan, logits[:, 0, :])
             ok = jnp.all(jnp.isfinite(row), axis=-1)
             nxt = jnp.argmax(row, axis=-1).astype(jnp.int32)
-            return new_cache["layers"], new_cache["pos"], nxt, ok
+            return (new_cache["layers"], new_cache["pos"], nxt,
+                    checks_and_stats(ok, stats))
 
         self._prefill = jax.jit(engine_prefill)
         # one program; the paged path calls it as ``_decode_paged`` (the
@@ -404,7 +427,7 @@ class ContinuousScheduler:
                        if self.spec else ()),
             chunk_ms=chunk_ms,
             # only packed linears dispatch through ternary_gemm; MoE expert
-            # banks are materialized in moe_apply and need no GEMM plan
+            # banks run through moe_expert_gemm, which takes no plan
             select=is_packed_linear,
             # warm exactly the impl linear_apply will dispatch ("ref"
             # off-TPU touches no autotune state)
@@ -882,7 +905,7 @@ class ContinuousScheduler:
         real.inc(sum(c for _, _, c in jobs))
         with phase("engine.chunk_readback", tr, pid):
             greedy = np.asarray(greedy_dev)
-            ok = np.asarray(ok_dev)
+            ok = self._split_stats(np.asarray(ok_dev), self._experts_chunk)
         now = obs_clock.now()
         with phase("engine.commit", tr, pid):
             self._commit_chunks(jobs, greedy, ok, t_window, now)
@@ -1018,15 +1041,17 @@ class ContinuousScheduler:
                 if self.cache_mode == "paged":
                     out = self._decode_paged(
                         self.params, self.pool.layers, self._dev_pos,
-                        self._dev_tok, mask, self._dev_table)
+                        self._dev_tok, mask, self._dev_live,
+                        self._dev_table)
                 else:
                     out = self._decode(self.params, self.pool.layers,
-                                       self._dev_pos, self._dev_tok, mask)
+                                       self._dev_pos, self._dev_tok, mask,
+                                       self._dev_live)
                 self.pool.layers, self._dev_pos, self._dev_tok, ok_dev = out
         self.decode_steps += 1
         with phase("engine.decode_readback", tr, pid):
             toks = np.asarray(self._dev_tok)
-            ok = np.asarray(ok_dev)
+            ok = self._split_stats(np.asarray(ok_dev), self._experts_decode)
         committed = 0
         with phase("engine.commit", tr, pid):
             for slot in list(self._live):
@@ -1051,18 +1076,31 @@ class ContinuousScheduler:
         self._note_step_time(t_step)
 
     def _upload(self) -> None:
-        """Push the host mirrors the device is behind on: positions and
-        tokens after admit/evict events, the block table after page
+        """Push the host mirrors the device is behind on: positions, tokens
+        and live rows after admit/evict events, the block table after page
         growth."""
         if self._dirty:
             self._dev_pos = jnp.asarray(self._pos)
             self._dev_tok = jnp.asarray(self._tok)
+            if self._has_moe:
+                live = np.zeros(self.max_slots, bool)
+                live[list(self._live)] = True
+                self._dev_live = jnp.asarray(live)
             if self.spec is not None:
                 self._dev_prev = jnp.asarray(self._prev_tok)
             self._dirty = False
         if self.cache_mode == "paged" and self.pool.table_dirty:
             self._dev_table = jnp.asarray(self.pool.table)
             self.pool.table_dirty = False
+
+    def _split_stats(self, checks: np.ndarray, counters) -> np.ndarray:
+        """A program's finite-check vector with its MoE stats in the tail
+        (``checks_and_stats``): count the stats, return the checks."""
+        n = checks.size - 2
+        if counters is not None:
+            for c, v in zip(counters, checks[n:]):
+                c.inc(int(v))
+        return checks[:n]
 
     @property
     def _step_ema(self) -> float:

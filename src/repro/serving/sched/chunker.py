@@ -47,7 +47,15 @@ import numpy as np
 
 from repro.kernels import ops as kops
 
-__all__ = ["ChunkRunner"]
+__all__ = ["ChunkRunner", "checks_and_stats"]
+
+
+def checks_and_stats(ok, moe_stats):
+    """The one int32 vector a step's program returns for its readback:
+    each row's finite check, then its MoE stats ((row, held expert) pairs
+    computed, held experts with a row)."""
+    return jnp.concatenate([ok.astype(jnp.int32).reshape(-1),
+                            moe_stats.astype(jnp.int32)])
 
 
 class ChunkRunner:
@@ -61,14 +69,19 @@ class ChunkRunner:
         self.rows = rows
 
         # the function names name the programs in a profiler trace
-        def engine_chunk_window(params, layers, pos, toks, table=None):
-            cache = {"layers": layers, "pos": pos}
+        def engine_chunk_window(params, layers, pos, toks, real,
+                                table=None):
+            # tokens not ``real`` (pad rows, repeat-last padding) route to
+            # no expert
+            cache = {"layers": layers, "pos": pos, "token_mask": real}
             if table is not None:
                 cache["block_table"] = table
-            logits, new_cache = model.decode_step(params, cache, toks)
+            logits, new_cache, stats = model.decode_step(
+                params, cache, toks, with_stats=True)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             ok = jnp.all(jnp.isfinite(logits), axis=(1, 2))
-            return new_cache["layers"], greedy, ok
+            return (new_cache["layers"], greedy,
+                    checks_and_stats(ok, stats))
 
         def engine_chunk_gather(layers, idx):
             return jax.tree.map(lambda x: x[:, idx], layers)
@@ -79,21 +92,25 @@ class ChunkRunner:
             self._gather = jax.jit(engine_chunk_gather)
 
     # ------------------------------------------------------------------
-    def pack_window(self, jobs) -> Tuple[List[int], np.ndarray, np.ndarray]:
+    def pack_window(self, jobs) -> Tuple[List[int], np.ndarray, np.ndarray,
+                                         np.ndarray]:
         """Rectangularize ``[(slot, req, c)]`` into the fixed-row batched
         window: real slot list, (rows,) start positions, (rows, S) tokens
-        with repeat-last padding. Rows beyond ``len(jobs)`` are pad lanes
-        (position 0, token 0) whose writes the caller discards."""
+        with repeat-last padding, and the (rows, S) mask of real tokens.
+        Rows beyond ``len(jobs)`` are pad lanes (position 0, token 0)
+        whose writes the caller discards."""
         slots = [s for s, _, _ in jobs]
         pos = np.zeros(self.rows, np.int32)
         s_max = max(c for _, _, c in jobs)
         toks = np.zeros((self.rows, s_max), np.int32)
+        real = np.zeros((self.rows, s_max), bool)
         for i, (_, req, c) in enumerate(jobs):
             a = req.prefill_pos
             pos[i] = a
             toks[i, :c] = req.prompt[a:a + c]
             toks[i, c:] = req.prompt[a + c - 1]
-        return slots, pos, toks
+            real[i, :c] = True
+        return slots, pos, toks, real
 
     def _pad_table(self, pool, slots) -> jnp.ndarray:
         """(rows, T) block table: real rows from the pool, pad rows all
@@ -111,15 +128,17 @@ class ChunkRunner:
         first ``len(jobs)`` rows follow ``jobs`` order: greedy[i, j] is
         the argmax after job i's token j (a completing row reads its
         first output token at its last real chunk position), ok[i] the
-        per-row finite-logits guard. ``greedy`` has one entry per row the
+        per-row finite-logits guard followed by the window's MoE stats
+        (``checks_and_stats``). ``greedy`` has one entry per row the
         window computes, pad rows included."""
-        slots, pos, toks = self.pack_window(jobs)
+        slots, pos, toks, real = self.pack_window(jobs)
         dev_pos = jnp.asarray(pos)
         dev_toks = jnp.asarray(toks)
+        dev_real = jnp.asarray(real)
         if self.paged:
             with kops.serving_phase("chunk"):
                 pool.layers, greedy, ok = self._fwd(
-                    params, pool.layers, dev_pos, dev_toks,
+                    params, pool.layers, dev_pos, dev_toks, dev_real,
                     self._pad_table(pool, slots))
         else:
             # pad lanes gather slot 0's rows; the garbage they compute
@@ -130,7 +149,7 @@ class ChunkRunner:
             gathered = self._gather(pool.layers, jnp.asarray(idx))
             with kops.serving_phase("chunk"):
                 gathered, greedy, ok = self._fwd(
-                    params, gathered, dev_pos, dev_toks)
+                    params, gathered, dev_pos, dev_toks, dev_real)
             pool.insert(slots, jax.tree.map(lambda x: x[:, :len(slots)],
                                             gathered))
         return greedy, ok
@@ -143,12 +162,13 @@ class ChunkRunner:
         for s in windows:
             pos = jnp.zeros(self.rows, jnp.int32)
             toks = jnp.zeros((self.rows, int(s)), jnp.int32)
+            real = jnp.zeros((self.rows, int(s)), jnp.bool_)
             with kops.serving_phase("chunk"):
                 if self.paged:
                     pool.layers, _, _ = self._fwd(
-                        params, pool.layers, pos, toks,
+                        params, pool.layers, pos, toks, real,
                         self._pad_table(pool, []))
                 else:
                     gathered = self._gather(
                         pool.layers, jnp.zeros(self.rows, jnp.int32))
-                    self._fwd(params, gathered, pos, toks)
+                    self._fwd(params, gathered, pos, toks, real)

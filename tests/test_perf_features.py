@@ -101,10 +101,13 @@ def test_blocked_moe_routing_matches_global():
                                rtol=1e-4, atol=1e-4)
 
 
-def test_packed_moe_matches_qat():
-    """MoE experts in the 2-bit packed serving format == QAT forward."""
+def test_packed_moe_matches_qat(monkeypatch):
+    """MoE experts in the 2-bit packed serving format == QAT forward: the
+    packed banks run through the grouped expert GEMM (gate, up and down),
+    dropless, and no bank is decoded whole."""
     import dataclasses
     from repro.core import weights
+    from repro.kernels import ops as kops
     from repro.models import layers as L
     cfg = get_config("mixtral-8x22b", reduced=True, dtype="float32",
                      ternary_min_dim=64, quantization="ternary",
@@ -121,7 +124,13 @@ def test_packed_moe_matches_qat():
     assert moe_node["w_in"].packed.ndim == 4       # (L, E, K/16, N) leaves
     cfg2 = dataclasses.replace(cfg, quantization="ternary_packed")
     m2 = LM(cfg2)
+    calls = []
+    grouped = kops.moe_expert_gemm
+    monkeypatch.setattr(kops, "moe_expert_gemm",
+                        lambda *a, **k: calls.append(1) or grouped(*a, **k))
+    monkeypatch.setattr(weights.Dense2Bit, "materialize", None)
     x2, _, _ = m2.forward(packed, {"tokens": toks})
+    assert len(calls) == 3
     np.testing.assert_allclose(np.asarray(x1, np.float32),
                                np.asarray(x2, np.float32),
                                rtol=1e-3, atol=1e-3)

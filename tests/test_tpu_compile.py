@@ -237,3 +237,24 @@ def test_tp_sharded_packed_linear_runs_per_shard(topo):
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert any(f"u32[{K // 16},{N // 2}]" in ln for ln in calls), calls
     assert not any(f"u32[{K // 16},{N}]" in ln for ln in calls), calls
+
+
+@pytest.mark.parametrize("k,n,m,bm", [(6144, 2048, 384, 16),
+                                      (2048, 6144, 384, 16),
+                                      (6144, 2048, 5120, 128)])
+def test_moe_expert_gemm_compiles_at_k_exaone_widths(sds, k, n, m, bm):
+    """The grouped expert GEMM at K-EXAONE widths (hidden 6144, experts of
+    2048, 8 held): gate/up (K 6144, N 2048) and down (K 2048, N 6144) at a
+    decode's 32 x 8 pairs in 16-row tiles, and gate/up at a chunk window's
+    in 128-row tiles. Its ``pallas_call`` falls in the ``expert`` class,
+    not ``gemm``."""
+    me = importlib.import_module("repro.kernels.moe_gemm")
+    e, tiles = 8, m // bm
+
+    def f(x, w, s, g, r, a):
+        return me.moe_expert_gemm_pallas(x, w, s, g, r, a, block_m=bm,
+                                         interpret=False)
+    compiled = _compile(f, sds((m, k), BF16), sds((e, k // 16, n), U32),
+                        sds((e, n), jnp.float32), sds((tiles,), jnp.int32),
+                        sds((tiles,), jnp.int32), sds((1,), jnp.int32))
+    assert _kernel_classes(compiled) == {"expert"}
