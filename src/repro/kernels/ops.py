@@ -715,32 +715,44 @@ def resolve_paged_attn(impl: str = "auto") -> str:
     return impl
 
 
+def page_stack(pages):
+    """KV pages as a stack of layer groups: one layer's (P, ps, KV, hd)
+    array or ``Int8Pages`` is viewed as the one-group stack
+    (1, P, ps, KV, hd); a stacked (G, P, ps, KV, hd) pool is returned as
+    it is."""
+    if jax.tree_util.tree_leaves(pages)[0].ndim == 4:
+        return jax.tree.map(lambda a: a[None], pages)
+    return pages
+
+
 def paged_decode_attention(q, k_pages, v_pages, block_table, lengths, *,
-                           window: int = 0, impl: str = "auto",
+                           layer=0, window: int = 0, impl: str = "auto",
                            interpret: Optional[bool] = None):
     """Decode attention over block-table-indexed KV pages.
 
-    q (B, H, hd); k_pages/v_pages (P, ps, KV, hd) arrays or
-    ``paging.quant.Int8Pages``; block_table (B, T) int32; lengths (B,)
-    int32 valid-token counts (including the current token). ``impl`` picks
-    a registered lowering (``resolve_paged_attn``)."""
+    q (B, H, hd); k_pages/v_pages the stacked (G, P, ps, KV, hd) pool
+    (arrays or ``paging.quant.Int8Pages``), of which group ``layer`` (an
+    int32 scalar, traced or not) is read, or one layer's (P, ps, KV, hd)
+    pages (``page_stack``); block_table (B, T) int32; lengths (B,) int32
+    valid-token counts (including the current token). ``impl`` picks a
+    registered lowering (``resolve_paged_attn``)."""
     chosen = _PAGED_ATTN[resolve_paged_attn(impl)]
     mesh = current_tp_mesh()
     if mesh is not None and chosen.impl != "jax":
         return _tp_paged_attention(mesh, chosen.fn, q, k_pages, v_pages,
-                                   block_table, lengths, window=window,
-                                   interpret=interpret)
-    return chosen.fn(q, k_pages, v_pages, block_table, lengths,
+                                   block_table, lengths, layer,
+                                   window=window, interpret=interpret)
+    return chosen.fn(q, k_pages, v_pages, block_table, lengths, layer=layer,
                      window=window, interpret=interpret)
 
 
 def paged_window_attention(q, k_pages, v_pages, block_table, lengths, *,
-                           window: int = 0, impl: str = "auto",
+                           layer=0, window: int = 0, impl: str = "auto",
                            interpret: Optional[bool] = None):
     """Attention of an S-token window per row over block-table-indexed KV
     pages (chunked prefill, speculative verify).
 
-    q (B, S, H, hd); pages and block_table (B, T) as in
+    q (B, S, H, hd); pages, ``layer`` and block_table (B, T) as in
     ``paged_decode_attention``; lengths (B,) int32 valid-token count of
     each row's first window token, whose K/V is already in the pages:
     token ``j`` attends the keys at positions ``< lengths + j``. Returns
@@ -754,45 +766,49 @@ def paged_window_attention(q, k_pages, v_pages, block_table, lengths, *,
         o = paged_decode_attention(
             q.reshape(b * s, *q.shape[2:]), k_pages, v_pages,
             jnp.repeat(block_table, s, axis=0),
-            (lengths[:, None] + jnp.arange(s)).reshape(-1), window=window,
-            impl=chosen.impl, interpret=interpret)
+            (lengths[:, None] + jnp.arange(s)).reshape(-1), layer=layer,
+            window=window, impl=chosen.impl, interpret=interpret)
         return o.reshape(q.shape)
     mesh = current_tp_mesh()
     if mesh is not None:
         return _tp_paged_attention(mesh, chosen.window_fn, q, k_pages,
-                                   v_pages, block_table, lengths,
+                                   v_pages, block_table, lengths, layer,
                                    window=window, interpret=interpret)
     return chosen.window_fn(q, k_pages, v_pages, block_table, lengths,
-                            window=window, interpret=interpret)
+                            layer=layer, window=window, interpret=interpret)
 
 
 def _tp_paged_attention(mesh, fn, q, k_pages, v_pages, block_table, lengths,
-                        *, window, interpret):
+                        layer, *, window, interpret):
     """A Pallas paged lowering per shard: q heads (the axis before
-    ``head_dim``, for decode rows and windows alike) and the pages'
-    KV-head axis split over ``"model"`` (the layout ``distributed.tp.
-    cache_sharding`` places), block table and lengths replicated."""
+    ``head_dim``, for decode rows and windows alike) and the stacked
+    pages' KV-head axis split over ``"model"`` (the layout ``distributed.
+    tp.cache_sharding`` places), block table, lengths and layer
+    replicated."""
     ntp = dict(mesh.shape)["model"]
-    kv = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
+    k_pages, v_pages = page_stack(k_pages), page_stack(v_pages)
+    kv = jax.tree_util.tree_leaves(k_pages)[0].shape[3]
     if q.shape[-2] % ntp or kv % ntp:
         raise ValueError(
             f"paged attention: {q.shape[-2]} query / {kv} KV heads do not "
             f"split {ntp} ways over the mesh's 'model' axis")
 
     def page_spec(a):
-        # (P, ps, KV, hd) pages and (P, ps, KV) int8 scales
-        return P(None, None, "model", None) if a.ndim == 4 \
-            else P(None, None, "model")
+        # (G, P, ps, KV, hd) pages and (G, P, ps, KV) int8 scales
+        return P(None, None, None, "model", None) if a.ndim == 5 \
+            else P(None, None, None, "model")
 
     heads = P(*[None] * (q.ndim - 2), "model", None)
     return jax.shard_map(
-        lambda qq, kk, vv, bt, ln: fn(qq, kk, vv, bt, ln, window=window,
-                                      interpret=interpret),
+        lambda qq, kk, vv, bt, ln, ly: fn(qq, kk, vv, bt, ln, layer=ly,
+                                          window=window,
+                                          interpret=interpret),
         mesh=mesh,
         in_specs=(heads, jax.tree.map(page_spec, k_pages),
-                  jax.tree.map(page_spec, v_pages), P(), P()),
+                  jax.tree.map(page_spec, v_pages), P(), P(), P()),
         out_specs=heads, check_vma=False)(
-            q, k_pages, v_pages, block_table, lengths)
+            q, k_pages, v_pages, block_table, lengths,
+            jnp.asarray(layer, jnp.int32))
 
 
 # ---------------------------------------------------------------------------

@@ -246,6 +246,7 @@ def attn_apply(params, x: jnp.ndarray, cfg: ModelConfig, *,
                cache_pos: Optional[jnp.ndarray] = None,
                kv_override: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
                block_table: Optional[jnp.ndarray] = None,
+               layer=None,
                ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """One attention layer.
 
@@ -258,10 +259,12 @@ def attn_apply(params, x: jnp.ndarray, cfg: ModelConfig, *,
       each window token's logits equal to what sequential decode at its
       position would produce. Non-rolling caches only (the caller —
       ``LM.decode_step`` — unrolls rolling-SWA layouts per token instead).
-    * paged decode: cache = {"k_pages", "v_pages"} + block_table (B, T) +
-      cache_pos (B,) vector (DESIGN.md §9); prefill never sees a paged
-      cache — the page pool scatters prefilled dense rows into pages.
-      Multi-token windows attend through ``kops.paged_window_attention``.
+    * paged decode: cache = the stacked {"k_pages", "v_pages"} pool
+      (G, P, ps, KV, hd) + ``layer``, the group this layer reads and
+      writes, + block_table (B, T) + cache_pos (B,) vector (DESIGN.md §9);
+      the new cache is the whole pool. Prefill never sees a paged cache —
+      the page pool scatters prefilled dense rows into pages. Multi-token
+      windows attend through ``kops.paged_window_attention``.
     * cross-attention: kv_override = (k, v) precomputed from the encoder.
     """
     kv, hd = cfg.num_kv_heads, cfg.head_dim
@@ -282,11 +285,12 @@ def attn_apply(params, x: jnp.ndarray, cfg: ModelConfig, *,
         causal = False
 
     if cache is not None and "k_pages" in cache:
-        assert cache_pos is not None and block_table is not None, (
-            "paged caches are decode-only and need a block table")
-        y, new_cache = _paged_decode(params, x, cfg, q, k, v, cache,
-                                     cache_pos, block_table)
-        return y, new_cache
+        assert cache_pos is not None and block_table is not None \
+            and layer is not None, (
+                "paged caches are decode-only and need a block table and "
+                "a layer index")
+        return _paged_decode(params, x, cfg, q, k, v, cache, cache_pos,
+                             block_table, layer)
 
     new_cache = cache
     opt = cache is not None and cfg.cache_layout == "opt"
@@ -477,9 +481,12 @@ def attn_apply(params, x: jnp.ndarray, cfg: ModelConfig, *,
 
 
 def _paged_decode(params, x, cfg: ModelConfig, q, k, v, cache,
-                  cache_pos, block_table):
+                  cache_pos, block_table, layer):
     """Paged decode step (DESIGN.md §9): scatter the token's K/V into its
-    row's current page, then attend over the block-table-indexed pages.
+    row's current page of group ``layer`` of the stacked (G, P, ps, KV,
+    hd) pool, then attend over the block-table-indexed pages of that
+    group. The pool is written and read in place: no copy of the layer's
+    pages is taken, and the whole pool is returned as the new cache.
 
     Every live row writes to a page it privately owns (COW in the page pool
     guarantees this); free slots' block tables are all-zero, so their
@@ -501,45 +508,31 @@ def _paged_decode(params, x, cfg: ModelConfig, q, k, v, cache,
     pos = jnp.asarray(cache_pos)
     b, sq = k.shape[0], k.shape[1]
     h = cfg.num_heads + cfg.head_pad
+    base = pos[:, None] if pos.ndim else pos
+    pos2d = jnp.broadcast_to(base + jnp.arange(sq), (b, sq))
+    pids = block_table[jnp.arange(b)[:, None], pos2d // ps]   # (B, S)
+    offs = pos2d % ps
+    # one scatter of B x S rows into the stacked pool, in place
+    if quantized:
+        kc, ks = quantize_rows(k)                 # (B,S,KV,hd)/(B,S,KV)
+        vc, vs = quantize_rows(v)
+        k_pages = Int8Pages(k_pages.codes.at[layer, pids, offs].set(kc),
+                            k_pages.scales.at[layer, pids, offs].set(ks))
+        v_pages = Int8Pages(v_pages.codes.at[layer, pids, offs].set(vc),
+                            v_pages.scales.at[layer, pids, offs].set(vs))
+    else:
+        k_pages = k_pages.at[layer, pids, offs].set(k.astype(k_pages.dtype))
+        v_pages = v_pages.at[layer, pids, offs].set(v.astype(v_pages.dtype))
     if sq == 1:
-        rows = jnp.arange(k.shape[0])
-        pids = block_table[rows, pos // ps]       # (B,) page of this token
-        offs = pos % ps
-        k_tok, v_tok = k[:, 0], v[:, 0]           # (B, KV, hd)
-        if quantized:
-            kc, ks = quantize_rows(k_tok)
-            vc, vs = quantize_rows(v_tok)
-            k_pages = Int8Pages(k_pages.codes.at[pids, offs].set(kc),
-                                k_pages.scales.at[pids, offs].set(ks))
-            v_pages = Int8Pages(v_pages.codes.at[pids, offs].set(vc),
-                                v_pages.scales.at[pids, offs].set(vs))
-        else:
-            k_pages = k_pages.at[pids, offs].set(k_tok.astype(k_pages.dtype))
-            v_pages = v_pages.at[pids, offs].set(v_tok.astype(v_pages.dtype))
         o = kops.paged_decode_attention(
-            q[:, 0], k_pages, v_pages, block_table, pos + 1,
+            q[:, 0], k_pages, v_pages, block_table, pos + 1, layer=layer,
             window=cfg.sliding_window, impl=cfg.paged_attn_impl)
         o_seq = o[:, None]                        # (B, 1, H, hd)
     else:
-        base = pos[:, None] if pos.ndim else pos
-        pos2d = jnp.broadcast_to(base + jnp.arange(sq), (b, sq))
-        rows = jnp.arange(b)[:, None]
-        pids = block_table[rows, pos2d // ps]     # (B, S)
-        offs = pos2d % ps
-        if quantized:
-            kc, ks = quantize_rows(k)             # (B,S,KV,hd)/(B,S,KV)
-            vc, vs = quantize_rows(v)
-            k_pages = Int8Pages(k_pages.codes.at[pids, offs].set(kc),
-                                k_pages.scales.at[pids, offs].set(ks))
-            v_pages = Int8Pages(v_pages.codes.at[pids, offs].set(vc),
-                                v_pages.scales.at[pids, offs].set(vs))
-        else:
-            k_pages = k_pages.at[pids, offs].set(k.astype(k_pages.dtype))
-            v_pages = v_pages.at[pids, offs].set(v.astype(v_pages.dtype))
         o_seq = kops.paged_window_attention(
             q, k_pages, v_pages, block_table,
-            jnp.broadcast_to(pos + 1, (b,)), window=cfg.sliding_window,
-            impl=cfg.paged_attn_impl)
+            jnp.broadcast_to(pos + 1, (b,)), layer=layer,
+            window=cfg.sliding_window, impl=cfg.paged_attn_impl)
     y = linear_apply(params["o"],
                      o_seq.reshape(*x.shape[:-1], h * cfg.head_dim),
                      cfg)

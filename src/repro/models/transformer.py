@@ -192,9 +192,11 @@ class LM:
     def _apply_block(self, bparams, x, kind: str, ffn: str, *,
                      positions, causal=True, cache=None, cache_pos=None,
                      enc_out=None, block_table=None, window=None,
-                     token_mask=None):
+                     token_mask=None, layer=None):
         """One layer -> (x, new_cache, aux loss, MoE stats (2,) int32:
-        (row, held expert) pairs computed and held experts with a row)."""
+        (row, held expert) pairs computed and held experts with a row).
+        A paged ``cache`` is the whole stacked page pool and ``layer`` the
+        group this layer reads and writes."""
         cfg = self.cfg
         lcfg = self.layer_cfg(cfg.sliding_window if window is None
                               else window)
@@ -204,7 +206,7 @@ class LM:
             h, new_cache = attention.attn_apply(
                 bparams["mixer"], h, lcfg, positions=positions,
                 causal=causal, cache=cache, cache_pos=cache_pos,
-                block_table=block_table)
+                block_table=block_table, layer=layer)
         else:
             h, new_cache = ssm.ssm_apply(
                 bparams["mixer"], h, cfg, cache=cache, cache_pos=cache_pos)
@@ -247,7 +249,12 @@ class LM:
         layer-invariant, so it rides into the scan body as a closure
         constant rather than a sliced xs leaf. ``token_mask`` (B, S) marks
         the rows that carry a request: MoE layers route only those.
-        Returns (x, new caches, summed aux loss, summed MoE stats)."""
+        Returns (x, new caches, summed aux loss, summed MoE stats).
+
+        A paged layer gets its whole stacked page pool and its group's
+        index, and writes its new tokens into the pool in place; no copy
+        of its pages is taken or written back. Dense and SSM caches are
+        sliced to the layer's group and written back."""
         cfg = self.cfg
         kw = dict(positions=positions, causal=causal, cache_pos=cache_pos,
                   enc_out=enc_out, block_table=block_table,
@@ -257,20 +264,24 @@ class LM:
         stats_lead = jnp.zeros((2,), jnp.int32)
         for i, (kind, ffn) in enumerate(self.lead_kinds):
             c = caches.get(f"lead{i}") if caches is not None else None
-            if c is not None:
+            paged = c is not None and "k_pages" in c
+            if c is not None and not paged:
                 c = jax.tree.map(lambda a: a[0], c)
             x, nc, aux, stats = self._apply_block(
                 params[f"lead{i}"], x, kind, ffn, cache=c,
-                window=self.lead_windows[i], **kw)
+                window=self.lead_windows[i], layer=0 if paged else None,
+                **kw)
             aux_lead += aux
             stats_lead += stats
             if nc is not None:
-                new_caches[f"lead{i}"] = jax.tree.map(lambda a: a[None], nc)
+                new_caches[f"lead{i}"] = nc if paged else jax.tree.map(
+                    lambda a: a[None], nc)
 
-        # The stacked caches ride in the scan's carry: each layer reads its
-        # group's slice and writes its new cache back in place, so no
-        # second cache-sized stack is built. A layer whose new cache has
-        # another form (the 'opt' layout's new tokens) emits it per group.
+        # The stacked caches ride in the scan's carry, so no second
+        # cache-sized stack is built. A paged layer updates its group of
+        # the pool in place; a dense layer reads its group's slice and
+        # writes its new cache back; a layer whose new cache has another
+        # form (the 'opt' layout's new tokens) emits it per group.
         def body(carry, xs):
             x, stacked = carry
             stacked = dict(stacked)
@@ -279,17 +290,21 @@ class LM:
             emitted = {}
             for j, (kind, ffn) in enumerate(self.block_kinds):
                 key = f"cache{j}"
-                c = None
-                if key in stacked:
-                    c = jax.tree.map(lambda a: a[xs["g"]], stacked[key])
+                c = stacked.get(key)
+                paged = c is not None and "k_pages" in c
+                if c is not None and not paged:
+                    c = jax.tree.map(lambda a: a[xs["g"]], c)
                 x, nc, aux, stats = self._apply_block(
                     xs[f"block{j}"], x, kind, ffn, cache=c,
-                    window=self.block_windows[j], **kw)
+                    window=self.block_windows[j],
+                    layer=xs["g"] if paged else None, **kw)
                 aux_total += aux
                 stats_total += stats
                 if nc is None:
                     continue
-                if (jax.tree_util.tree_structure(nc)
+                if paged:
+                    stacked[key] = nc
+                elif (jax.tree_util.tree_structure(nc)
                         == jax.tree_util.tree_structure(c)):
                     stacked[key] = jax.tree.map(
                         lambda a, n: a.at[xs["g"]].set(n.astype(a.dtype)),

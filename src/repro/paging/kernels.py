@@ -37,7 +37,13 @@ than token j-1. Registered lowerings (``kernels.ops.register_paged_attn``):
 
 All three accept bf16 page arrays or ``quant.Int8Pages`` containers
 (per-page scales dequantized after the gather — inside the kernel for the
-Pallas path, so HBM reads stay int8).
+Pallas path, so HBM reads stay int8), either one layer's
+``(P, ps, KV, hd)`` pages or the model's stacked ``(G, P, ps, KV, hd)``
+pool with ``layer`` naming the group to read (``kernels.ops.page_stack``
+views one layer's pages as ``G = 1``). The Pallas kernels take the layer
+as one more scalar-prefetch operand and their page index maps lead with
+it, so a layer's pages are DMA'd straight out of the stacked pool and no
+copy of the layer is taken; the XLA lowerings index the layer themselves.
 """
 from __future__ import annotations
 
@@ -50,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ops import register_paged_attn
+from repro.kernels.ops import page_stack, register_paged_attn
 from repro.paging.quant import Int8Pages, dequantize_rows
 
 NEG_INF = -1e30
@@ -62,10 +68,10 @@ Pages = Union[jnp.ndarray, Int8Pages]
 
 
 def _page_geometry(pages: Pages):
-    """(n_pages, page_size, kv_heads, head_dim) of a page operand."""
+    """(n_pages, page_size, kv_heads, head_dim) of a stacked page operand."""
     shape = pages.codes.shape if isinstance(pages, Int8Pages) else pages.shape
-    assert len(shape) == 4, f"expected (P, ps, KV, hd) pages, got {shape}"
-    return shape
+    assert len(shape) == 5, f"expected (G, P, ps, KV, hd) pages, got {shape}"
+    return shape[1:]
 
 
 def _page_step(q, k, v, m, l, acc, *, kv_heads: int, pos0, length,
@@ -118,17 +124,19 @@ def _load_page(pages, dtype=jnp.float32):
     return pages.astype(dtype)
 
 
-def _gather(pages: Pages, block_table: jnp.ndarray, dtype) -> jnp.ndarray:
-    """(B, T) block table -> (B, T*ps, KV, hd) gathered sequence view.
-    int8 pages dequantize to ``dtype``; raw pages keep their storage dtype
-    (it already equals the dense cache dtype, which the bit-exactness
-    contract with the dense path requires)."""
+def _gather(pages: Pages, layer, block_table: jnp.ndarray,
+            dtype) -> jnp.ndarray:
+    """(B, T) block table -> (B, T*ps, KV, hd) gathered sequence view of
+    group ``layer`` of the stacked pages. int8 pages dequantize to
+    ``dtype``; raw pages keep their storage dtype (it already equals the
+    dense cache dtype, which the bit-exactness contract with the dense
+    path requires)."""
     if isinstance(pages, Int8Pages):
-        codes = pages.codes[block_table]          # (B, T, ps, KV, hd)
-        scales = pages.scales[block_table]        # (B, T, ps, KV)
+        codes = pages.codes[layer, block_table]   # (B, T, ps, KV, hd)
+        scales = pages.scales[layer, block_table]  # (B, T, ps, KV)
         seq = dequantize_rows(codes, scales, dtype)
     else:
-        seq = pages[block_table]
+        seq = pages[layer, block_table]
     b, t, ps, kv, hd = seq.shape
     return seq.reshape(b, t * ps, kv, hd)
 
@@ -139,7 +147,8 @@ def _gather(pages: Pages, block_table: jnp.ndarray, dtype) -> jnp.ndarray:
 
 @register_paged_attn("jax", priority=10)
 def paged_decode_attention_jax(q, k_pages: Pages, v_pages: Pages,
-                               block_table, lengths, *, window: int = 0,
+                               block_table, lengths, *, layer=0,
+                               window: int = 0,
                                interpret: Optional[bool] = None):
     """Gather + dense-identical attention (see module docstring).
 
@@ -148,8 +157,8 @@ def paged_decode_attention_jax(q, k_pages: Pages, v_pages: Pages,
     (causal=False) — tests/test_paging.py pins the bitwise equality."""
     del interpret
     b, h, hd = q.shape
-    ks = _gather(k_pages, block_table, q.dtype)
-    vs = _gather(v_pages, block_table, q.dtype)
+    ks = _gather(page_stack(k_pages), layer, block_table, q.dtype)
+    vs = _gather(page_stack(v_pages), layer, block_table, q.dtype)
     kvh = ks.shape[2]
     qg = q.reshape(b, 1, kvh, h // kvh, hd)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg, ks,
@@ -171,18 +180,21 @@ def paged_decode_attention_jax(q, k_pages: Pages, v_pages: Pages,
 
 @functools.partial(jax.jit, static_argnames=("window",))
 def paged_decode_attention_ref(q, k_pages: Pages, v_pages: Pages,
-                               block_table, lengths, *, window: int = 0):
+                               block_table, lengths, *, layer=0,
+                               window: int = 0):
     """Bit-exact mirror of the Pallas kernel: per row, the same
     ``_page_step`` over the row's pages in block-table order, compiled as
     one program like the interpret-mode kernel (op-by-op dispatch rounds
     differently). Reference only — the B·T loop unrolls into the trace."""
     b, h, hd = q.shape
+    k_pages, v_pages = page_stack(k_pages), page_stack(v_pages)
     _, ps, kv, _ = _page_geometry(k_pages)
 
     def page(pages, pid):
         if isinstance(pages, Int8Pages):
-            return _load_page((pages.codes[pid], pages.scales[pid]))
-        return _load_page(pages[pid])
+            return _load_page((pages.codes[layer, pid],
+                               pages.scales[layer, pid]))
+        return _load_page(pages[layer, pid])
 
     outs = []
     for i in range(b):
@@ -205,8 +217,14 @@ def paged_decode_attention_ref(q, k_pages: Pages, v_pages: Pages,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, kv_heads: int,
-            window: int, quantized: bool):
+def _layer_operand(layer) -> jnp.ndarray:
+    """The layer index as the (1,) int32 scalar-prefetch operand that the
+    page index maps lead with."""
+    return jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+
+
+def _kernel(bt_ref, len_ref, _layer_ref, q_ref, *refs, page_size: int,
+            kv_heads: int, window: int, quantized: bool):
     b = pl.program_id(0)
     t = pl.program_id(1)
     if quantized:
@@ -246,15 +264,19 @@ def _kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, kv_heads: int,
 @functools.partial(jax.jit,
                    static_argnames=("window", "interpret"))
 def paged_decode_attention_pallas(q, k_pages: Pages, v_pages: Pages,
-                                  block_table, lengths, *, window: int = 0,
+                                  block_table, lengths, *, layer=0,
+                                  window: int = 0,
                                   interpret: Optional[bool] = None):
-    """q (B, H, hd); pages (P, ps, KV, hd) (or ``Int8Pages``); block_table
-    (B, T) int32 (pad unused entries with any valid page id, e.g. 0 — their
-    keys are masked out by ``lengths``); lengths (B,) int32 valid-token
-    counts including the current token. Returns (B, H, hd)."""
+    """q (B, H, hd); pages (G, P, ps, KV, hd) (or ``Int8Pages``; one
+    layer's (P, ps, KV, hd) reads as G = 1), of which group ``layer`` is
+    read; block_table (B, T) int32 (pad unused entries with any valid page
+    id, e.g. 0 — their keys are masked out by ``lengths``); lengths (B,)
+    int32 valid-token counts including the current token. Returns
+    (B, H, hd)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, hd = q.shape
+    k_pages, v_pages = page_stack(k_pages), page_stack(v_pages)
     _, ps, kv, _ = _page_geometry(k_pages)
     t = block_table.shape[1]
     quantized = isinstance(k_pages, Int8Pages)
@@ -265,11 +287,13 @@ def paged_decode_attention_pallas(q, k_pages: Pages, v_pages: Pages,
         return bt[i, jnp.minimum(j, jnp.maximum(ln[i] - 1, 0) // ps)]
 
     page_spec = pl.BlockSpec(
-        (1, ps, kv, hd), lambda i, j, bt, ln: (page_index(i, j, bt, ln),
-                                               0, 0, 0))
+        (None, 1, ps, kv, hd),
+        lambda i, j, bt, ln, ly: (ly[0], page_index(i, j, bt, ln), 0, 0, 0))
     scale_spec = pl.BlockSpec(
-        (1, ps, kv), lambda i, j, bt, ln: (page_index(i, j, bt, ln), 0, 0))
-    in_specs = [pl.BlockSpec((1, h, hd), lambda i, j, bt, ln: (i, 0, 0))]
+        (None, 1, ps, kv),
+        lambda i, j, bt, ln, ly: (ly[0], page_index(i, j, bt, ln), 0, 0))
+    in_specs = [pl.BlockSpec((1, h, hd),
+                             lambda i, j, bt, ln, ly: (i, 0, 0))]
     if quantized:
         in_specs += [page_spec, scale_spec, page_spec, scale_spec]
         operands = [q, k_pages.codes, k_pages.scales,
@@ -279,10 +303,11 @@ def paged_decode_attention_pallas(q, k_pages: Pages, v_pages: Pages,
         operands = [q, k_pages, v_pages]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, t),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, hd), lambda i, j, bt, ln: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, hd),
+                               lambda i, j, bt, ln, ly: (i, 0, 0)),
         scratch_shapes=[pltpu.VMEM((h, hd), jnp.float32)] * 3,
     )
     return pl.pallas_call(
@@ -295,7 +320,7 @@ def paged_decode_attention_pallas(q, k_pages: Pages, v_pages: Pages,
         name="paged_decode_attention",
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      *operands)
+      _layer_operand(layer), *operands)
 
 
 # ---------------------------------------------------------------------------
@@ -346,8 +371,9 @@ def _window_page_step(q, k, v, m, l, acc, *, group: int, pos0, first,
             jnp.broadcast_to(l_new, (kvh, r, hd)), alpha * acc + pv)
 
 
-def _window_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int,
-                   group: int, window: int, quantized: bool):
+def _window_kernel(bt_ref, len_ref, _layer_ref, q_ref, *refs,
+                   page_size: int, group: int, window: int,
+                   quantized: bool):
     b = pl.program_id(0)
     t = pl.program_id(2)
     if quantized:
@@ -398,11 +424,12 @@ def _window_tile(s: int, group: int, kv: int, hd: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_window_attention_pallas(q, k_pages: Pages, v_pages: Pages,
-                                  block_table, lengths, *, window: int = 0,
+                                  block_table, lengths, *, layer=0,
+                                  window: int = 0,
                                   interpret: Optional[bool] = None):
     """Attention of an S-token window per row over its own pages.
 
-    q (B, S, H, hd); pages and block_table (B, T) as in
+    q (B, S, H, hd); pages, ``layer`` and block_table (B, T) as in
     ``paged_decode_attention_pallas``; lengths (B,) int32 valid-token
     count of each row's first window token: token ``j`` attends the keys
     at positions ``< lengths + j``. Returns (B, S, H, hd), equal per token
@@ -412,6 +439,7 @@ def paged_window_attention_pallas(q, k_pages: Pages, v_pages: Pages,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, s, h, hd = q.shape
+    k_pages, v_pages = page_stack(k_pages), page_stack(v_pages)
     _, ps, kv, _ = _page_geometry(k_pages)
     g = h // kv
     t = block_table.shape[1]
@@ -430,13 +458,15 @@ def paged_window_attention_pallas(q, k_pages: Pages, v_pages: Pages,
         return bt[i, jnp.minimum(j, last)]
 
     page_spec = pl.BlockSpec(
-        (1, ps, kv, hd),
-        lambda i, qi, j, bt, ln: (page_index(i, qi, j, bt, ln), 0, 0, 0))
+        (None, 1, ps, kv, hd),
+        lambda i, qi, j, bt, ln, ly: (ly[0], page_index(i, qi, j, bt, ln),
+                                      0, 0, 0))
     scale_spec = pl.BlockSpec(
-        (1, ps, kv),
-        lambda i, qi, j, bt, ln: (page_index(i, qi, j, bt, ln), 0, 0))
+        (None, 1, ps, kv),
+        lambda i, qi, j, bt, ln, ly: (ly[0], page_index(i, qi, j, bt, ln),
+                                      0, 0))
     q_spec = pl.BlockSpec((1, kv, rows, hd),
-                          lambda i, qi, j, bt, ln: (i, 0, qi, 0))
+                          lambda i, qi, j, bt, ln, ly: (i, 0, qi, 0))
     if quantized:
         in_specs = [q_spec, page_spec, scale_spec, page_spec, scale_spec]
         operands = [qw, k_pages.codes, k_pages.scales,
@@ -446,7 +476,7 @@ def paged_window_attention_pallas(q, k_pages: Pages, v_pages: Pages,
         operands = [qw, k_pages, v_pages]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, s // tile, t),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -462,7 +492,7 @@ def paged_window_attention_pallas(q, k_pages: Pages, v_pages: Pages,
         name="paged_decode_attention_window",
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      *operands)
+      _layer_operand(layer), *operands)
     return out.reshape(b, kv, s, g, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(b, s, h, hd)
 

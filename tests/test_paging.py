@@ -116,13 +116,13 @@ def _window_case(heads, kv_heads, page_size, s, kv_dtype, seed=0):
     return q, kp, vp, table, lengths
 
 
-def _flat_ref(q, kp, vp, table, lengths, window):
+def _flat_ref(q, kp, vp, table, lengths, window, layer=0):
     """The window as (B·S) single-query rows through the decode
     kernel's reference: token j of row b has ``lengths[b] + j`` keys."""
     b, s, h, hd = q.shape
     return paged_decode_attention_ref(
         q.reshape(b * s, h, hd), kp, vp, jnp.repeat(table, s, axis=0),
-        (lengths[:, None] + jnp.arange(s)).reshape(-1),
+        (lengths[:, None] + jnp.arange(s)).reshape(-1), layer=layer,
         window=window).reshape(q.shape)
 
 
@@ -146,6 +146,99 @@ def test_paged_window_kernel_matches_flattened_ref(heads, kv_heads,
         np.asarray(out), np.asarray(_flat_ref(q, kp, vp, table, lengths,
                                               window)),
         rtol=2e-5, atol=2e-5)
+
+
+def _stacked_case(kv_dtype, groups=3):
+    """A window case over a stacked pool of ``groups`` layers with
+    distinct pages. Row 0's table entries past its length and all of row
+    2's (a pad row) point at the trash page 0."""
+    q, _, _, table, lengths = _window_case(4, 2, 4, 3, kv_dtype)
+    layers = [_window_case(4, 2, 4, 3, kv_dtype, seed=10 + g)[1:3]
+              for g in range(groups)]
+    kp = jax.tree.map(lambda *a: jnp.stack(a), *[k for k, _ in layers])
+    vp = jax.tree.map(lambda *a: jnp.stack(a), *[v for _, v in layers])
+    table = table.at[0, 2:].set(0)
+    if kv_dtype == "bf16":
+        q = q.astype(jnp.bfloat16)
+    return q, kp, vp, table, lengths
+
+
+def _paged_call(impl, form, q, kp, vp, table, lengths, window, **layer):
+    if form == "decode":
+        fn = {"pallas": paged_decode_attention_pallas,
+              "jax": paged_decode_attention_jax,
+              "ref": paged_decode_attention_ref}[impl]
+        return fn(q[:, 0], kp, vp, table, lengths, window=window, **layer)
+    if impl == "ref":
+        return _flat_ref(q, kp, vp, table, lengths, window, **layer)
+    return kops.paged_window_attention(q, kp, vp, table, lengths,
+                                       window=window, impl=impl, **layer)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "jax", "ref"])
+@pytest.mark.parametrize("form", ["decode", "window"])
+@pytest.mark.parametrize("window", [0, 5])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_layer_indexed_lowerings_equal_the_layers_slice(impl, form, window,
+                                                        kv_dtype):
+    """Each lowering (Pallas in interpret mode, the jax gather, the
+    reference) reading group ``g`` of a stacked (G, P, ps, KV, hd) pool
+    equals the same call on ``pages[g]`` alone, bit for bit, for every
+    g: decode rows and 3-token windows, bf16 and int8 pages, a sliding
+    window, dead table entries on the trash page."""
+    q, kp, vp, table, lengths = _stacked_case(kv_dtype)
+    for g in range(3):
+        one_k = jax.tree.map(lambda a: a[g], kp)
+        one_v = jax.tree.map(lambda a: a[g], vp)
+        got = _paged_call(impl, form, q, kp, vp, table, lengths, window,
+                          layer=g)
+        want = _paged_call(impl, form, q, one_k, one_v, table, lengths,
+                           window)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("impl", ["jax", "pallas"])
+@pytest.mark.parametrize("sq", [1, 3])
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_paged_decode_writes_its_layer_of_the_stack_only(impl, sq,
+                                                         kv_dtype):
+    """``_paged_decode`` at layer ``g`` of a stacked pool scatters the new
+    tokens into group ``g`` alone: every other group's pages stay bit for
+    bit as they were, and group ``g`` and the output equal the same step
+    on a one-group pool holding ``g``'s pages."""
+    cfg = _cfg(paged_attn_impl=impl)
+    params, _ = attention.attn_init(jax.random.PRNGKey(0), cfg)
+    b, ps, t, groups = 2, 4, 3, 3
+    one = attention.init_paged_kv_cache(cfg, 1 + b * t, ps,
+                                        dtype=jnp.float32,
+                                        kv_dtype=kv_dtype)
+    keys = jax.random.split(jax.random.PRNGKey(1), 8)
+    pool = {n: jax.tree.map(
+        lambda a, key=key: jax.random.normal(
+            key, (groups,) + a.shape, jnp.float32).astype(a.dtype)
+        if a.dtype != jnp.int8 else jax.random.randint(
+            key, (groups,) + a.shape, -127, 128, jnp.int32).astype(a.dtype),
+        one[n]) for n, key in zip(one, keys[:2])}
+    table = 1 + jnp.arange(b * t, dtype=jnp.int32).reshape(b, t)
+    pos = jnp.asarray([2, 5], jnp.int32)
+    x = jax.random.normal(keys[2], (b, sq, cfg.d_model), jnp.float32)
+    q = jax.random.normal(keys[3], (b, sq, cfg.num_heads, cfg.head_dim))
+    k = jax.random.normal(keys[4], (b, sq, cfg.num_kv_heads, cfg.head_dim))
+    v = jax.random.normal(keys[5], (b, sq, cfg.num_kv_heads, cfg.head_dim))
+    for g in range(groups):
+        y, new = attention._paged_decode(params, x, cfg, q, k, v, pool, pos,
+                                         table, g)
+        alone = jax.tree.map(lambda a: a[g:g + 1], pool)
+        y1, new1 = attention._paged_decode(params, x, cfg, q, k, v, alone,
+                                           pos, table, 0)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(y1))
+        for a, n, n1 in zip(jax.tree.leaves(pool), jax.tree.leaves(new),
+                            jax.tree.leaves(new1)):
+            a, n, n1 = np.asarray(a), np.asarray(n), np.asarray(n1)
+            others = [o for o in range(groups) if o != g]
+            np.testing.assert_array_equal(n[others], a[others])
+            np.testing.assert_array_equal(n[g], n1[0])
+            assert not np.array_equal(n[g], a[g])
 
 
 def test_paged_window_kernel_query_tiles(monkeypatch):
@@ -205,14 +298,15 @@ def test_paged_decode_dispatch_by_window_width(monkeypatch, sq):
                 calls.append(_n), _f(*a, **k))[1])
     params, _ = attention.attn_init(jax.random.PRNGKey(0), cfg)
     b, ps, t = 2, 4, 3
-    cache = attention.init_paged_kv_cache(cfg, 1 + b * t, ps,
-                                          dtype=jnp.float32)
+    cache = jax.tree.map(lambda a: a[None], attention.init_paged_kv_cache(
+        cfg, 1 + b * t, ps, dtype=jnp.float32))
     table = 1 + jnp.arange(b * t, dtype=jnp.int32).reshape(b, t)
     pos = jnp.asarray([2, 5], jnp.int32)
     x = jnp.ones((b, sq, cfg.d_model), jnp.float32)
     q = jnp.ones((b, sq, cfg.num_heads, cfg.head_dim), jnp.float32)
     kv = jnp.ones((b, sq, cfg.num_kv_heads, cfg.head_dim), jnp.float32)
-    attention._paged_decode(params, x, cfg, q, kv, kv, cache, pos, table)
+    attention._paged_decode(params, x, cfg, q, kv, kv, cache, pos, table,
+                            0)
     assert calls[0] == ("paged_decode_attention" if sq == 1
                         else "paged_window_attention")
     assert ("paged_window_attention" in calls) == (sq > 1)

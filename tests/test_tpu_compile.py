@@ -188,10 +188,10 @@ def test_paged_window_attention_compiles_at_mistral_widths(sds, b, s):
     assert _kernel_classes(compiled) == {"attn"}
 
 
-def test_tp_paged_window_attention_runs_per_shard(topo):
-    """Under a 2-way ``"model"`` axis the window kernel runs per shard on
-    half the KV heads: q's head axis (the one before ``head_dim``) splits
-    with the pages' KV-head axis."""
+def _assert_window_runs_per_shard(topo, groups):
+    """The window kernel, compiled under a 2-way ``"model"`` axis over
+    one layer's pages (``groups`` None) or a stacked pool of ``groups``
+    layers read at its last layer, runs on half the KV heads a shard."""
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
     b, s, h, kv, hd, ps, t = 4, 4, 32, 8, 128, 128, 2
 
@@ -199,14 +199,16 @@ def test_tp_paged_window_attention_runs_per_shard(topo):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    pages = placed((b * t + 1, ps, kv, hd), BF16,
-                   P(None, None, "model", None))
+    lead = () if groups is None else (groups,)
+    pages = placed(lead + (b * t + 1, ps, kv, hd), BF16,
+                   P(*[None] * len(lead), None, None, "model", None))
     args = (placed((b, s, h, hd), BF16, P(None, None, "model", None)),
             pages, pages, placed((b, t), jnp.int32, P()),
             placed((b,), jnp.int32, P()))
+    layer = 0 if groups is None else groups - 1
     with ops.tensor_parallel(mesh):
         lowered = jax.jit(lambda *a: ops.paged_window_attention(
-            *a, impl="pallas", interpret=False)).lower(*args)
+            *a, layer=layer, impl="pallas", interpret=False)).lower(*args)
     calls = [ln for ln in lowered.compile().as_text().splitlines()
              if "tpu_custom_call" in ln]
     rows = s * h // kv
@@ -214,6 +216,19 @@ def test_tp_paged_window_attention_runs_per_shard(topo):
         calls
     assert not any(f"bf16[{b},{kv},{rows},{hd}]" in ln for ln in calls), \
         calls
+
+
+def test_tp_paged_window_attention_runs_per_shard(topo):
+    """Under a 2-way ``"model"`` axis the window kernel runs per shard on
+    half the KV heads: q's head axis (the one before ``head_dim``) splits
+    with the pages' KV-head axis."""
+    _assert_window_runs_per_shard(topo, None)
+
+
+def test_tp_paged_window_attention_reads_the_stacked_pool_per_shard(topo):
+    """The same split over a stacked pool of three layers read at a layer
+    index: the pool's KV-head axis (after the layer axis) splits."""
+    _assert_window_runs_per_shard(topo, 3)
 
 
 def test_tp_sharded_packed_linear_runs_per_shard(topo):
@@ -258,3 +273,95 @@ def test_moe_expert_gemm_compiles_at_k_exaone_widths(sds, k, n, m, bm):
                         sds((e, n), jnp.float32), sds((tiles,), jnp.int32),
                         sds((tiles,), jnp.int32), sds((1,), jnp.int32))
     assert _kernel_classes(compiled) == {"expert"}
+
+
+# One layer's K (or V) pages at Mistral-NeMo's widths: 193 pages of 128
+# tokens x 8 KV heads x 128.
+POOL_PAGES, PAGE, KV_HEADS, HEAD_DIM = 193, 128, 8, 128
+LAYER_PAGES = POOL_PAGES * PAGE * KV_HEADS * HEAD_DIM
+
+
+def _mistral_nemo_stack():
+    from repro.configs.base import ModelConfig
+    return ModelConfig(
+        name="mistral-nemo-12b", family="dense", num_layers=2,
+        d_model=5120, num_heads=32, num_kv_heads=KV_HEADS,
+        head_dim=HEAD_DIM, d_ff=14336, vocab_size=131072,
+        quantization="ternary_packed", ternary_min_dim=512,
+        param_dtype="float32", fused_mlp="off", ternary_kernel="pallas",
+        paged_attn_impl="pallas")
+
+
+def _k_exaone_stack():
+    """K-EXAONE's widths with one dense lead layer and two MoE layers
+    (each one scan group), every layer a 128-token window."""
+    from repro.configs.base import ModelConfig
+    return ModelConfig(
+        name="k-exaone", family="moe", num_layers=3, d_model=6144,
+        num_heads=64, num_kv_heads=KV_HEADS, head_dim=HEAD_DIM,
+        d_ff=18432, vocab_size=153600, layer_windows=(128,) * 3,
+        layer_ffns=("mlp", "moe", "moe"), qk_norm=True,
+        sublayer_norm="post", rope_window_only=True, num_experts=128,
+        experts_held=8, num_experts_per_tok=8, d_ff_expert=2048,
+        n_shared_experts=1, moe_scoring="sigmoid", routed_scaling=2.5,
+        quantization="ternary_packed", ternary_min_dim=512,
+        param_dtype="bfloat16", fused_mlp="off", ternary_kernel="pallas",
+        paged_attn_impl="pallas")
+
+
+POOL_COPY_OPS = ("dynamic-slice", "dynamic-update-slice", "copy",
+                 "copy-start", "copy-done")
+
+
+def _pool_copies(text):
+    """The instructions of an optimized HLO module (fused computations
+    included) that slice, update or copy an array of one layer's pages
+    or more."""
+    out = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?\S+\s*=\s*(.*?)\s([a-z][\w-]*)\(",
+                     ln)
+        if not m or m.group(2) not in POOL_COPY_OPS:
+            continue
+        sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                 for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))]
+        if max(sizes, default=0) >= LAYER_PAGES:
+            out.append(ln.strip()[:200])
+    return out
+
+
+@pytest.mark.parametrize("case", ["decode", "chunk_window", "lead_layer"])
+def test_layer_loop_copies_no_layers_pages(sds, monkeypatch, case):
+    """The engine's step programs at full widths, compiled for a v5e: a
+    32-row paged decode step and a 32 x 16 chunk window of a two-layer
+    Mistral-NeMo stack, and a decode step of a K-EXAONE stack with a
+    lead layer, each over 193 pages of 128 tokens. Every paged layer
+    reads and writes the stacked pool at its index, so the programs hold
+    no slice, update or copy of a layer's pages (101 MB at these widths)
+    outside the paged attention kernels."""
+    from repro.models import LM
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _k_exaone_stack() if case == "lead_layer" else \
+        _mistral_nemo_stack()
+    rows, s = 32, 16 if case == "chunk_window" else 1
+    model = LM(cfg)
+    as_sds = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                                       tree)
+    params = as_sds(model.init_with_specs_abstract()[0])
+    layers = as_sds(jax.eval_shape(
+        lambda: model.init_paged_cache(POOL_PAGES, PAGE, rows)["layers"]))
+
+    def step(params, layers, pos, toks, real, table):
+        cache = {"layers": layers, "pos": pos, "token_mask": real,
+                 "block_table": table}
+        logits, new, _ = model.decode_step(params, cache, toks,
+                                           with_stats=True)
+        return new["layers"], jnp.argmax(logits, axis=-1)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, layers, sds((rows,), jnp.int32), sds((rows, s), jnp.int32),
+        sds((rows, s), jnp.bool_),
+        sds((rows, 1024 // PAGE), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "attn" in _kernel_classes(compiled)
+    assert _pool_copies(text) == []
